@@ -9,12 +9,12 @@ After the per-strategy rows, ``fig1/batch/p*`` times the projection-
 batched loop nest (DESIGN.md §7) against the per-projection nest at
 several ``pbatch`` depths — same strategy, same projections, only the
 volume-residency structure changes.  ``fig1/batch_db/p*`` and
-``fig1/batch_micro/p*`` then time the batched *Pallas kernel* variants
-(DESIGN.md §9: deep DMA pipeline, micro-window compute) on a smaller
+``fig1/batch_shared*/p*`` then time the batched *Pallas kernel* variants
+(DESIGN.md §9: deep DMA pipeline, shared superset window) on a smaller
 kernel-sized volume — structural numbers in interpret mode off-TPU,
 compiled on TPU, comparable within one backend either way.  Then the
 autotuner sweeps its candidate space on this geometry (now including
-the ``pbatch × {plain, db, micro}`` cross), persists the winner
+the ``pbatch × {plain, db}`` cross), persists the winner
 (``.repro_tune/``), and the ``fig1/auto`` row times ``strategy="auto"``
 resolving through that cache — the chosen config lands in the
 ``--json`` trajectory via ``record_extra``.
@@ -72,7 +72,7 @@ def run(L: int | None = None):
          f"nproj={n_proj}")
 
     # Batched kernel variants: full n_proj stack per call through the
-    # Pallas batch path, db (depth-2 rotation) and micro-window compute.
+    # Pallas batch path, db (depth-2 rotation) and the shared window.
     # A smaller volume keeps interpret-mode (off-TPU) rows tractable;
     # the rows compare variants against each other, not against the jnp
     # rows above.
@@ -83,7 +83,6 @@ def run(L: int | None = None):
     for pb in sorted({min(pb, n_proj) for pb in KERNEL_PBATCHES}):
         for tag, flags in (("batch_db", dict(double_buffer=True,
                                              db_depth=2)),
-                           ("batch_micro", dict(micro=True)),
                            ("batch_shared", dict(shared_window=True)),
                            ("batch_shared_bf16",
                             dict(shared_window=True,

@@ -5,6 +5,9 @@ core-bound").  Here: ``shard_map`` reconstruction over an N-device mesh
 (subprocess with fake CPU devices so the parent process keeps 1 device),
 volume z-planes over ``data`` x projections over ``model`` — plus the
 collective-bytes model for the production mesh from the same code path.
+This is a CPU figure on fake devices: each child pins the CPU backend
+(a chip belongs to the one process that opened it, and the parent holds
+it), and a child that fails fails the module.
 """
 
 from __future__ import annotations
@@ -54,14 +57,15 @@ def run(L: int | None = None, n_proj: int | None = None):
                            "data": data, "model": model}
         env = dict(os.environ)
         env.pop("XLA_FLAGS", None)
+        env["JAX_PLATFORMS"] = "cpu"
         out = subprocess.run([sys.executable, "-c", script], env=env,
                              capture_output=True, text=True, timeout=600,
                              cwd=os.path.dirname(os.path.dirname(
                                  os.path.abspath(__file__))))
         if out.returncode != 0:
-            emit(f"fig2/ndev={ndev}", 0.0,
-                 f"ERROR {out.stderr.strip()[-120:]}")
-            continue
+            raise RuntimeError(
+                f"fig2 child ndev={ndev} exited {out.returncode}: "
+                f"{out.stderr.strip()[-2000:]}")
         rec = json.loads(out.stdout.strip().splitlines()[-1])
         results[ndev] = rec
         base = results.get(1, rec)["dt"]

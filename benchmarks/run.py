@@ -43,6 +43,8 @@ from pathlib import Path
 
 import jax
 
+from repro.compile_cache import enable_compile_cache
+
 from . import common
 from . import (ct_hillclimb, cycle_model, dispatch, fig1_single_device,
                fig2_scaling, fig3_codegen, fig4_streaming, fig5_serving,
@@ -128,21 +130,27 @@ def main(argv=None) -> None:
     # into its --json trajectory entry.
     common.RESULTS.clear()
     common.EXTRAS.clear()
+    cache = enable_compile_cache()
     print("name,us_per_call,derived")
     n_fail = 0
     ran = []
-    for name, mod in MODULES:
-        if only is not None and name not in only:
-            continue
-        ran.append(name)
-        t0 = time.time()
-        try:
-            mod.run()
-            print(f"# {name} done in {time.time() - t0:.1f}s", flush=True)
-        except Exception:  # noqa: BLE001 — keep the harness going
-            n_fail += 1
-            print(f"# {name} FAILED:")
-            traceback.print_exc()
+    try:
+        for name, mod in MODULES:
+            if only is not None and name not in only:
+                continue
+            ran.append(name)
+            t0 = time.time()
+            try:
+                mod.run()
+                print(f"# {name} done in {time.time() - t0:.1f}s",
+                      flush=True)
+            except Exception:  # noqa: BLE001 — keep the harness going
+                n_fail += 1
+                print(f"# {name} FAILED:")
+                traceback.print_exc()
+        print(f"# {cache.summary()}", flush=True)
+    finally:
+        cache.close()
     if args.json:
         _write_json(args.json, ran, n_fail)
     if n_fail:

@@ -1,12 +1,4 @@
-"""repro: paper-reproduction kernels + the LM/CT production stack.
-
-Importing any ``repro.*`` module routes through this package init, which
-installs the JAX API compatibility shims first (`repro._compat`) so the
-rest of the codebase — and the subprocess bodies the test suite spawns —
-can target the modern sharding surface unconditionally.
-"""
-
-from . import _compat  # noqa: F401  (side effect: backfill jax API names)
+"""repro: paper-reproduction kernels + the LM/CT production stack."""
 
 __version__ = "0.1.0"
 

@@ -8,25 +8,37 @@ linter prices with the same bytes, and vice versa (PR 6's hard-coded
 traffic-model tile is the bug class this kills).
 
 The model mirrors what the wrappers actually allocate
-(``repro.kernels.backproject_ops`` / ``backproject.py``):
+(``repro.kernels.backproject_ops`` / ``backproject.py``).  Every strip
+is a DMA *window*: the ``(band, width)`` footprint grown to
+:func:`repro.kernels.backproject.strip_window` (one extra
+``(sublane, 128)`` tile per axis, because window origins are
+tile-aligned).
 
-* **strip slots** — ``max(pbatch, depth) · band · width · itemsize``.
+* **strip slots** — ``max(pbatch, depth) · window · itemsize``.
   The plain batch kernel rotates 2 slots, the pipelined variant
   ``db_depth``, the shared-window kernel one ``(pbatch, band, width)``
-  slab; an ANY-space promotion may keep up to ``pbatch`` resident, so
-  the screen prices the larger of the two (the tuner's historical
-  conservative rule, kept bit-for-bit).
-* **volume tile** — aliased in/out ``(1, ty, chunk)`` f32 pair plus the
-  f32 accumulator: ``3 · ty · chunk · 4``.
-* **one-hot selectors** — ``rowsel (ty·chunk, band)`` and ``colsel
-  (ty·chunk, width)`` f32 temporaries of :func:`_tile_contrib`.
-* **int8 scale sideband** — the ``(pbatch, 2, rows)`` f32 scale/offset
-  block is VMEM-resident for the whole call (constant BlockSpec), with
-  ``rows`` the *padded* row count: ``max(band, n_v + 2)`` rounded up to
-  the wire dtype's sublane tile (32 rows for the 1-byte wire —
-  ``repro.kernels.backproject_ops._SUBLANE``).
+  slab; the screen prices the larger of the two (the tuner's
+  historical conservative rule).
+* **volume tile** — the aliased in/out ``(1, ty, chunk)`` f32 blocks,
+  each double-buffered by the grid pipeline, plus the f32 accumulator:
+  ``5 · ty · chunk · 4``.
+* **one-hot selectors** — per tile row, :func:`_tile_contrib` holds
+  ``colsel (width, chunk)`` and ``rowsel (band, chunk)`` f32 with their
+  int32 iotas, and the f32 strip the MXU reads:
+  ``2 · chunk · (band + width) · 4 + band · width · 4`` at window dims.
+* **int8 scale sideband** — the ``(pbatch, rows, 2)`` f32 scale/offset
+  block is VMEM-resident for the whole call (constant BlockSpec,
+  double-buffered like every blocked input), its 2-wide minor dim
+  padded to the 128-lane tile: ``2 · pbatch · rows · 128 · 4`` with
+  ``rows`` the *padded* row count.
 * **SMEM** — the ``(pbatch, 3, 4)`` f32 matrix stack (reported, never
   binding: SMEM is KBs and the stack is tiny).
+
+``compiled.memory_analysis()`` of a kernel program reports HBM
+arguments and temporaries only (its temp bytes are 0 for these
+kernels), so it says nothing about VMEM; the v5e compile in
+``tests/test_tpu_compile.py`` is the check that the configs the tuner
+proposes fit the chip.
 """
 
 from __future__ import annotations
@@ -49,19 +61,13 @@ VMEM_BUDGET_BYTES = 8 * 2 ** 20
 # ``repro.core.backproject.strip_wire_dtype`` validates against.
 WIRE_ITEMSIZE = {"float32": 4, "bfloat16": 2, "int8": 1}
 
-# Sublane tile per wire itemsize — mirrors (and is asserted in tests
-# against) ``repro.kernels.backproject_ops._SUBLANE``; duplicated here
-# so the byte model stays importable without pulling the kernel stack.
-_SUBLANE = {1: 32, 2: 16, 4: 8}
-
-
 def _padded_rows(gs: GeomStatic, band: int, itemsize: int) -> int:
     """Padded detector row count for a wire itemsize — the row shape
-    the ``(P, 2, rows)`` scale sideband is allocated at
-    (``backproject_ops._encode_padded``'s rounding)."""
-    sub = _SUBLANE.get(itemsize, 8)
-    rows = max(band, gs.n_v + 2)
-    return rows + (-rows) % sub
+    the ``(P, rows, 2)`` scale sideband is allocated at
+    (``backproject_ops._padded_dims``)."""
+    from repro.kernels.backproject_ops import _padded_dims
+
+    return _padded_dims(gs.n_v, gs.n_u, band, 128, itemsize)[0]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -107,10 +113,13 @@ def batch_vmem_estimate(gs: GeomStatic, *, pbatch: int, ty: int,
             raise ValueError(
                 f"unknown strip_dtype {strip_dtype!r}; want one of "
                 f"{tuple(WIRE_ITEMSIZE)}") from None
-    strips = max(pbatch, depth) * band * width * itemsize
-    tile = 3 * ty * chunk * 4
-    onehot = ty * chunk * (band + width) * 4
-    scales = (pbatch * 2 * _padded_rows(gs, band, itemsize) * 4
+    from repro.kernels.backproject import strip_window
+
+    wband, wwidth = strip_window(band, width, itemsize)
+    strips = max(pbatch, depth) * wband * wwidth * itemsize
+    tile = 5 * ty * chunk * 4
+    onehot = 2 * chunk * (wband + wwidth) * 4 + wband * wwidth * 4
+    scales = (2 * pbatch * _padded_rows(gs, band, itemsize) * 128 * 4
               if itemsize == 1 else 0)
     smem = pbatch * 3 * 4 * 4
     return VmemEstimate(strip_bytes=strips, tile_bytes=tile,

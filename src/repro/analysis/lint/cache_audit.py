@@ -91,7 +91,7 @@ def audit_tuned_config(gs: GeomStatic, cfg, geom=None) -> list:
 
     Static checks always run (strategy/option-key membership, wire
     dtype, the VMEM byte model); with a full ``geom`` the planner
-    re-validates the jnp window and the Pallas tile/micro/shared-window
+    re-validates the jnp window and the Pallas tile/shared-window
     coverage exactly as the execution wrappers would.
     """
     from repro.tune.cache import _PALLAS_KEYS, _STRATEGY_KEYS
@@ -151,17 +151,10 @@ def audit_tuned_config(gs: GeomStatic, cfg, geom=None) -> list:
         ty, chunk, band, width = clamp_tiles(
             gs, int(pallas.get("ty", 8)), int(pallas.get("chunk", 128)),
             int(pallas.get("band", 16)), int(pallas.get("width", 512)))
-        micro_kw = {}
-        if pallas.get("micro", False):
-            micro_kw = dict(micro=True,
-                            micro_group=int(pallas.get("micro_group", 8)),
-                            micro_band=int(pallas.get("micro_band", 8)),
-                            micro_width=int(pallas.get("micro_width",
-                                                       32)))
         for A in mats:
             try:
                 validate_strip_config(geom, A, ty=ty, chunk=chunk,
-                                      band=band, width=width, **micro_kw)
+                                      band=band, width=width)
             except ValueError as e:
                 reasons.append(
                     f"pallas tile fails the current planner: {e}")

@@ -37,7 +37,7 @@ Invariants proved per replay (each violation is a finding):
   variant promises (``pipeline-under-depth``): a rotation that never
   fills is a silently-degraded pipeline, PR 5's bug class.
 
-The replay space crosses all seven variants with ``db_depth`` ∈
+The replay space crosses all five variants with ``db_depth`` ∈
 {2, 3, 4}, ``pbatch`` ∈ {4, 3} (3 exercises the ``pbatch ∤ n_proj``
 remainder group the batch wrapper dispatches at the tail), and the
 quantized (int8 + scale-sideband) ref layout.
@@ -91,6 +91,8 @@ def _norm(idx):
             out.append(i.key())
         elif i is Ellipsis:
             out.append("...")
+        elif isinstance(i, slice):
+            out.append(("slice", i.start, i.stop))
         else:
             out.append(int(i))
     return tuple(out)
@@ -104,8 +106,8 @@ def _np_index(idx):
     for i in idx:
         if isinstance(i, _DS):
             out.append(i.as_slice())
-        elif i is Ellipsis:
-            out.append(Ellipsis)
+        elif i is Ellipsis or isinstance(i, slice):
+            out.append(i)
         else:
             out.append(int(i))
     return tuple(out)
@@ -256,6 +258,10 @@ class _PLStub:
         return _DS(start, size)
 
     @staticmethod
+    def multiple_of(x, _):
+        return x
+
+    @staticmethod
     def when(cond):
         def deco(f):
             if bool(cond):
@@ -323,11 +329,11 @@ def _patched(modules, pl_stub, pltpu_stub):
 
 # Replay shape: tiny volume, 4 z-planes × 2 y-bands × 1 chunk grid —
 # enough steps to wrap every rotation depth several times while keeping
-# a full-suite replay in seconds.
-_L, _TY, _CHUNK, _BAND, _WIDTH = 8, 4, 8, 8, 128
-_ROWS, _COLS = 32, 256
+# a full-suite replay in seconds.  ``_BAND``/``_WIDTH`` are DMA window
+# dims: tile multiples for every wire (32 rows covers the int8 tile).
+_L, _TY, _CHUNK, _BAND, _WIDTH = 8, 4, 8, 32, 128
+_ROWS, _COLS = 64, 256
 _GRID = (4, 2, 1)
-_MICRO = dict(group=4, gband=8, gwidth=32)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -341,22 +347,20 @@ class ReplayCase:
     """
 
     name: str
-    kind: str                      # single|single_micro|single_db|batch|
-    #                                batch_micro|batch_db|batch_shared
+    kind: str              # single|single_db|batch|batch_db|batch_shared
     pbatch: int = 1
     depth: int = 2
     quantized: bool = False
 
     @property
     def n_slots(self) -> int:
-        return {"single": 1, "single_micro": 1, "single_db": self.depth,
-                "batch": 2, "batch_micro": 2, "batch_db": self.depth,
-                "batch_shared": 1}[self.kind]
+        return {"single": 1, "single_db": self.depth, "batch": 2,
+                "batch_db": self.depth, "batch_shared": 1}[self.kind]
 
     @property
     def promised(self):
         steps = _GRID[0] * _GRID[1] * _GRID[2]
-        if self.kind in ("single", "single_micro"):
+        if self.kind == "single":
             return None            # DMA only under the active flag
         if self.kind == "single_db":
             return min(self.depth, steps)
@@ -371,10 +375,8 @@ def _default_kernel(case: ReplayCase):
     import repro.kernels.backproject as K
 
     return {"single": K.backproject_kernel,
-            "single_micro": K.backproject_kernel_micro,
             "single_db": K.backproject_kernel_db,
             "batch": K.backproject_kernel_batch,
-            "batch_micro": K.backproject_kernel_batch_micro,
             "batch_db": K.backproject_kernel_batch_db,
             "batch_shared": K.backproject_kernel_batch_shared}[case.kind]
 
@@ -406,7 +408,7 @@ def replay(case: ReplayCase, kernel_fn=None, extra_modules=()) -> Ledger:
                             dtype=np.int8)
         scl = np.stack([rng.uniform(0.01, 0.1, (P, _ROWS)),
                         rng.uniform(-1.0, 1.0, (P, _ROWS))],
-                       axis=1).astype(np.float32)     # (P, 2, rows)
+                       axis=-1).astype(np.float32)    # (P, rows, 2)
     else:
         imgs = rng.standard_normal((P, _ROWS, _COLS)).astype(np.float32)
         scl = None
@@ -418,9 +420,6 @@ def replay(case: ReplayCase, kernel_fn=None, extra_modules=()) -> Ledger:
     kwargs = dict(o_mm=(gs.O, gs.MM), n_u=gs.n_u, n_v=gs.n_v, ty=_TY,
                   chunk=_CHUNK, band=_BAND, width=_WIDTH,
                   quantized=case.quantized)
-    if case.kind in ("single_micro", "batch_micro"):
-        kwargs.update(group=_MICRO["group"], gband=_MICRO["gband"],
-                      gwidth=_MICRO["gwidth"])
     if batched:
         kwargs["pbatch"] = P
     if case.kind in ("single_db", "batch_db"):
@@ -439,10 +438,8 @@ def replay(case: ReplayCase, kernel_fn=None, extra_modules=()) -> Ledger:
     # Scratch persists across grid steps — exactly the dimension the
     # rotation ledgers depend on.
     strip_shape = {"single": (_BAND, _WIDTH),
-                   "single_micro": (_BAND, _WIDTH),
                    "single_db": (case.depth, _BAND, _WIDTH),
                    "batch": (2, _BAND, _WIDTH),
-                   "batch_micro": (2, _BAND, _WIDTH),
                    "batch_db": (case.depth, _BAND, _WIDTH),
                    "batch_shared": (P, _BAND, _WIDTH)}[case.kind]
     strip_ref = StubRef(np.zeros(strip_shape, imgs.dtype), "strip")
@@ -470,13 +467,11 @@ def replay(case: ReplayCase, kernel_fn=None, extra_modules=()) -> Ledger:
 
 
 def builtin_cases() -> list:
-    """The full replay space for the repo's seven kernel variants."""
+    """The full replay space for the repo's five kernel variants."""
     cases = [
         ReplayCase("single", "single"),
-        ReplayCase("single_micro", "single_micro"),
         ReplayCase("batch_shared_p4", "batch_shared", pbatch=4),
         ReplayCase("batch_int8_p4", "batch", pbatch=4, quantized=True),
-        ReplayCase("batch_micro_p4", "batch_micro", pbatch=4),
     ]
     for depth in (2, 3, 4):
         cases.append(ReplayCase(f"single_db_d{depth}", "single_db",
@@ -534,8 +529,7 @@ def run_ledger_pass(fixture=None, cases=None) -> PassResult:
         notes.append(f"{case.name}: issues={ledger.issues} "
                      f"waits={ledger.waits} "
                      f"max_in_flight={ledger.max_in_flight}")
-        if ledger.issues == 0 and case.kind not in ("single",
-                                                    "single_micro"):
+        if ledger.issues == 0 and case.kind != "single":
             findings.append(Finding(
                 "ledger", "vacuous-replay", case.name,
                 "replay executed zero DMAs — the case proves nothing"))

@@ -111,6 +111,13 @@ DEFAULT_PBATCH = 4
 
 _EPS_W = 1e-6
 
+# MXU precision of the one-hot interpolation dots.  On a TPU an f32 dot
+# at default precision runs as one bf16 pass, which rounds every tap
+# weight and value to 8 bits: a v5e volume then misses a float64
+# reference by 3.2e-3 of its scale, and by 1.8e-4 at HIGHEST.  (No
+# effect off-TPU: CPU dots are f32 either way.)
+_MXU_PRECISION = jax.lax.Precision.HIGHEST
+
 
 class GeomStatic(NamedTuple):
     """The static scalars a kernel needs (hashable -> jit-static)."""
@@ -246,7 +253,8 @@ def sample_onehot(padded, ix, iy, gs: GeomStatic, *, vox_block: int = 512):
                   + (riota == rr + 1) * syb[:, None])
         colsel = ((ciota == cc) * (1.0 - sxb[:, None])
                   + (ciota == cc + 1) * sxb[:, None])
-        rowmix = rowsel.astype(padded.dtype) @ padded     # (vb, W)
+        rowmix = jnp.matmul(rowsel.astype(padded.dtype), padded,
+                            precision=_MXU_PRECISION)      # (vb, W)
         return jnp.sum(rowmix * colsel, axis=-1)
 
     vals = jax.lax.map(block, (iixf, iiyf, sxf, syf))
@@ -345,7 +353,8 @@ def sample_strip(padded, ix, iy, gs: GeomStatic, *, chunk: int = 128,
             colsel = ((wiota == creli[:, None]) * (1.0 - sxi[:, None])
                       + (wiota == creli[:, None] + 1) * sxi[:, None])
             if wire is None:
-                rowmix = rowsel.astype(padded.dtype) @ strip
+                rowmix = jnp.matmul(rowsel.astype(padded.dtype), strip,
+                                    precision=_MXU_PRECISION)
             else:
                 if quant is not None:   # int8: dequant after the gather
                     scl = jax.lax.dynamic_slice(quant.scale, (r0i,),
@@ -357,7 +366,7 @@ def sample_strip(padded, ix, iy, gs: GeomStatic, *, chunk: int = 128,
                 # f32 weights x (bf16 | dequantised) strip -> f32
                 rowmix = jax.lax.dot_general(
                     rowsel, strip.astype(jnp.float32),
-                    (((1,), (0,)), ((), ())),
+                    (((1,), (0,)), ((), ())), precision=_MXU_PRECISION,
                     preferred_element_type=jnp.float32)
             return jnp.sum(rowmix * colsel, axis=-1)       # (chunk, width)
 
@@ -436,7 +445,8 @@ def sample_strip2(padded, ix, iy, gs: GeomStatic, *, group: int = 8,
             colsel = ((wiota == creli[:, None]) * (1.0 - sxi[:, None])
                       + (wiota == creli[:, None] + 1) * sxi[:, None])
             if wire is None:
-                rowmix = rowsel.astype(padded.dtype) @ win
+                rowmix = jnp.matmul(rowsel.astype(padded.dtype), win,
+                                    precision=_MXU_PRECISION)
             else:
                 if quant is not None:   # int8: dequant after the gather
                     scl = jax.lax.dynamic_slice(quant.scale, (r0i,),
@@ -448,7 +458,7 @@ def sample_strip2(padded, ix, iy, gs: GeomStatic, *, group: int = 8,
                 # f32 weights x (bf16 | dequantised) window -> f32
                 rowmix = jax.lax.dot_general(
                     rowsel, win.astype(jnp.float32),
-                    (((1,), (0,)), ((), ())),
+                    (((1,), (0,)), ((), ())), precision=_MXU_PRECISION,
                     preferred_element_type=jnp.float32)
             return jnp.sum(rowmix * colsel, axis=-1)       # (group, gwidth)
 

@@ -10,19 +10,19 @@ mechanisms the x86 kernels could only approximate:
    the minimal ``(band, width)`` strip.  One DMA descriptor replaces
    ``4 * TY * CHUNK`` scattered loads: this is the pairwise-load idea at
    DMA granularity.
-2. **MXU as texture unit** — the vertical interpolation is a banded
-   one-hot matmul ``rowsel(P, band) @ strip(band, width)`` on the MXU; the
-   horizontal 2-tap selection runs as iota-compare/select on the VPU.
-   Out-of-band one-hot rows are identically zero, which (with the 1-pixel
-   zero border added by ops.py) gives exact zero-outside-detector
-   semantics with *no* per-tap conditionals — the paper's zero-padded
-   buffer trick (section 5.1.1).
+2. **MXU as texture unit** — per tile row the horizontal interpolation is
+   a one-hot matmul ``strip(band, width) @ colsel(width, CHUNK)`` on the
+   MXU; the vertical 2-tap blend runs as iota-compare/select plus a
+   sublane reduction on the VPU.  Voxels stay on the lanes throughout, so
+   no step needs a relayout.  Out-of-window one-hot entries are
+   identically zero, which (with the 1-pixel zero border added by ops.py)
+   gives exact zero-outside-detector semantics with *no* per-tap
+   conditionals — the paper's zero-padded buffer trick (section 5.1.1).
 3. **Grid pipelining instead of SMT** — KNC needed 4-way SMT to hide
    gather latency and still failed (section 6.4); here the volume-tile
    loads/stores are pipelined by the Pallas grid machinery, and the strip
    DMA for step ``k+1`` can be issued during step ``k``'s compute
-   (double-buffered variant, ``double_buffer=True`` — hillclimb CT-2 in
-   EXPERIMENTS.md).
+   (double-buffered variant, ``double_buffer=True``).
 
 Semantics are identical to ``repro.core.backproject.sample_scalar`` +
 ``accumulate`` (floor bilinear, zero outside, ``1/w^2`` weighting), which
@@ -30,9 +30,10 @@ is the oracle in ``backproject_ref.py``; correctness requires
 ``band``/``width`` to cover each tile's footprint (guaranteed by the
 host-side planner in ``repro.core.clipping`` — ops.py checks it).
 
-VMEM budget per step (defaults TY=8, CHUNK=128, band=16, width=512, f32):
-strip 32 KB (x2 when double-buffered) + rowmix 2 MB + volume tile 4 KB —
-comfortably inside 16 MB, leaving the pipeline room to prefetch.
+The DMA engine slices HBM only at ``(sublane, 128)`` tile boundaries, so
+every strip origin is aligned *down* to a tile and the window the kernel
+moves is one tile larger per axis than the footprint the planner
+validated (:func:`strip_window`).
 """
 
 from __future__ import annotations
@@ -45,11 +46,38 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["backproject_kernel", "backproject_kernel_batch",
-           "backproject_kernel_batch_db", "backproject_kernel_batch_micro",
-           "backproject_kernel_batch_shared",
-           "backproject_volume_pallas", "backproject_volume_pallas_batch"]
+           "backproject_kernel_batch_db", "backproject_kernel_batch_shared",
+           "backproject_kernel_db", "backproject_volume_pallas",
+           "backproject_volume_pallas_batch", "strip_window", "SUBLANE"]
 
 _EPS_W = 1e-6
+_LANE = 128
+
+# Rows per HBM/VMEM tile for each strip wire itemsize (f32 8, bf16 16,
+# int8 32): strip origins and padded row counts are multiples of this.
+SUBLANE = {1: 32, 2: 16, 4: 8}
+
+# MXU precision of the one-hot interpolation dot, as
+# ``repro.core.backproject._MXU_PRECISION``: at Mosaic's default a TPU
+# v5e volume misses a float64 reference by 3.2e-3 of its scale, at
+# HIGHEST by 1.8e-4.
+_PRECISION = jax.lax.Precision.HIGHEST
+
+
+def strip_window(band: int, width: int, itemsize: int) -> tuple[int, int]:
+    """DMA window dims that hold a ``(band, width)`` footprint.
+
+    Strip origins are aligned down to the ``(SUBLANE[itemsize], 128)``
+    tile, which moves the footprint up to one tile into the window, so
+    the window is the footprint rounded up to the tile plus one tile.
+    """
+    sub = SUBLANE[itemsize]
+    return (band + (-band) % sub + sub,
+            width + (-width) % _LANE + _LANE)
+
+
+def _row_tile(ref) -> int:
+    return SUBLANE[jnp.dtype(ref.dtype).itemsize]
 
 
 def _read_A(A_ref, p=None):
@@ -68,11 +96,14 @@ def _read_A(A_ref, p=None):
 def _part1_tile(A, o_mm, z, y0, x0, ty, chunk):
     """Part 1 on the VPU: ICS coords for a (ty, chunk) voxel tile.
 
-    ``A`` is the nested scalar tuple from :func:`_read_A`.
+    ``A`` is the nested scalar tuple from :func:`_read_A`.  Mosaic only
+    builds integer iotas, so the voxel offsets are cast after the iota.
     """
     O, MM = o_mm
-    ys = (y0 + jax.lax.broadcasted_iota(jnp.float32, (ty, chunk), 0))
-    xs = (x0 + jax.lax.broadcasted_iota(jnp.float32, (ty, chunk), 1))
+    ys = y0 + jax.lax.broadcasted_iota(jnp.int32, (ty, chunk), 0).astype(
+        jnp.float32)
+    xs = x0 + jax.lax.broadcasted_iota(jnp.int32, (ty, chunk), 1).astype(
+        jnp.float32)
     wx = O + xs * MM
     wy = O + ys * MM
     wz = O + z.astype(jnp.float32) * MM
@@ -84,18 +115,20 @@ def _part1_tile(A, o_mm, z, y0, x0, ty, chunk):
 
 
 def _strip_origin(A, o_mm, z, y0, x0, *, n_u, n_v, ty, chunk, band, width,
-                  pad_rows, pad_cols):
-    """Strip origin for a (ty, chunk) tile from its four *corner* voxels.
+                  pad_rows, pad_cols, row_tile):
+    """Tile-aligned strip origin for a (ty, chunk) tile from its four
+    *corner* voxels.
 
-    The cheap origin-only geometry: ``w`` is affine over the tile, so its
-    minimum sits at a corner, and where ``w > 0`` both detector
-    coordinates are monotone along each voxel axis — the tile extremes of
-    ``ix``/``iy`` are corner values.  Twelve scalar FMAs per corner
-    replace the full ``(ty, chunk)`` Part-1 pass the double-buffered
-    kernel used to run just to obtain a prefetch address.  Matches the
-    full-tile ``min`` exactly whenever ``w > eps`` across the tile (every
-    sane cone-beam geometry); prefetch and compute always agree because
-    both sides call this one helper.
+    ``w`` is affine over the tile, so its minimum sits at a corner, and
+    where ``w > 0`` both detector coordinates are monotone along each
+    voxel axis — the tile extremes of ``ix``/``iy`` are corner values.
+    Twelve scalar FMAs per corner replace a full ``(ty, chunk)`` Part-1
+    pass; prefetch and compute always agree because both sides call this
+    one helper.  The origin is clamped so the ``(band, width)`` window
+    stays in the padded image, then aligned down to the
+    ``(row_tile, 128)`` DMA tile (``pad_rows``/``band`` and
+    ``pad_cols``/``width`` are tile multiples, so the clamp survives the
+    alignment).
     """
     O, MM = o_mm
     wz = O + z.astype(jnp.float32) * MM
@@ -114,21 +147,9 @@ def _strip_origin(A, o_mm, z, y0, x0, *, n_u, n_v, ty, chunk, band, width,
             r_lo = iy if r_lo is None else jnp.minimum(r_lo, iy)
     r0 = jnp.clip(jnp.floor(r_lo).astype(jnp.int32), 0, pad_rows - band)
     c0 = jnp.clip(jnp.floor(c_lo).astype(jnp.int32), 0, pad_cols - width)
+    r0 = pl.multiple_of(jax.lax.div(r0, row_tile) * row_tile, row_tile)
+    c0 = pl.multiple_of(jax.lax.div(c0, _LANE) * _LANE, _LANE)
     return r0, c0
-
-
-def _tile_geometry(A, o_mm, z, y0, x0, *, n_u, n_v, ty, chunk, band,
-                   width, pad_rows, pad_cols):
-    """Part 1 + strip origin + activity flag for one (ty, chunk) tile."""
-    ix, iy, w, r = _part1_tile(A, o_mm, z, y0, x0, ty, chunk)
-    ix_c = jnp.clip(ix, -1.0, jnp.float32(n_u))
-    iy_c = jnp.clip(iy, -1.0, jnp.float32(n_v))
-    r0 = jnp.clip(jnp.floor(jnp.min(iy_c)).astype(jnp.int32),
-                  0, pad_rows - band)
-    c0 = jnp.clip(jnp.floor(jnp.min(ix_c)).astype(jnp.int32),
-                  0, pad_cols - width)
-    active = _tile_active(ix, iy, w, n_u, n_v)
-    return ix, iy, w, r, r0, c0, active
 
 
 def _tile_active(ix, iy, w, n_u, n_v):
@@ -142,37 +163,41 @@ def _dequant_strip(strip, scl_ref, r0, band, p=None):
     """Decode an int8 code strip in-register, next to the accumulator.
 
     ``scl_ref`` is the per-detector-row scale block, VMEM-resident for
-    the whole kernel: ``scl_ref[0] = scale``, ``scl_ref[1] = offset``
-    per padded row (stacked ``(P, 2, rows)`` in the batch kernels,
-    indexed by ``p``), so ``value = code * scale[row] + offset[row]``.
-    ``scl_ref=None`` means the wire is not quantised and the strip
-    passes through untouched — every variant calls this unconditionally
-    and the f32 path traces to a no-op.  Dequantisation happens *here*,
-    after the DMA: only 1-byte codes ever move on the strip wire, and
-    only the resident ``(band, width)`` window widens to f32.
+    the whole kernel: ``scl_ref[row] = (scale, offset)`` per padded row
+    (stacked ``(P, rows, 2)`` in the batch kernels, indexed by ``p``), so
+    ``value = code * scale[row] + offset[row]``.  Rows sit on sublanes,
+    so the window's scales load with the strip's own (tile-aligned) row
+    offset and broadcast across its lanes.  ``scl_ref=None`` means the
+    wire is not quantised and the strip passes through untouched — every
+    variant calls this unconditionally and the f32 path traces to a
+    no-op.  Only 1-byte codes ever move on the strip wire, and only the
+    resident ``(band, width)`` window widens to f32.
     """
     if scl_ref is None:
         return strip
     if p is None:
-        scl = scl_ref[0, pl.ds(r0, band)]
-        off = scl_ref[1, pl.ds(r0, band)]
+        sc = scl_ref[pl.ds(r0, band), :]
     else:
-        scl = scl_ref[p, 0, pl.ds(r0, band)]
-        off = scl_ref[p, 1, pl.ds(r0, band)]
-    return strip.astype(jnp.float32) * scl[:, None] + off[:, None]
+        sc = scl_ref[p, pl.ds(r0, band), :]
+    return strip.astype(jnp.float32) * sc[:, 0:1] + sc[:, 1:2]
 
 
 def _tile_contrib(get_strip, ix, iy, r, r0, c0, *, ty, chunk, band, width):
-    """Parts 2+3 for one tile against a resident (band, width) strip.
+    """Parts 2+3 for one (ty, chunk) tile against a resident
+    ``(band, width)`` strip at padded origin ``(r0, c0)``.
 
-    Banded one-hot vertical interpolation on the MXU, 2-tap horizontal
-    blend on the VPU, ``1/w²`` weighting folded in.  Taps outside the
-    strip select all-zero one-hot rows and vanish — with the zero border
-    this is the exact zero-outside-detector semantics.  Returns the f32
-    ``(ty, chunk)`` contribution.
+    ``ix``/``iy``/``r`` are the tile's Part-1 arrays.  Row by row, with
+    the row's ``chunk`` voxels on the lanes: the horizontal 2-tap
+    selector ``colsel (width, chunk)`` is built by iota-compare, the MXU
+    contracts it with the strip into ``(band, chunk)``, and the vertical
+    2-tap selector ``rowsel (band, chunk)`` blends that with a sublane
+    reduction.  Taps outside the strip select all-zero one-hot entries
+    and vanish — with the zero border this is the exact
+    zero-outside-detector semantics.  The ``1/w²`` weight is folded in;
+    returns the f32 ``(ty, chunk)`` contribution.
 
     ``get_strip`` is a zero-arg callable (wait on the strip DMA, read the
-    scratch) invoked only once the one-hot selectors are built, so the
+    scratch) invoked once the first row's selectors are built, so the
     copy overlaps the selector arithmetic.
     """
     fx = jnp.floor(ix)
@@ -180,27 +205,29 @@ def _tile_contrib(get_strip, ix, iy, r, r0, c0, *, ty, chunk, band, width):
     sx = ix - fx
     sy = iy - fy
     # Padded-relative tap coordinates (+1: pad offset).
-    rel_r = fy.astype(jnp.int32) + 1 - r0
     rel_c = fx.astype(jnp.int32) + 1 - c0
-
-    p = ty * chunk
-    rel_r_f = rel_r.reshape(p, 1)
-    rel_c_f = rel_c.reshape(p, 1)
-    sy_f = sy.reshape(p, 1)
-    sx_f = sx.reshape(p, 1)
-
-    biota = jax.lax.broadcasted_iota(jnp.int32, (p, band), 1)
-    wiota = jax.lax.broadcasted_iota(jnp.int32, (p, width), 1)
-    rowsel = ((biota == rel_r_f).astype(jnp.float32) * (1.0 - sy_f)
-              + (biota == rel_r_f + 1).astype(jnp.float32) * sy_f)
-    colsel = ((wiota == rel_c_f).astype(jnp.float32) * (1.0 - sx_f)
-              + (wiota == rel_c_f + 1).astype(jnp.float32) * sx_f)
-    # MXU: vertical interpolation for the whole tile at once.
-    rowmix = jax.lax.dot_general(
-        rowsel, get_strip().astype(jnp.float32), (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)                # (p, width)
-    val = jnp.sum(rowmix * colsel, axis=1)                 # VPU 2-tap blend
-    return val.reshape(ty, chunk) * (r * r)
+    rel_r = fy.astype(jnp.int32) + 1 - r0
+    rw2 = r * r
+    biota = jax.lax.broadcasted_iota(jnp.int32, (band, chunk), 0)
+    wiota = jax.lax.broadcasted_iota(jnp.int32, (width, chunk), 0)
+    yiota = jax.lax.broadcasted_iota(jnp.int32, (ty, chunk), 0)
+    strip = None
+    out = jnp.zeros((ty, chunk), jnp.float32)
+    for y in range(ty):
+        row = slice(y, y + 1)
+        colsel = jnp.where(wiota == rel_c[row], 1.0 - sx[row],
+                           jnp.where(wiota == rel_c[row] + 1, sx[row], 0.0))
+        rowsel = jnp.where(biota == rel_r[row], 1.0 - sy[row],
+                           jnp.where(biota == rel_r[row] + 1, sy[row], 0.0))
+        if strip is None:
+            strip = get_strip().astype(jnp.float32)
+        # MXU: horizontal interpolation of every strip row at once.
+        colmix = jax.lax.dot_general(
+            strip, colsel, (((1,), (0,)), ((), ())), precision=_PRECISION,
+            preferred_element_type=jnp.float32)            # (band, chunk)
+        val = jnp.sum(colmix * rowsel, axis=0, keepdims=True) * rw2[row]
+        out = jnp.where(yiota == y, val, out)
+    return out
 
 
 def backproject_kernel(A_ref, img_ref, *refs,
@@ -210,10 +237,11 @@ def backproject_kernel(A_ref, img_ref, *refs,
     volume tile.
 
     Refs: ``A_ref`` (3,4) f32 in SMEM; ``img_ref`` zero-padded projection
-    in ANY/HBM; with ``quantized=True`` a ``(2, rows)`` per-row scale
+    in ANY/HBM; with ``quantized=True`` a ``(rows, 2)`` per-row scale
     block in VMEM follows (``img_ref`` then holds int8 codes); then the
     aliased ``vol_in/out`` volume tile in VMEM, ``strip_ref`` VMEM
-    scratch, ``sem`` DMA semaphore.
+    scratch, ``sem`` DMA semaphore.  ``band``/``width`` are the DMA
+    window dims (:func:`strip_window`).
     """
     scl_ref = None
     if quantized:
@@ -222,11 +250,14 @@ def backproject_kernel(A_ref, img_ref, *refs,
     z = pl.program_id(0)
     y0 = (pl.program_id(1) * ty).astype(jnp.float32)
     x0 = (pl.program_id(2) * chunk).astype(jnp.float32)
+    A = _read_A(A_ref)
 
-    ix, iy, w, r, r0, c0, active = _tile_geometry(
-        _read_A(A_ref), o_mm, z, y0, x0, n_u=n_u, n_v=n_v, ty=ty,
-        chunk=chunk, band=band, width=width, pad_rows=img_ref.shape[0],
-        pad_cols=img_ref.shape[1])
+    ix, iy, w, r = _part1_tile(A, o_mm, z, y0, x0, ty, chunk)
+    active = _tile_active(ix, iy, w, n_u, n_v)
+    r0, c0 = _strip_origin(
+        A, o_mm, z, y0, x0, n_u=n_u, n_v=n_v, ty=ty, chunk=chunk,
+        band=band, width=width, pad_rows=img_ref.shape[0],
+        pad_cols=img_ref.shape[1], row_tile=_row_tile(img_ref))
 
     @pl.when(active)
     def _():
@@ -239,134 +270,11 @@ def backproject_kernel(A_ref, img_ref, *refs,
             copy.wait()
             return _dequant_strip(strip_ref[...], scl_ref, r0, band)
 
-        contrib = _tile_contrib(strip, ix, iy, r, r0, c0,
-                                ty=ty, chunk=chunk, band=band, width=width)
+        contrib = _tile_contrib(strip, ix, iy, r, r0, c0, ty=ty,
+                                chunk=chunk, band=band, width=width)
         # --- Part 3: inverse-square-law weighted accumulate -------------
         vol_out_ref[...] = vol_in_ref[...] + contrib.astype(
             vol_in_ref.dtype)[None]
-
-    @pl.when(jnp.logical_not(active))
-    def _():
-        vol_out_ref[...] = vol_in_ref[...]
-
-
-def _micro_tile_accumulate(wait_strip, read_window, update, ix, iy, r, *,
-                           r0, c0, ty, chunk, band, width, group, gband,
-                           gwidth):
-    """Parts 2+3 per ``group``-voxel micro-window against a resident
-    strip — the one implementation the single-projection micro kernel and
-    the batched micro variant share, so the planner-validated
-    ``(micro_band, micro_width)`` window semantics exist exactly once.
-
-    ``wait_strip`` blocks on the strip DMA (called once the per-voxel tap
-    coordinates are built, so the copy overlaps the selector
-    arithmetic); ``read_window(r0g, c0g)`` returns the ``(gband,
-    gwidth)`` sub-window at an in-strip origin; ``update(row, col,
-    val)`` folds one group's ``(group,)`` f32 contribution into the
-    accumulation target at tile row ``row``, columns ``[col, col +
-    group)``.
-    """
-    fx = jnp.floor(ix)
-    fy = jnp.floor(iy)
-    sx = (ix - fx).reshape(ty * chunk)
-    sy = (iy - fy).reshape(ty * chunk)
-    rel_r = (fy.astype(jnp.int32) + 1 - r0).reshape(ty * chunk)
-    rel_c = (fx.astype(jnp.int32) + 1 - c0).reshape(ty * chunk)
-    rw2 = (r * r).reshape(ty * chunk)
-
-    wait_strip()
-    n_groups = (ty * chunk) // group
-    cols_per_row = chunk // group
-
-    biota = jax.lax.broadcasted_iota(jnp.int32, (group, gband), 1)
-    wiota = jax.lax.broadcasted_iota(jnp.int32, (group, gwidth), 1)
-
-    def one_group(g, _):
-        gs_ = g * group
-        rr = jax.lax.dynamic_slice(rel_r, (gs_,), (group,))
-        cc = jax.lax.dynamic_slice(rel_c, (gs_,), (group,))
-        sxg = jax.lax.dynamic_slice(sx, (gs_,), (group,))
-        syg = jax.lax.dynamic_slice(sy, (gs_,), (group,))
-        wg = jax.lax.dynamic_slice(rw2, (gs_,), (group,))
-        # Window origin from the *in-strip* tap positions only (far
-        # out-of-detector voxels would otherwise drag the window off
-        # the contributing taps; their own one-hots are zero either
-        # way).
-        r0g = jnp.clip(jnp.min(jnp.clip(rr, 0, band - 1)),
-                       0, band - gband)
-        c0g = jnp.clip(jnp.min(jnp.clip(cc, 0, width - 1)),
-                       0, width - gwidth)
-        win = read_window(r0g, c0g)
-        rowsel = ((biota == (rr - r0g)[:, None]).astype(jnp.float32)
-                  * (1.0 - syg[:, None])
-                  + (biota == (rr - r0g)[:, None] + 1).astype(
-                      jnp.float32) * syg[:, None])
-        colsel = ((wiota == (cc - c0g)[:, None]).astype(jnp.float32)
-                  * (1.0 - sxg[:, None])
-                  + (wiota == (cc - c0g)[:, None] + 1).astype(
-                      jnp.float32) * sxg[:, None])
-        mix = jax.lax.dot_general(
-            rowsel, win.astype(jnp.float32),
-            (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)          # (group, gwidth)
-        val = jnp.sum(mix * colsel, axis=1) * wg
-        update(gs_ // chunk, (g % cols_per_row) * group, val)
-        return 0
-
-    jax.lax.fori_loop(0, n_groups, one_group, 0)
-
-
-def backproject_kernel_micro(A_ref, img_ref, *refs,
-                             o_mm, n_u, n_v, ty, chunk, band, width,
-                             group, gband, gwidth, quantized=False):
-    """Micro-window variant (hillclimb CT-5): strip DMA as usual, but the
-    tap selection runs per ``group``-voxel micro-window instead of one
-    tile-wide banded matmul.
-
-    The plain kernel's rowsel matmul costs ``2 * band * width`` flops per
-    voxel (16k at production size) because every voxel's one-hot row
-    spans the whole strip.  Within a group of 8 consecutive voxels the
-    taps span only ~``group * du`` columns and ~2 rows, so a
-    ``(gband, gwidth)`` VMEM sub-slice + tiny selects bring it down to
-    ``~2 * gband * gwidth`` (256) flops per voxel — the same napkin math
-    as the jnp ``strip2`` strategy, now at kernel level where the strip
-    load is a DMA rather than an XLA gather.
-    """
-    scl_ref = None
-    if quantized:
-        scl_ref, *refs = refs
-    vol_in_ref, vol_out_ref, strip_ref, sem = refs
-    z = pl.program_id(0)
-    y0 = (pl.program_id(1) * ty).astype(jnp.float32)
-    x0 = (pl.program_id(2) * chunk).astype(jnp.float32)
-
-    ix, iy, w, r, r0, c0, active = _tile_geometry(
-        _read_A(A_ref), o_mm, z, y0, x0, n_u=n_u, n_v=n_v, ty=ty,
-        chunk=chunk, band=band, width=width, pad_rows=img_ref.shape[0],
-        pad_cols=img_ref.shape[1])
-
-    @pl.when(active)
-    def _():
-        copy = pltpu.make_async_copy(
-            img_ref.at[pl.ds(r0, band), pl.ds(c0, width)], strip_ref,
-            sem)
-        copy.start()
-
-        def update(row, col, val):
-            cur = vol_in_ref[0, row, pl.ds(col, group)]
-            vol_out_ref[0, row, pl.ds(col, group)] = \
-                cur + val.astype(vol_in_ref.dtype)
-
-        _micro_tile_accumulate(
-            copy.wait,
-            # Dequant per micro-window at its *global* row origin
-            # r0 + r0g — the scale block indexes padded detector rows.
-            lambda r0g, c0g: _dequant_strip(
-                strip_ref[pl.ds(r0g, gband), pl.ds(c0g, gwidth)],
-                scl_ref, r0 + r0g, gband),
-            update, ix, iy, r, r0=r0, c0=c0, ty=ty, chunk=chunk,
-            band=band, width=width, group=group, gband=gband,
-            gwidth=gwidth)
 
     @pl.when(jnp.logical_not(active))
     def _():
@@ -377,25 +285,20 @@ def backproject_kernel_db(A_ref, img_ref, *refs,
                           o_mm, n_u, n_v, ty, chunk, band, width,
                           grid_dims, depth=2, quantized=False):
     """Double-buffered variant: the strip DMA for grid step ``k+1`` is
-    issued before step ``k``'s compute (hillclimb CT-3), generalised to
-    a ``depth``-slot rotation running ``depth - 1`` fetches ahead.
+    issued before step ``k``'s compute, generalised to a ``depth``-slot
+    rotation running ``depth - 1`` fetches ahead.
 
     KNC had no usable gather prefetch (the paper found
     ``vgatherpf0dps`` blocking and scalar prefetch too expensive,
     section 6.4); on TPU the strip origin is *computed* geometry, so
     future tiles' DMAs can be launched any number of steps ahead into a
-    ``(depth, band, width)`` scratch — compute and DMA overlap with
-    zero extra instructions on the critical path.  Step 0 primes the
-    first ``depth - 1`` fetches; step ``k`` then issues the fetch for
-    step ``k + depth - 1`` (whose slot was drained at step ``k - 1``)
-    and waits on its own — the same rotation ledger as the batched
+    ``(depth, band, width)`` scratch.  Step 0 primes the first
+    ``depth - 1`` fetches; step ``k`` then issues the fetch for step
+    ``k + depth - 1`` (whose slot was drained at step ``k - 1``) and
+    waits on its own — the same rotation ledger as the batched
     :func:`backproject_kernel_batch_db` at ``pbatch = 1``, so a tuned
-    ``db_depth`` means one thing on both paths.
-
-    Both the prefetch *and* this step's own strip address come from the
-    corner-based :func:`_strip_origin` (the full Part-1 pass previously
-    rerun per prefetch computed ``ix/iy/w/r`` for the whole next tile
-    just to floor two minima), so producer and consumer agree by
+    ``db_depth`` means one thing on both paths.  Producer and consumer
+    both take the origin from :func:`_strip_origin`, so they agree by
     construction.
     """
     scl_ref = None
@@ -409,9 +312,12 @@ def backproject_kernel_db(A_ref, img_ref, *refs,
     step = (z * ny + yb) * nc + cb
     total = nz * ny * nc
     slot = jax.lax.rem(step, depth)
+    y0 = (yb * ty).astype(jnp.float32)
+    x0 = (cb * chunk).astype(jnp.float32)
 
     pad_rows = img_ref.shape[0]
     pad_cols = img_ref.shape[1]
+    row_tile = _row_tile(img_ref)
     A = _read_A(A_ref)
 
     def origin(zi, yi, ci):
@@ -419,7 +325,7 @@ def backproject_kernel_db(A_ref, img_ref, *refs,
             A, o_mm, zi, (yi * ty).astype(jnp.float32),
             (ci * chunk).astype(jnp.float32), n_u=n_u, n_v=n_v, ty=ty,
             chunk=chunk, band=band, width=width, pad_rows=pad_rows,
-            pad_cols=pad_cols)
+            pad_cols=pad_cols, row_tile=row_tile)
 
     def start_dma(t):
         cn = jax.lax.rem(t, nc)
@@ -432,8 +338,7 @@ def backproject_kernel_db(A_ref, img_ref, *refs,
             img_ref.at[pl.ds(r0n, band), pl.ds(c0n, width)],
             strip_ref.at[s], sems.at[s]).start()
 
-    ix, iy, w, r = _part1_tile(A, o_mm, z, (yb * ty).astype(jnp.float32),
-                               (cb * chunk).astype(jnp.float32), ty, chunk)
+    ix, iy, w, r = _part1_tile(A, o_mm, z, y0, x0, ty, chunk)
     active = _tile_active(ix, iy, w, n_u, n_v)
     r0, c0 = origin(z, yb, cb)
 
@@ -459,8 +364,8 @@ def backproject_kernel_db(A_ref, img_ref, *refs,
             wait_strip()
             return _dequant_strip(strip_ref[slot], scl_ref, r0, band)
 
-        contrib = _tile_contrib(strip, ix, iy, r, r0, c0,
-                                ty=ty, chunk=chunk, band=band, width=width)
+        contrib = _tile_contrib(strip, ix, iy, r, r0, c0, ty=ty,
+                                chunk=chunk, band=band, width=width)
         vol_out_ref[...] = vol_in_ref[...] + contrib.astype(
             vol_in_ref.dtype)[None]
 
@@ -472,44 +377,61 @@ def backproject_kernel_db(A_ref, img_ref, *refs,
         vol_out_ref[...] = vol_in_ref[...]
 
 
-def _batch_strip_loop(A_ref, imgs_ref, strip_ref, sems, consume, *,
-                      o_mm, n_u, n_v, ty, chunk, band, width, pbatch,
-                      z, y0, x0):
-    """The per-projection strip pipeline the plain and micro batch
-    kernels share — one DMA ledger, two compute schemes.
+def backproject_kernel_batch(A_ref, imgs_ref, *refs,
+                             o_mm, n_u, n_v, ty, chunk, band, width,
+                             pbatch, quantized=False):
+    """Projection-batched grid step: the ``(1, ty, chunk)`` volume tile
+    stays resident in VMEM while an in-kernel ``fori_loop`` folds in
+    ``pbatch`` projections — the inverted loop nest (DESIGN.md §7).
 
-    Per in-kernel projection ``p``: projection ``p+1``'s strip (address
-    from the corner-based :func:`_strip_origin`) is prefetched into the
-    other half of a 2-slot rotation while ``p``'s contribution computes
-    — the CT-3 trick applied where it pays most.  Every projection's
+    Refs: ``A_ref`` stacked ``(pbatch, 3, 4)`` f32 in SMEM; ``imgs_ref``
+    stacked zero-padded projections ``(pbatch, rows, cols)`` in ANY/HBM;
+    with ``quantized=True`` a ``(pbatch, rows, 2)`` scale block in VMEM
+    follows (``imgs_ref`` then holds int8 codes); then the aliased
+    ``vol_in/out`` volume tile, ``strip_ref`` ``(2, band, width)`` VMEM
+    scratch, ``acc_ref`` ``(ty, chunk)`` f32 accumulator, ``sems`` 2
+    DMA semaphores.
+
+    The volume tile is loaded once and stored once per ``pbatch``
+    projections — volume HBM traffic drops by the batch factor versus
+    the per-projection kernels.  Per in-kernel projection ``p``,
+    projection ``p+1``'s strip (address from the corner-based
+    :func:`_strip_origin`) is prefetched into the other half of a 2-slot
+    rotation while ``p``'s contribution computes.  Every projection's
     strip is DMA'd and waited unconditionally (clamped origins are
     always in-bounds) so the semaphores balance; off-detector
-    projections contribute zero through the all-zero one-hot rows and
-    the ``r²`` mask.  ``consume(p, slot, wait_strip, ix, iy, r, r0,
-    c0)`` runs under the active flag and folds projection ``p``'s
-    contribution into the caller's accumulator (calling ``wait_strip``
-    once its selectors are built, so the copy overlaps them; ``p`` lets
-    the int8 consumers pick projection ``p``'s scale rows).
+    projections contribute zero through the all-zero one-hot entries
+    and the ``r²`` mask.
     """
+    scl_ref = None
+    if quantized:
+        scl_ref, *refs = refs
+    vol_in_ref, vol_out_ref, strip_ref, acc_ref, sems = refs
+    z = pl.program_id(0)
+    y0 = (pl.program_id(1) * ty).astype(jnp.float32)
+    x0 = (pl.program_id(2) * chunk).astype(jnp.float32)
     pad_rows = imgs_ref.shape[1]
     pad_cols = imgs_ref.shape[2]
+    row_tile = _row_tile(imgs_ref)
 
     def origin(p):
         return _strip_origin(
             _read_A(A_ref, p), o_mm, z, y0, x0, n_u=n_u, n_v=n_v, ty=ty,
             chunk=chunk, band=band, width=width, pad_rows=pad_rows,
-            pad_cols=pad_cols)
+            pad_cols=pad_cols, row_tile=row_tile)
 
     def start_dma(p, r0, c0, slot):
         pltpu.make_async_copy(
             imgs_ref.at[p, pl.ds(r0, band), pl.ds(c0, width)],
             strip_ref.at[slot], sems.at[slot]).start()
 
-    r0_first, c0_first = origin(0)
-    start_dma(0, r0_first, c0_first, 0)
+    acc_ref[...] = vol_in_ref[0].astype(jnp.float32)
+    start_dma(0, *origin(0), 0)
 
-    def body(p, carry):
-        r0, c0 = carry                 # projection p's strip (in flight)
+    def body(p, _):
+        # Projection p's strip is in flight; the wait recomputes the
+        # origin its issuer used.
+        r0, c0 = origin(p)
         slot = jax.lax.rem(p, 2)
 
         # Prefetch projection p+1's strip into the other slot while p's
@@ -523,8 +445,8 @@ def _batch_strip_loop(A_ref, imgs_ref, strip_ref, sems, consume, *,
         def _():
             start_dma(pn, r0n, c0n, 1 - slot)
 
-        ix, iy, w, r = _part1_tile(_read_A(A_ref, p), o_mm, z, y0, x0,
-                                   ty, chunk)
+        A = _read_A(A_ref, p)
+        ix, iy, w, r = _part1_tile(A, o_mm, z, y0, x0, ty, chunk)
         active = _tile_active(ix, iy, w, n_u, n_v)
 
         def wait_strip():
@@ -534,60 +456,22 @@ def _batch_strip_loop(A_ref, imgs_ref, strip_ref, sems, consume, *,
 
         @pl.when(active)
         def _():
-            consume(p, slot, wait_strip, ix, iy, r, r0, c0)
+            def strip():
+                wait_strip()
+                return _dequant_strip(strip_ref[slot], scl_ref, r0, band,
+                                      p)
+
+            acc_ref[...] += _tile_contrib(
+                strip, ix, iy, r, r0, c0, ty=ty, chunk=chunk, band=band,
+                width=width)
 
         @pl.when(jnp.logical_not(active))
         def _():
             wait_strip()               # balance the unconditional DMA
 
-        return (r0n, c0n)
+        return 0
 
-    jax.lax.fori_loop(0, pbatch, body, (r0_first, c0_first))
-
-
-def backproject_kernel_batch(A_ref, imgs_ref, *refs,
-                             o_mm, n_u, n_v, ty, chunk, band, width,
-                             pbatch, quantized=False):
-    """Projection-batched grid step: the ``(1, ty, chunk)`` volume tile
-    stays resident in VMEM while an in-kernel ``fori_loop`` folds in
-    ``pbatch`` projections — the inverted loop nest (DESIGN.md §7).
-
-    Refs: ``A_ref`` stacked ``(pbatch, 3, 4)`` f32 in SMEM; ``imgs_ref``
-    stacked zero-padded projections ``(pbatch, rows, cols)`` in ANY/HBM;
-    with ``quantized=True`` a ``(pbatch, 2, rows)`` scale block in VMEM
-    follows (``imgs_ref`` then holds int8 codes); then the aliased
-    ``vol_in/out`` volume tile, ``strip_ref`` ``(2, band, width)`` VMEM
-    scratch, ``acc_ref`` ``(ty, chunk)`` f32 accumulator, ``sems`` 2
-    DMA semaphores.
-
-    The volume tile is loaded once and stored once per ``pbatch``
-    projections — volume HBM traffic drops by the batch factor versus
-    the per-projection kernels.  The strip DMA discipline lives in
-    :func:`_batch_strip_loop` (shared with the micro variant).
-    """
-    scl_ref = None
-    if quantized:
-        scl_ref, *refs = refs
-    vol_in_ref, vol_out_ref, strip_ref, acc_ref, sems = refs
-    z = pl.program_id(0)
-    y0 = (pl.program_id(1) * ty).astype(jnp.float32)
-    x0 = (pl.program_id(2) * chunk).astype(jnp.float32)
-
-    acc_ref[...] = vol_in_ref[0].astype(jnp.float32)
-
-    def consume(p, slot, wait_strip, ix, iy, r, r0, c0):
-        def strip():
-            wait_strip()
-            return _dequant_strip(strip_ref[slot], scl_ref, r0, band, p)
-
-        acc_ref[...] += _tile_contrib(
-            strip, ix, iy, r, r0, c0, ty=ty, chunk=chunk, band=band,
-            width=width)
-
-    _batch_strip_loop(A_ref, imgs_ref, strip_ref, sems, consume,
-                      o_mm=o_mm, n_u=n_u, n_v=n_v, ty=ty, chunk=chunk,
-                      band=band, width=width, pbatch=pbatch, z=z, y0=y0,
-                      x0=x0)
+    jax.lax.fori_loop(0, pbatch, body, 0)
     vol_out_ref[...] = acc_ref[...].astype(vol_out_ref.dtype)[None]
 
 
@@ -610,8 +494,8 @@ def backproject_kernel_batch_db(A_ref, imgs_ref, *refs,
     corner-based :func:`_strip_origin` — nothing about a tile has to be
     resident to prefetch for it.  ``depth=2`` is the classical double
     buffer without the per-step drain; deeper pipelines keep more
-    fetches in flight (the ROADMAP's "in-flight depth > 2" item), which
-    pays once a single strip latency exceeds one projection's compute.
+    fetches in flight, which pays once a single strip latency exceeds
+    one projection's compute.
 
     Refs as :func:`backproject_kernel_batch`, except ``strip_ref`` is
     ``(depth, band, width)`` and ``sems`` ``depth`` DMA semaphores.
@@ -634,11 +518,13 @@ def backproject_kernel_batch_db(A_ref, imgs_ref, *refs,
     x0 = (cb * chunk).astype(jnp.float32)
     pad_rows = imgs_ref.shape[1]
     pad_cols = imgs_ref.shape[2]
+    row_tile = _row_tile(imgs_ref)
 
     def origin(A, zi, yi, xi):
         return _strip_origin(A, o_mm, zi, yi, xi, n_u=n_u, n_v=n_v, ty=ty,
                              chunk=chunk, band=band, width=width,
-                             pad_rows=pad_rows, pad_cols=pad_cols)
+                             pad_rows=pad_rows, pad_cols=pad_cols,
+                             row_tile=row_tile)
 
     def start_dma(t):
         """Issue the strip fetch for global sequence index ``t`` —
@@ -711,50 +597,6 @@ def backproject_kernel_batch_db(A_ref, imgs_ref, *refs,
     vol_out_ref[...] = acc_ref[...].astype(vol_out_ref.dtype)[None]
 
 
-def backproject_kernel_batch_micro(A_ref, imgs_ref, *refs,
-                                   o_mm, n_u, n_v, ty, chunk, band,
-                                   width, pbatch, group, gband, gwidth,
-                                   quantized=False):
-    """Micro-window batched grid step: the volume tile stays resident
-    across the in-kernel projection loop exactly as in
-    :func:`backproject_kernel_batch` (same strip DMA double-buffering,
-    same corner-based origins), but Parts 2+3 run per ``group``-voxel
-    ``(gband, gwidth)`` micro-window through the shared
-    :func:`_micro_tile_accumulate` — the CT-5 flop cut applied on top of
-    the §7 traffic cut, so the tuner's fastest single-projection compute
-    scheme no longer has to give up the batched path's volume locality.
-    """
-    scl_ref = None
-    if quantized:
-        scl_ref, *refs = refs
-    vol_in_ref, vol_out_ref, strip_ref, acc_ref, sems = refs
-    z = pl.program_id(0)
-    y0 = (pl.program_id(1) * ty).astype(jnp.float32)
-    x0 = (pl.program_id(2) * chunk).astype(jnp.float32)
-
-    acc_ref[...] = vol_in_ref[0].astype(jnp.float32)
-
-    def consume(p, slot, wait_strip, ix, iy, r, r0, c0):
-        def update(row, col, val):
-            cur = acc_ref[row, pl.ds(col, group)]
-            acc_ref[row, pl.ds(col, group)] = cur + val
-
-        _micro_tile_accumulate(
-            wait_strip,
-            lambda r0g, c0g: _dequant_strip(
-                strip_ref[slot, pl.ds(r0g, gband), pl.ds(c0g, gwidth)],
-                scl_ref, r0 + r0g, gband, p),
-            update, ix, iy, r, r0=r0, c0=c0, ty=ty, chunk=chunk,
-            band=band, width=width, group=group, gband=gband,
-            gwidth=gwidth)
-
-    _batch_strip_loop(A_ref, imgs_ref, strip_ref, sems, consume,
-                      o_mm=o_mm, n_u=n_u, n_v=n_v, ty=ty, chunk=chunk,
-                      band=band, width=width, pbatch=pbatch, z=z, y0=y0,
-                      x0=x0)
-    vol_out_ref[...] = acc_ref[...].astype(vol_out_ref.dtype)[None]
-
-
 def backproject_kernel_batch_shared(A_ref, imgs_ref, *refs,
                                     o_mm, n_u, n_v, ty, chunk, band,
                                     width, pbatch, quantized=False):
@@ -764,16 +606,14 @@ def backproject_kernel_batch_shared(A_ref, imgs_ref, *refs,
     Adjacent angles' strips over one tile overlap heavily, so the group
     is served from a single superset window anchored at the elementwise
     *minimum* of the members' corner origins (:func:`_strip_origin` per
-    projection; each is already clamped in-bounds, so the minimum is
-    too).  The DMA moves a ``(pbatch, band, width)`` slab — same total
-    pixel area only when the members coincide, but always a ``pbatch``×
-    cut in DMA *descriptors*, and strictly fewer bytes than ``pbatch``
-    fetches of the same ``(band, width)`` whenever the superset dims are
-    tighter than ``pbatch`` disjoint windows would need.  Coverage is
-    NOT checked here: ops.py sizes/validates ``(band, width)`` against
-    the host planner's :func:`repro.core.clipping
-    .shared_window_requirement` — an undersized window would drop taps
-    silently, so the wrapper raises before this kernel ever runs.
+    projection; each is already clamped in-bounds and tile-aligned, so
+    the minimum is too).  The DMA moves a ``(pbatch, band, width)``
+    slab — same total pixel area only when the members coincide, but
+    always a ``pbatch``× cut in DMA *descriptors*.  Coverage is NOT
+    checked here: ops.py sizes/validates ``(band, width)`` against the
+    host planner's :func:`repro.core.clipping.shared_window_requirement`
+    — an undersized window would drop taps silently, so the wrapper
+    raises before this kernel ever runs.
 
     Refs as :func:`backproject_kernel_batch`, except the scratch is one
     ``(pbatch, band, width)`` window slab and a single DMA semaphore.
@@ -793,7 +633,7 @@ def backproject_kernel_batch_shared(A_ref, imgs_ref, *refs,
         r0p, c0p = _strip_origin(
             _read_A(A_ref, p), o_mm, z, y0, x0, n_u=n_u, n_v=n_v, ty=ty,
             chunk=chunk, band=band, width=width, pad_rows=pad_rows,
-            pad_cols=pad_cols)
+            pad_cols=pad_cols, row_tile=_row_tile(imgs_ref))
         r0s = r0p if r0s is None else jnp.minimum(r0s, r0p)
         c0s = c0p if c0s is None else jnp.minimum(c0s, c0p)
 
@@ -805,69 +645,57 @@ def backproject_kernel_batch_shared(A_ref, imgs_ref, *refs,
     copy.wait()
 
     def body(p, _):
-        ix, iy, w, r = _part1_tile(_read_A(A_ref, p), o_mm, z, y0, x0,
-                                   ty, chunk)
+        A = _read_A(A_ref, p)
+        ix, iy, w, r = _part1_tile(A, o_mm, z, y0, x0, ty, chunk)
         active = _tile_active(ix, iy, w, n_u, n_v)
 
         @pl.when(active)
         def _():
             acc_ref[...] += _tile_contrib(
                 lambda: _dequant_strip(win_ref[p], scl_ref, r0s, band, p),
-                ix, iy, r, r0s, c0s, ty=ty,
-                chunk=chunk, band=band, width=width)
+                ix, iy, r, r0s, c0s, ty=ty, chunk=chunk, band=band,
+                width=width)
         return 0
 
     jax.lax.fori_loop(0, pbatch, body, 0)
     vol_out_ref[...] = acc_ref[...].astype(vol_out_ref.dtype)[None]
 
 
+def _check_window(padded_shape, band, width):
+    rows, cols = padded_shape[-2:]
+    if rows < band or cols < width:
+        raise ValueError(
+            f"padded projection {tuple(padded_shape)} is smaller than the "
+            f"({band}, {width}) strip window")
+
+
 def backproject_volume_pallas(volume, padded_img, A, *, o_mm, n_u, n_v,
                               ty=8, chunk=128, band=16, width=512,
-                              double_buffer=False, db_depth=2,
-                              micro=False, micro_group=8, micro_band=8,
-                              micro_width=32, scales=None,
+                              double_buffer=False, db_depth=2, scales=None,
                               interpret=False):
     """``pallas_call`` wrapper: one projection into the whole volume.
 
     ``volume``: (L, L, L) f32; ``padded_img``: zero-padded projection,
-    row/col counts already rounded up by ops.py so ``band``/``width``
-    slices always fit.  Returns the updated volume (input aliased).
-    ``double_buffer=True`` selects the DMA-prefetching variant (CT-3;
-    ``db_depth`` slots in rotation, same ledger as the batched variant);
-    ``micro=True`` the per-group micro-window compute (CT-5).
+    row/col counts already rounded up by ops.py so the strip window
+    (:func:`strip_window` of ``(band, width)``) always fits.  Returns the
+    updated volume (input aliased).  ``double_buffer=True`` selects the
+    DMA-prefetching variant (``db_depth`` slots in rotation, same ledger
+    as the batched variant).
 
     ``scales`` selects the int8 wire: ``padded_img`` holds int8 codes
-    and ``scales`` the ``(2, rows)`` f32 per-row scale/offset block
+    and ``scales`` the ``(rows, 2)`` f32 per-row scale/offset block
     (built by ops.py from :func:`repro.quant.quantize_rows`), kept
-    VMEM-resident for the whole call via a constant-index BlockSpec —
-    it is ~8 bytes per detector row against the strip stream it
-    sidesteps, so it is fetched once, not per window.
-
-    (``micro_band`` used to default to 4 — the same silent tap-drop
-    hazard class PR 2 fixed for the jnp ``strip2`` ``gband``; 8 covers
-    every geometry in the repo's sweeps, and ops.py now validates the
-    micro window against the host planner.)
+    VMEM-resident for the whole call via a constant-index BlockSpec.
     """
     L = volume.shape[0]
     assert L % ty == 0 and L % chunk == 0
     grid = (L, L // ty, L // chunk)
     quantized = scales is not None
+    band, width = strip_window(band, width, padded_img.dtype.itemsize)
+    _check_window(padded_img.shape, band, width)
 
     vol_spec = pl.BlockSpec((1, ty, chunk), lambda z, y, x: (z, y, x))
-    if micro and double_buffer:
-        raise ValueError(
-            "kernel variants are exclusive: got micro=True and "
-            "double_buffer=True; a tuned decision names exactly one")
-    if micro:
-        kernel = functools.partial(
-            backproject_kernel_micro, o_mm=o_mm, n_u=n_u, n_v=n_v,
-            ty=ty, chunk=chunk, band=band, width=width,
-            group=micro_group, gband=micro_band, gwidth=micro_width,
-            quantized=quantized)
-        scratch = [pltpu.VMEM((band, width), padded_img.dtype),
-                   pltpu.SemaphoreType.DMA]
-        name = "backproject_strip_micro"
-    elif double_buffer:
+    if double_buffer:
         depth = int(db_depth)
         if depth < 2:
             raise ValueError(
@@ -891,7 +719,7 @@ def backproject_volume_pallas(volume, padded_img, A, *, o_mm, n_u, n_v,
 
     in_specs = [
         pl.BlockSpec(memory_space=pltpu.SMEM),       # A (3, 4)
-        pl.BlockSpec(memory_space=pltpu.ANY),        # padded image (HBM)
+        pl.BlockSpec(memory_space=pl.ANY),           # padded image (HBM)
     ]
     args = [A, padded_img]
     if quantized:
@@ -918,10 +746,8 @@ def backproject_volume_pallas(volume, padded_img, A, *, o_mm, n_u, n_v,
 def backproject_volume_pallas_batch(volume, padded_imgs, A_stack, *, o_mm,
                                     n_u, n_v, ty=8, chunk=128, band=16,
                                     width=512, double_buffer=False,
-                                    db_depth=2, micro=False, micro_group=8,
-                                    micro_band=8, micro_width=32,
-                                    shared_window=False, scales=None,
-                                    interpret=False):
+                                    db_depth=2, shared_window=False,
+                                    scales=None, interpret=False):
     """``pallas_call`` wrapper: one *batch* of projections into the whole
     volume, volume tile resident across the in-kernel projection loop.
 
@@ -932,19 +758,17 @@ def backproject_volume_pallas_batch(volume, padded_imgs, A_stack, *, o_mm,
     ``L³`` — a ``pbatch``× cut versus ``pbatch`` calls of
     :func:`backproject_volume_pallas`.
 
-    Variants mirror the single-projection wrapper: ``micro=True``
-    selects the per-group micro-window compute (CT-5) on the batched
-    nest; ``double_buffer=True`` the deep DMA pipeline
+    ``double_buffer=True`` selects the deep DMA pipeline
     (:func:`backproject_kernel_batch_db`, ``db_depth`` slots in
     rotation, in-flight depth ``db_depth - 1`` across the plane loop);
     ``shared_window=True`` the one-DMA-per-group superset-window scheme
     (:func:`backproject_kernel_batch_shared` — here ``band``/``width``
     are the *superset* dims ops.py sized against the group planner).
-    The variants are exclusive — asking for two raises rather than
+    The variants are exclusive — asking for both raises rather than
     silently preferring one, because a tuned decision named exactly one.
 
     ``scales`` selects the int8 wire exactly as in
-    :func:`backproject_volume_pallas`, stacked ``(pbatch, 2, rows)``.
+    :func:`backproject_volume_pallas`, stacked ``(pbatch, rows, 2)``.
     """
     L = volume.shape[0]
     pbatch = int(A_stack.shape[0])
@@ -952,90 +776,69 @@ def backproject_volume_pallas_batch(volume, padded_imgs, A_stack, *, o_mm,
     assert padded_imgs.shape[0] == pbatch
     grid = (L, L // ty, L // chunk)
     quantized = scales is not None
+    band, width = strip_window(band, width, padded_imgs.dtype.itemsize)
+    _check_window(padded_imgs.shape, band, width)
 
     vol_spec = pl.BlockSpec((1, ty, chunk), lambda z, y, x: (z, y, x))
-    if micro and double_buffer or shared_window and (micro or double_buffer):
+    if shared_window and double_buffer:
         raise ValueError(
-            f"batch kernel variants are exclusive: got micro={micro}, "
-            f"double_buffer={double_buffer}, shared_window="
-            f"{shared_window}; a tuned decision names exactly one")
-
-    def specs_and_args():
-        in_specs = [
-            pl.BlockSpec(memory_space=pltpu.SMEM),   # A stack (P, 3, 4)
-            pl.BlockSpec(memory_space=pltpu.ANY),    # padded images (HBM)
-        ]
-        args = [A_stack, padded_imgs]
-        if quantized:
-            # Whole (P, 2, rows) scale block VMEM-resident per call.
-            in_specs.append(
-                pl.BlockSpec(scales.shape, lambda z, y, x: (0, 0, 0)))
-            args.append(scales)
-        in_specs.append(vol_spec)                    # volume tile in
-        args.append(volume)
-        return in_specs, args
+            f"batch kernel variants are exclusive: got double_buffer="
+            f"{double_buffer}, shared_window={shared_window}; a tuned "
+            f"decision names exactly one")
 
     if shared_window:
         kernel = functools.partial(
             backproject_kernel_batch_shared, o_mm=o_mm, n_u=n_u, n_v=n_v,
             ty=ty, chunk=chunk, band=band, width=width, pbatch=pbatch,
             quantized=quantized)
-        in_specs, args = specs_and_args()
         name = f"backproject_strip_batch_shared_p{pbatch}"
-        return pl.pallas_call(
-            kernel,
-            grid=grid,
-            in_specs=in_specs,
-            out_specs=vol_spec,
-            out_shape=jax.ShapeDtypeStruct(volume.shape, volume.dtype),
-            scratch_shapes=[
-                pltpu.VMEM((pbatch, band, width), padded_imgs.dtype),
-                pltpu.VMEM((ty, chunk), jnp.float32),
-                pltpu.SemaphoreType.DMA,
-            ],
-            input_output_aliases={len(args) - 1: 0},
-            interpret=interpret,
-            name=name + ("_int8" if quantized else ""),
-        )(*args)
-    if micro:
-        kernel = functools.partial(
-            backproject_kernel_batch_micro, o_mm=o_mm, n_u=n_u, n_v=n_v,
-            ty=ty, chunk=chunk, band=band, width=width, pbatch=pbatch,
-            group=micro_group, gband=micro_band, gwidth=micro_width,
-            quantized=quantized)
-        n_slots = 2
-        name = f"backproject_strip_batch_micro_p{pbatch}"
-    elif double_buffer:
-        n_slots = int(db_depth)
-        if n_slots < 2:
-            raise ValueError(
-                f"db_depth={db_depth}: the pipelined batch kernel needs "
-                f"an in-flight slot rotation of at least 2")
-        kernel = functools.partial(
-            backproject_kernel_batch_db, o_mm=o_mm, n_u=n_u, n_v=n_v,
-            ty=ty, chunk=chunk, band=band, width=width, pbatch=pbatch,
-            depth=n_slots, grid_dims=grid, quantized=quantized)
-        name = f"backproject_strip_batch_db{n_slots}_p{pbatch}"
+        scratch = [pltpu.VMEM((pbatch, band, width), padded_imgs.dtype),
+                   pltpu.VMEM((ty, chunk), jnp.float32),
+                   pltpu.SemaphoreType.DMA]
     else:
-        kernel = functools.partial(
-            backproject_kernel_batch, o_mm=o_mm, n_u=n_u, n_v=n_v,
-            ty=ty, chunk=chunk, band=band, width=width, pbatch=pbatch,
-            quantized=quantized)
-        n_slots = 2
-        name = f"backproject_strip_batch_p{pbatch}"
-    in_specs, args = specs_and_args()
+        if double_buffer:
+            n_slots = int(db_depth)
+            if n_slots < 2:
+                raise ValueError(
+                    f"db_depth={db_depth}: the pipelined batch kernel "
+                    f"needs an in-flight slot rotation of at least 2")
+            kernel = functools.partial(
+                backproject_kernel_batch_db, o_mm=o_mm, n_u=n_u, n_v=n_v,
+                ty=ty, chunk=chunk, band=band, width=width, pbatch=pbatch,
+                depth=n_slots, grid_dims=grid, quantized=quantized)
+            name = f"backproject_strip_batch_db{n_slots}_p{pbatch}"
+        else:
+            kernel = functools.partial(
+                backproject_kernel_batch, o_mm=o_mm, n_u=n_u, n_v=n_v,
+                ty=ty, chunk=chunk, band=band, width=width, pbatch=pbatch,
+                quantized=quantized)
+            n_slots = 2
+            name = f"backproject_strip_batch_p{pbatch}"
+        scratch = [pltpu.VMEM((n_slots, band, width), padded_imgs.dtype),
+                   pltpu.VMEM((ty, chunk), jnp.float32),
+                   pltpu.SemaphoreType.DMA((n_slots,))]
+
+    in_specs = [
+        pl.BlockSpec(memory_space=pltpu.SMEM),       # A stack (P, 3, 4)
+        pl.BlockSpec(memory_space=pl.ANY),           # padded images (HBM)
+    ]
+    args = [A_stack, padded_imgs]
+    if quantized:
+        # Whole (P, rows, 2) scale block VMEM-resident per call.
+        in_specs.append(
+            pl.BlockSpec(scales.shape, lambda z, y, x: (0, 0, 0)))
+        args.append(scales)
+        name += "_int8"
+    in_specs.append(vol_spec)                        # volume tile in
+    args.append(volume)
     return pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=in_specs,
         out_specs=vol_spec,
         out_shape=jax.ShapeDtypeStruct(volume.shape, volume.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((n_slots, band, width), padded_imgs.dtype),
-            pltpu.VMEM((ty, chunk), jnp.float32),
-            pltpu.SemaphoreType.DMA((n_slots,)),
-        ],
+        scratch_shapes=scratch,
         input_output_aliases={len(args) - 1: 0},
         interpret=interpret,
-        name=name + ("_int8" if quantized else ""),
+        name=name,
     )(*args)

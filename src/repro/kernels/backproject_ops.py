@@ -3,9 +3,11 @@
 Handles everything the kernel assumes away: zero-padding the projection to
 the 1-pixel border the zero-outside semantics rely on, rounding the padded
 buffer up so every (band, width) strip slice is in-bounds, validating the
-static strip size against the host planner, and falling back to
-``interpret=True`` off-TPU so the same entry point works everywhere
-(kernels are *validated* on CPU, *targeted* at TPU).
+static strip size against the host planner, and running the kernels in
+interpret mode off-TPU so the same entry point works everywhere (kernels
+are *validated* on CPU, *targeted* at TPU).  On a TPU backend the
+kernels always compile: there is no interpret fallback to hide a kernel
+the chip's compiler refuses.
 """
 
 from __future__ import annotations
@@ -23,15 +25,16 @@ from repro.core.clipping import (_round8, _round128, plan_strips,
                                  shared_window_requirement)
 from repro.core.geometry import Geometry
 
-from .backproject import (backproject_volume_pallas,
-                          backproject_volume_pallas_batch)
+from .backproject import (SUBLANE, backproject_volume_pallas,
+                          backproject_volume_pallas_batch, strip_window)
 
 __all__ = ["pallas_backproject_one", "pallas_backproject_batch",
            "validate_strip_config", "shared_window_dims", "clamp_tiles"]
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
+def _interpret() -> bool:
+    """Interpret mode exactly when the backend is not a TPU."""
+    return jax.default_backend() != "tpu"
 
 
 def clamp_tiles(gs: GeomStatic, ty: int, chunk: int, band: int,
@@ -49,38 +52,59 @@ def clamp_tiles(gs: GeomStatic, ty: int, chunk: int, band: int,
     return ty, chunk, band, width
 
 
-# Sublane tile per wire itemsize (f32 8, bf16 16, int8 32): padded row
-# counts are rounded to this so every (band, width) slice is aligned.
-_SUBLANE = {1: 32, 2: 16, 4: 8}
-
-
-def _pad_up(image, band: int, width: int, dtype=None):
-    """1-pixel zero border, then round rows/cols up to slice-safe sizes.
-
-    Rows are rounded to a multiple of the sublane tile (8 for f32, 16
-    for 2-byte wire dtypes, 32 for 1-byte) and cols to a multiple of
-    128 (lane tile), and at least (band, width), so any clamped
-    ``(band, width)`` dynamic slice stays in-bounds and
-    hardware-aligned.  ``dtype`` casts the image to the strip wire
-    dtype *before* padding (``None`` leaves the dtype — and the f32
-    bits — untouched).
-    """
-    if dtype is not None:
-        image = image.astype(dtype)
-    sub = _SUBLANE.get(image.dtype.itemsize, 8)
-    n_v, n_u = image.shape
-    rows = max(band, n_v + 2)
-    rows += (-rows) % sub
-    cols = max(width, n_u + 2)
+def _padded_dims(n_v: int, n_u: int, band: int, width: int,
+                 itemsize: int) -> tuple[int, int]:
+    """Padded projection dims: a 1-pixel zero border, at least the strip
+    window, rounded to the wire's ``(sublane, 128)`` tile so every
+    tile-aligned window slice stays in-bounds."""
+    wband, wwidth = strip_window(band, width, itemsize)
+    rows = max(wband, n_v + 2)
+    rows += (-rows) % SUBLANE[itemsize]
+    cols = max(wwidth, n_u + 2)
     cols += (-cols) % 128
-    return jnp.pad(image, ((1, rows - n_v - 1), (1, cols - n_u - 1)))
+    return rows, cols
+
+
+def _pad_up(images, band: int, width: int, itemsize: int | None = None):
+    """Zero-border one projection ``(n_v, n_u)`` or a stack ``(P, n_v,
+    n_u)`` and round it up to :func:`_padded_dims` for a wire of
+    ``itemsize`` bytes (default: the images' own)."""
+    n_v, n_u = images.shape[-2:]
+    rows, cols = _padded_dims(n_v, n_u, band, width,
+                              itemsize or images.dtype.itemsize)
+    lead = ((0, 0),) * (images.ndim - 2)
+    return jnp.pad(images, lead + ((1, rows - n_v - 1),
+                                   (1, cols - n_u - 1)))
+
+
+def _wire_pad(images, band: int, width: int, strip_dtype: str):
+    """Put one projection or a stack on the strip wire.
+
+    Returns ``(padded, scales)``.  f32 passes through bit-for-bit, bf16
+    casts before padding, and int8 pads the f32 image to the 1-byte tile
+    shape *first*, then row-encodes it (:func:`repro.quant.quantize_rows`
+    — per-row affine grid, residual feedback along the row), so pad
+    rows/cols are all-zero rows that decode to exactly 0.0 and the codes
+    slab is directly DMA-sliceable.  ``scales`` is ``None`` off the int8
+    wire, else f32 ``(..., rows, 2)`` — ``scale, offset`` per padded
+    row, the layout :func:`repro.kernels.backproject._dequant_strip`
+    reads.
+    """
+    wire = strip_wire_dtype(strip_dtype)
+    if wire is not jnp.int8:
+        if wire is not None:
+            images = images.astype(wire)
+        return _pad_up(images, band, width), None
+    from repro.quant import quantize_rows
+
+    padded = _pad_up(images.astype(jnp.float32), band, width, itemsize=1)
+    encode = quantize_rows if padded.ndim == 2 else jax.vmap(quantize_rows)
+    rq = encode(padded)
+    return rq.codes, jnp.stack([rq.scale, rq.offset], axis=-1)
 
 
 def validate_strip_config(geom: Geometry, A: np.ndarray, *, ty: int,
-                          chunk: int, band: int, width: int,
-                          micro: bool = False, micro_group: int = 8,
-                          micro_band: int = 8,
-                          micro_width: int = 32) -> None:
+                          chunk: int, band: int, width: int) -> None:
     """Host-side check that (band, width) covers every tile footprint.
 
     A tile spans ``ty`` lines x ``chunk`` voxels; per-line strip needs are
@@ -88,14 +112,6 @@ def validate_strip_config(geom: Geometry, A: np.ndarray, *, ty: int,
     lines' strips are merged by taking min/max origins.  Raises with the
     required sizes if the static config is too small — silent tap loss is
     never possible.
-
-    With ``micro=True`` the per-group ``(micro_band, micro_width)``
-    window is checked too: the micro kernel selects taps from a window
-    sliced out of the strip, and a window smaller than a group's tap
-    footprint drops taps exactly as silently as an undersized strip
-    (``micro_band`` defaulted to 4 until this check existed).  The
-    planner run with ``chunk=micro_group`` gives the exact per-group
-    footprint.
     """
     plan = plan_strips(geom, A, chunk=chunk)
     r0 = plan.r0.astype(np.int64)
@@ -113,101 +129,29 @@ def validate_strip_config(geom: Geometry, A: np.ndarray, *, ty: int,
             f"strip config (band={band}, width={width}) does not cover the "
             f"tile footprint; need at least (band={need_band}, "
             f"width={need_width}) for ty={ty}, chunk={chunk}")
-    if micro:
-        if chunk % micro_group:
-            raise ValueError(
-                f"micro_group={micro_group} must divide chunk={chunk}")
-        gplan = plan_strips(geom, A, chunk=micro_group)
-        # A full-strip window can never lose a tap (its origin clamps
-        # into the strip), so the requirement saturates at the strip
-        # dimensions — mirrors validate_strip_opts' full-detector rule.
-        need_gb = min(gplan.required_band, band)
-        need_gw = min(gplan.required_width, width)
-        if micro_band < need_gb or micro_width < need_gw:
-            raise ValueError(
-                f"micro window (micro_band={micro_band}, "
-                f"micro_width={micro_width}) does not cover the "
-                f"{micro_group}-voxel group tap footprint; need at least "
-                f"(micro_band={need_gb}, micro_width={need_gw}) — "
-                f"undersized micro windows drop taps silently")
-
-
-def _encode_padded(image, band: int, width: int):
-    """Pad (to the int8 sublane tile) then encode once for the int8
-    wire.
-
-    The f32 image is zero-bordered and rounded up to the 1-byte tile
-    shape *first*, then row-encoded (:func:`repro.quant.quantize_rows`
-    — per-row affine grid, residual feedback along the row), so pad
-    rows/cols are all-zero rows that decode to exactly 0.0 and the
-    codes slab is directly DMA-sliceable.  Returns ``(codes, scales)``
-    with ``codes`` int8 ``(rows, cols)`` and ``scales`` f32 ``(2,
-    rows)`` — ``[0] = scale``, ``[1] = offset`` — the layout
-    :func:`repro.kernels.backproject._dequant_strip` reads.
-    """
-    from repro.quant import quantize_rows
-
-    sub = _SUBLANE[1]
-    n_v, n_u = image.shape
-    rows = max(band, n_v + 2)
-    rows += (-rows) % sub
-    cols = max(width, n_u + 2)
-    cols += (-cols) % 128
-    padded = jnp.pad(image.astype(jnp.float32),
-                     ((1, rows - n_v - 1), (1, cols - n_u - 1)))
-    rq = quantize_rows(padded)
-    return rq.codes, jnp.stack([rq.scale, rq.offset])
-
-
-def _encode_padded_stack(images, band: int, width: int):
-    """Stacked :func:`_encode_padded`: ``(P, rows, cols)`` int8 codes
-    plus ``(P, 2, rows)`` per-projection scale blocks."""
-    from repro.quant import quantize_rows
-
-    sub = _SUBLANE[1]
-    n_proj, n_v, n_u = images.shape
-    rows = max(band, n_v + 2)
-    rows += (-rows) % sub
-    cols = max(width, n_u + 2)
-    cols += (-cols) % 128
-    padded = jnp.pad(images.astype(jnp.float32),
-                     ((0, 0), (1, rows - n_v - 1), (1, cols - n_u - 1)))
-    rq = jax.vmap(quantize_rows)(padded)
-    return rq.codes, jnp.stack([rq.scale, rq.offset], axis=1)
 
 
 @functools.partial(
     jax.jit,
     static_argnames=("gs", "ty", "chunk", "band", "width",
-                     "double_buffer", "db_depth", "micro", "micro_group",
-                     "micro_band", "micro_width", "strip_dtype",
+                     "double_buffer", "db_depth", "strip_dtype",
                      "interpret"))
 def _run(volume, image, A, gs: GeomStatic, ty, chunk, band, width,
-         double_buffer, db_depth, micro, micro_group, micro_band,
-         micro_width, strip_dtype, interpret):
-    wire = strip_wire_dtype(strip_dtype)
-    if wire is jnp.int8:
-        padded, scales = _encode_padded(image, band, width)
-    else:
-        padded = _pad_up(image, band, width, wire)
-        scales = None
+         double_buffer, db_depth, strip_dtype, interpret):
+    padded, scales = _wire_pad(image, band, width, strip_dtype)
     return backproject_volume_pallas(
         volume, padded, A,
         o_mm=(gs.O, gs.MM), n_u=gs.n_u, n_v=gs.n_v,
         ty=ty, chunk=chunk, band=band, width=width,
-        double_buffer=double_buffer, db_depth=db_depth, micro=micro,
-        micro_group=micro_group, micro_band=micro_band,
-        micro_width=micro_width, scales=scales, interpret=interpret)
+        double_buffer=double_buffer, db_depth=db_depth, scales=scales,
+        interpret=interpret)
 
 
 def pallas_backproject_one(volume, image, A, geom: Geometry | GeomStatic,
                            *, ty: int = 8, chunk: int = 128, band: int = 16,
                            width: int = 512, double_buffer: bool = False,
-                           db_depth: int = 2, micro: bool = False,
-                           micro_group: int = 8, micro_band: int = 8,
-                           micro_width: int = 32,
+                           db_depth: int = 2,
                            strip_dtype: str = "float32",
-                           interpret: bool | None = None,
                            validate: bool = False,
                            strategy: str = "fixed"):
     """Add one projection to ``volume`` using the Pallas kernel.
@@ -216,28 +160,24 @@ def pallas_backproject_one(volume, image, A, geom: Geometry | GeomStatic,
     every strip DMA and the VMEM scratch) in bf16; the kernels already
     upcast the window to f32 at the one-hot matmul and accumulate in
     f32, so only the tap values are rounded.  ``strip_dtype="int8"``
-    encodes the padded projection once (:func:`_encode_padded` — per-row
+    encodes the padded projection once (:func:`_wire_pad` — per-row
     affine codes + error feedback) and moves 1-byte codes on every strip
     DMA, dequantising in-register next to the accumulator.  The f32
     default path is bitwise-unchanged.
 
-    ``interpret=None`` auto-selects: compiled on TPU, interpreter
+    The kernel compiles on a TPU backend and runs in interpret mode
     elsewhere.  ``validate=True`` runs the host planner check first
-    (cheap; recommended once per geometry) — with ``micro=True`` it also
-    checks the ``(micro_band, micro_width)`` group window, the hazard
-    that made ``micro_band=4`` silently drop taps.  ``double_buffer=True``
-    overlaps strip DMA with compute (hillclimb CT-3), ``db_depth`` slots
-    in rotation.
+    (cheap; recommended once per geometry).  ``double_buffer=True``
+    overlaps strip DMA with compute, ``db_depth`` slots in rotation.
 
     ``strategy="auto"`` pulls the tile parameters (``ty``/``chunk``/
-    ``band``/``width``/``double_buffer``/``db_depth``/``micro``) from
-    the process dispatcher (:mod:`repro.dispatch` — cache hit, in-situ
-    first-call selection, or a logged fallback) for this geometry/
-    backend/device; when no decision carries a kernel config the
-    explicitly passed parameters stand.  (``pbatch`` is the one tuned
-    key with no single-projection meaning — there is nothing to batch
-    here; batch callers resolve it through
-    :func:`pallas_backproject_batch`.)
+    ``band``/``width``/``double_buffer``/``db_depth``) from the process
+    dispatcher (:mod:`repro.dispatch` — cache hit, in-situ first-call
+    selection, or a logged fallback) for this geometry/backend/device;
+    when no decision carries a kernel config the explicitly passed
+    parameters stand.  (``pbatch`` is the one tuned key with no
+    single-projection meaning — there is nothing to batch here; batch
+    callers resolve it through :func:`pallas_backproject_batch`.)
     """
     gs = geom if isinstance(geom, GeomStatic) else GeomStatic.of(geom)
     if strategy == "auto":
@@ -254,85 +194,47 @@ def pallas_backproject_one(volume, image, A, geom: Geometry | GeomStatic,
             # resolve it with the flag (same rotation ledger on the
             # single-projection kernel as on the batched one).
             db_depth = int(tuned.get("db_depth", db_depth))
-            micro = bool(tuned.get("micro", micro))
-            # The tuned micro decision was validated at a specific
-            # window; resolve the whole window, not just the flag.
-            micro_group = int(tuned.get("micro_group", micro_group))
-            micro_band = int(tuned.get("micro_band", micro_band))
-            micro_width = int(tuned.get("micro_width", micro_width))
             strip_dtype = str(tuned.get("strip_dtype", strip_dtype))
     elif strategy != "fixed":
         raise ValueError(
             f"unknown strategy {strategy!r}; want 'fixed' or 'auto'")
     strip_wire_dtype(strip_dtype)   # loud on typos, before any tracing
     ty, chunk, band, width = clamp_tiles(gs, ty, chunk, band, width)
-    micro_band = min(micro_band, band)
-    micro_width = min(micro_width, width)
     if validate:
         if isinstance(geom, GeomStatic):
             raise ValueError("validate=True needs the full Geometry")
         validate_strip_config(geom, np.asarray(A, np.float64), ty=ty,
-                              chunk=chunk, band=band, width=width,
-                              micro=micro, micro_group=micro_group,
-                              micro_band=micro_band,
-                              micro_width=micro_width)
-    if interpret is None:
-        interpret = not _on_tpu()
+                              chunk=chunk, band=band, width=width)
     return _run(jnp.asarray(volume), jnp.asarray(image),
                 jnp.asarray(A, jnp.float32), gs, ty, chunk, band, width,
-                double_buffer, int(db_depth), micro, micro_group,
-                micro_band, micro_width, strip_dtype, interpret)
-
-
-def _pad_up_stack(images, band: int, width: int, dtype=None):
-    """The stacked analogue of :func:`_pad_up`: pad the whole projection
-    stack once (1-pixel zero border + slice-safe round-up; ``dtype``
-    casts to the strip wire dtype first, ``None`` = untouched f32)."""
-    if dtype is not None:
-        images = images.astype(dtype)
-    sub = _SUBLANE.get(images.dtype.itemsize, 8)
-    n_proj, n_v, n_u = images.shape
-    rows = max(band, n_v + 2)
-    rows += (-rows) % sub
-    cols = max(width, n_u + 2)
-    cols += (-cols) % 128
-    return jnp.pad(images, ((0, 0), (1, rows - n_v - 1),
-                            (1, cols - n_u - 1)))
+                double_buffer, int(db_depth), strip_dtype, _interpret())
 
 
 @functools.partial(
     jax.jit,
     static_argnames=("gs", "ty", "chunk", "band", "width", "pbatch",
-                     "double_buffer", "db_depth", "micro", "micro_group",
-                     "micro_band", "micro_width", "shared_window",
+                     "double_buffer", "db_depth", "shared_window",
                      "strip_dtype", "interpret"))
 def _run_batched(volume, images, mats, gs: GeomStatic, ty, chunk, band,
-                 width, pbatch, double_buffer, db_depth, micro,
-                 micro_group, micro_band, micro_width, shared_window,
+                 width, pbatch, double_buffer, db_depth, shared_window,
                  strip_dtype, interpret):
     from repro.core.backproject import _stream_batches
 
     # With shared_window the (band, width) passed here are already the
-    # superset-window dims sized by the caller.
-    wire = strip_wire_dtype(strip_dtype)
-    if wire is jnp.int8:
-        # Encode once for the whole stack; _stream_batches slices the
-        # (codes, scales) pair per batch as one pytree.
-        padded = _encode_padded_stack(images, band, width)
-    else:
-        padded = _pad_up_stack(images, band, width, wire)
+    # superset-window dims sized by the caller.  Encode once for the
+    # whole stack; _stream_batches slices the (codes, scales) pair per
+    # batch as one pytree.
+    padded, scales = _wire_pad(images, band, width, strip_dtype)
 
     def call(vol, imgs, A):
-        codes, scl = imgs if isinstance(imgs, tuple) else (imgs, None)
+        codes, scl = imgs
         return backproject_volume_pallas_batch(
             vol, codes, A, o_mm=(gs.O, gs.MM), n_u=gs.n_u, n_v=gs.n_v,
             ty=ty, chunk=chunk, band=band, width=width,
-            double_buffer=double_buffer, db_depth=db_depth, micro=micro,
-            micro_group=micro_group, micro_band=micro_band,
-            micro_width=micro_width, shared_window=shared_window,
-            scales=scl, interpret=interpret)
+            double_buffer=double_buffer, db_depth=db_depth,
+            shared_window=shared_window, scales=scl, interpret=interpret)
 
-    return _stream_batches(padded, mats, volume, pbatch, call)
+    return _stream_batches((padded, scales), mats, volume, pbatch, call)
 
 
 # Projection stacks already proven covered by (geom, tile config) — the
@@ -392,14 +294,11 @@ def pallas_backproject_batch(volume, images, mats,
                              width: int = 512,
                              pbatch: int = DEFAULT_PBATCH,
                              double_buffer: bool = False,
-                             db_depth: int = 2, micro: bool = False,
-                             micro_group: int = 8, micro_band: int = 8,
-                             micro_width: int = 32,
+                             db_depth: int = 2,
                              shared_window: bool = False,
                              shared_band: int | None = None,
                              shared_width: int | None = None,
                              strip_dtype: str = "float32",
-                             interpret: bool | None = None,
                              validate: bool = True,
                              strategy: str = "fixed"):
     """Add a stack of projections to ``volume``, ``pbatch`` per kernel
@@ -411,29 +310,27 @@ def pallas_backproject_batch(volume, images, mats,
     ``n_proj`` is chunked into ``pbatch``-sized batches inside one jit
     (a ``pbatch ∤ n_proj`` remainder runs as one final smaller batch).
     Every projection's footprint is validated against the host planner
-    by default (memoised per problem) — with ``micro=True`` the
-    ``(micro_band, micro_width)`` group window included; pass
-    ``validate=False`` only when the exact (geometry, matrices, tile)
-    triple was already validated.
+    by default (memoised per problem); pass ``validate=False`` only when
+    the exact (geometry, matrices, tile) triple was already validated.
+    The kernel compiles on a TPU backend and runs in interpret mode
+    elsewhere.
 
     ``double_buffer=True`` selects the deep DMA pipeline
-    (``db_depth``-slot rotation crossing the plane loop, DESIGN.md §9);
-    ``micro=True`` the per-group micro-window compute.  ``strategy=
-    "auto"`` pulls the full tuned surface — ``ty``/``chunk``/``band``/
-    ``width``, ``pbatch``, *and* the ``double_buffer``/``db_depth``/
-    ``micro``/``micro_*`` variant flags — from the process dispatcher
-    (:mod:`repro.dispatch`) for this key: every tuned decision runs the
-    kernel it was timed on, and an impossible combination raises
-    instead of being shed.
+    (``db_depth``-slot rotation crossing the plane loop, DESIGN.md §9).
+    ``strategy="auto"`` pulls the full tuned surface — ``ty``/``chunk``/
+    ``band``/``width``, ``pbatch``, *and* the ``double_buffer``/
+    ``db_depth``/``shared_window`` variant flags — from the process
+    dispatcher (:mod:`repro.dispatch`) for this key: every tuned
+    decision runs the kernel it was timed on, and an impossible
+    combination raises instead of being shed.
 
     ``strip_dtype="bfloat16"`` carries the padded stack (all strip/
     window DMAs and the VMEM scratch) in bf16 — the kernels upcast to
     f32 at the one-hot matmul and accumulate in f32, so only the tap
     values round; ``strip_dtype="int8"`` encodes the stack once into
-    per-row affine codes plus a ``(pbatch, 2, rows)`` scale block
-    (:func:`_encode_padded_stack`) and every strip/window DMA moves
-    1-byte codes, dequantised in-register; the f32 default is
-    bitwise-unchanged.
+    per-row affine codes plus a ``(pbatch, rows, 2)`` scale block
+    (:func:`_wire_pad`) and every strip/window DMA moves 1-byte codes,
+    dequantised in-register; the f32 default is bitwise-unchanged.
     ``shared_window=True`` selects the superset-window kernel: one
     ``(pbatch, band, width)`` window DMA per (volume tile, projection
     group) instead of ``pbatch`` strip fetches.  The window dims are
@@ -456,12 +353,6 @@ def pallas_backproject_batch(volume, images, mats,
             pbatch = int(tuned.get("pbatch", pbatch))
             double_buffer = bool(tuned.get("double_buffer", double_buffer))
             db_depth = int(tuned.get("db_depth", db_depth))
-            micro = bool(tuned.get("micro", micro))
-            # A tuned micro decision was validated at a specific window;
-            # resolve the whole window, not just the flag.
-            micro_group = int(tuned.get("micro_group", micro_group))
-            micro_band = int(tuned.get("micro_band", micro_band))
-            micro_width = int(tuned.get("micro_width", micro_width))
             shared_window = bool(tuned.get("shared_window", shared_window))
             shared_band = tuned.get("shared_band", shared_band)
             shared_width = tuned.get("shared_width", shared_width)
@@ -469,20 +360,17 @@ def pallas_backproject_batch(volume, images, mats,
     elif strategy != "fixed":
         raise ValueError(
             f"unknown strategy {strategy!r}; want 'fixed' or 'auto'")
-    if (micro and double_buffer
-            or shared_window and (micro or double_buffer)):
+    if shared_window and double_buffer:
         raise ValueError(
-            f"batch kernel variants are exclusive: got micro={micro}, "
-            f"double_buffer={double_buffer}, shared_window="
-            f"{shared_window}; a tuned decision names exactly one")
+            f"batch kernel variants are exclusive: got double_buffer="
+            f"{double_buffer}, shared_window={shared_window}; a tuned "
+            f"decision names exactly one")
     if double_buffer and int(db_depth) < 2:
         raise ValueError(
             f"db_depth={db_depth}: the pipelined batch kernel needs an "
             f"in-flight slot rotation of at least 2")
     strip_wire_dtype(strip_dtype)   # loud on typos, before any tracing
     ty, chunk, band, width = clamp_tiles(gs, ty, chunk, band, width)
-    micro_band = min(micro_band, band)
-    micro_width = min(micro_width, width)
     images = jnp.asarray(images)
     mats_f32 = jnp.asarray(mats, jnp.float32)
     n_proj = int(images.shape[0])
@@ -504,23 +392,16 @@ def pallas_backproject_batch(volume, images, mats,
         if isinstance(geom, GeomStatic):
             raise ValueError("validate=True needs the full Geometry")
         mats64 = np.asarray(mats, np.float64).reshape(-1, 3, 4)
-        key = (gs, ty, chunk, band, width, micro,
-               (micro_group, micro_band, micro_width) if micro else None,
+        key = (gs, ty, chunk, band, width,
                hashlib.sha1(mats64.tobytes()).hexdigest())
         if key not in _VALIDATED_STACKS:
             for A in mats64:
                 validate_strip_config(geom, A, ty=ty, chunk=chunk,
-                                      band=band, width=width, micro=micro,
-                                      micro_group=micro_group,
-                                      micro_band=micro_band,
-                                      micro_width=micro_width)
+                                      band=band, width=width)
             if len(_VALIDATED_STACKS) >= 4096:
                 _VALIDATED_STACKS.clear()
             _VALIDATED_STACKS.add(key)
-    if interpret is None:
-        interpret = not _on_tpu()
     return _run_batched(jnp.asarray(volume), images, mats_f32, gs, ty,
                         chunk, band, width, pbatch, double_buffer,
-                        int(db_depth), micro, micro_group, micro_band,
-                        micro_width, shared_window, strip_dtype,
-                        interpret)
+                        int(db_depth), shared_window, strip_dtype,
+                        _interpret())

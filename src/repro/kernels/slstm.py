@@ -119,9 +119,9 @@ def slstm_pallas(zifo, r, *, tb: int = 8, td: int = 128,
         grid=grid,
         in_specs=[
             pl.BlockSpec((4, td), lambda b, d: (0, d)),   # r tile
-            pl.BlockSpec(memory_space=pltpu.ANY),         # zifo (HBM)
+            pl.BlockSpec(memory_space=pl.ANY),            # zifo (HBM)
         ],
-        out_specs=pl.BlockSpec(memory_space=pltpu.ANY),   # hs (HBM)
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),      # hs (HBM)
         out_shape=jax.ShapeDtypeStruct((B, S, di), zifo.dtype),
         scratch_shapes=[
             pltpu.VMEM((tb, td), jnp.float32),            # c

@@ -39,7 +39,7 @@ DEFAULT_STRATEGY = "strip2"
 # Bumped whenever the persisted TunedConfig layout or the semantics of a
 # tuned decision change (v2: the ``pbatch`` axis — a v1 decision timed
 # the per-projection loop nest, which no longer exists; v3: batched
-# kernel candidates carry ``double_buffer``/``db_depth``/``micro`` and
+# kernel candidates carry ``double_buffer``/``db_depth`` and
 # the batch path *honors* them — a v2 decision's variant flags were
 # timed against a batch path that silently shed them, so replaying one
 # would misattribute its numbers; v4: the ``strip_dtype`` and
@@ -48,22 +48,20 @@ DEFAULT_STRATEGY = "strip2"
 # and replaying it would freeze the old design space; v5: the
 # ``strip_dtype="int8"`` axis — a v4 decision's wire-dtype winner never
 # competed against the per-row-affine int8 candidates, and the VMEM
-# screen is now itemsize-aware at 1 byte).  ``load_tuned`` treats any
-# other version as untuned, so stale ``.repro_tune/`` files are
-# *ignored*, never misread into the new dataclass.
-TUNE_SCHEMA_VERSION = 5
+# screen is now itemsize-aware at 1 byte; v6: the micro-window kernel
+# variants are gone and kernel tiles are TPU block-legal, so a v5
+# decision may name a kernel that no longer exists).  ``load_tuned``
+# treats any other version as untuned, so stale ``.repro_tune/`` files
+# are *ignored*, never misread into the new dataclass.
+TUNE_SCHEMA_VERSION = 6
 
-# ``micro_*`` ride along with ``micro``: a tuned micro decision was
-# validated (and timed) at a specific ``(micro_band, micro_width)``
-# window — resolving the flag without the window would run the kernel at
-# defaults it was never validated at.  ``db_depth`` likewise rides with
-# ``double_buffer``: the depth is part of the timed pipeline shape, and
-# ``shared_band``/``shared_width`` with ``shared_window`` (``None`` dims
-# auto-size from the group planner at resolve time, so they are usually
-# absent).  ``strip_dtype`` is the wire dtype the decision was timed at.
+# ``db_depth`` rides along with ``double_buffer``: the depth is part of
+# the timed pipeline shape, and ``shared_band``/``shared_width`` with
+# ``shared_window`` (``None`` dims auto-size from the group planner at
+# resolve time, so they are usually absent).  ``strip_dtype`` is the
+# wire dtype the decision was timed at.
 _PALLAS_KEYS = ("ty", "chunk", "band", "width", "double_buffer",
-                "db_depth", "micro", "micro_group", "micro_band",
-                "micro_width", "shared_window", "shared_band",
+                "db_depth", "shared_window", "shared_band",
                 "shared_width", "strip_dtype", "pbatch")
 
 # Options each jnp strategy actually accepts — caller options riding

@@ -5,7 +5,7 @@ a given chip — maps here to a compact grid over the five jnp strategies
 (DESIGN.md §2) plus the Pallas kernel variants, each with the tile
 parameters that govern its locality/width trade-off (``chunk``/``band``/
 ``width`` for strips, ``group``/``gband``/``gwidth`` for micro-windows,
-``ty``/``double_buffer``/``micro`` for the kernel).  Every family also
+``ty``/``double_buffer`` for the kernel).  Every family also
 spans the ``pbatch`` axis — how many projections fold into the volume per
 volume pass (DESIGN.md §7): the loop-nest inversion trades volume HBM
 traffic (÷pbatch) against working-set pressure, so the right depth is a
@@ -22,6 +22,7 @@ from typing import NamedTuple
 from repro.analysis.lint.budget import (VMEM_BUDGET_BYTES,
                                         batch_vmem_estimate)
 from repro.core.backproject import GeomStatic
+from repro.kernels.backproject_ops import clamp_tiles
 
 __all__ = ["Candidate", "jnp_candidates", "pallas_candidates",
            "default_space", "pallas_batch_fits_vmem"]
@@ -135,25 +136,24 @@ def pallas_candidates(gs: GeomStatic,
                       pbatches: tuple[int, ...] = _PBATCHES
                       ) -> list[Candidate]:
     """Kernel variants at a geometry-clamped base tile: plain /
-    double-buffer / micro per-projection, plus the projection-batched
-    kernel crossed ``pbatch × {plain, db, micro}`` at every depth that
-    fits the VMEM budget — the batch path honors the full tuned config
-    surface, so every variant competes at every batch depth.  The
-    deepest fitting ``pbatch`` also proposes a 4-deep DMA rotation
-    (``db_depth=4``), the ROADMAP's "in-flight depth > 2" point.
+    double-buffer per-projection, plus the projection-batched kernel
+    crossed ``pbatch × {plain, db}`` at every depth that fits the VMEM
+    budget — the batch path honors the full tuned config surface, so
+    every variant competes at every batch depth.  The deepest fitting
+    ``pbatch`` also proposes a 4-deep DMA rotation (``db_depth=4``).
+
+    The base tile is TPU block-legal (``ty`` 8 or the whole axis,
+    ``chunk`` 128 or the whole axis) and its ``(192, 1152)`` strip
+    covers every tile footprint of the RabbitCT sweep at L=512 (the
+    planner needs ``(190, 1125)`` over all 496 angles);
+    ``clamp_tiles`` shrinks it to small detectors.
     """
-    base = dict(ty=min(8, gs.L), chunk=min(32, gs.L), band=16, width=128)
-    micro_win = dict(micro=True, micro_group=min(8, gs.L), micro_band=8,
-                     micro_width=32)
+    base = dict(ty=8 if gs.L % 8 == 0 else gs.L,
+                chunk=128 if gs.L % 128 == 0 else gs.L, band=192,
+                width=1152)
     cands = [
         Candidate.of("pallas", **base),
         Candidate.of("pallas", double_buffer=True, **base),
-        # The micro candidate names its window explicitly so the values
-        # it is validated and timed at are the values that persist into
-        # the TunedConfig — resolving ``micro=True`` without them would
-        # run windows the sweep never saw.  Same for ``db_depth`` with
-        # ``double_buffer``.
-        Candidate.of("pallas", **micro_win, **base),
     ]
     batched = [pb for pb in pbatches
                if pb > 1 and pallas_batch_fits_vmem(gs, pbatch=pb, **base)]
@@ -161,8 +161,6 @@ def pallas_candidates(gs: GeomStatic,
         cands.append(Candidate.of("pallas", pbatch=pb, **base))
         cands.append(Candidate.of("pallas", pbatch=pb, double_buffer=True,
                                   db_depth=2, **base))
-        cands.append(Candidate.of("pallas", pbatch=pb, **micro_win,
-                                  **base))
         # Narrow-wire axes on the plain batch kernel: bf16 halves strip
         # DMA bytes, int8 halves them again (per-row affine codes, 1-byte
         # scratch — the VMEM screen at itemsize=1 admits it wherever the
@@ -174,12 +172,14 @@ def pallas_candidates(gs: GeomStatic,
                                       strip_dtype="int8", **base))
         # Shared superset window: one DMA per projection group.  The
         # window dims auto-size from the group planner at run time; the
-        # VMEM screen assumes up to 2x the base strip dims per slab
-        # (itemsize 2 for the bf16 variant, 1 for int8).
+        # VMEM screen assumes up to 2x the base strip dims per slab,
+        # clamped to the detector as the wrapper clamps them.
+        _, _, sband, swidth = clamp_tiles(gs, base["ty"], base["chunk"],
+                                          2 * base["band"],
+                                          2 * base["width"])
         if pallas_batch_fits_vmem(gs, pbatch=pb, ty=base["ty"],
-                                  chunk=base["chunk"],
-                                  band=2 * base["band"],
-                                  width=2 * base["width"], depth=pb):
+                                  chunk=base["chunk"], band=sband,
+                                  width=swidth, depth=pb):
             cands.append(Candidate.of("pallas", pbatch=pb,
                                       shared_window=True, **base))
             cands.append(Candidate.of("pallas", pbatch=pb,

@@ -7,9 +7,11 @@ candidate carrying ``pbatch`` is timed through the batch-major drivers on
 a ``pbatch``-deep projection stack and normalised to **us per
 projection**, so depths compete on one scale with the classical
 per-projection nest.  Candidates whose static windows cannot cover the
-geometry's tap footprint are *skipped with a recorded reason* rather than
-timed — a config the validator rejects would produce silently wrong
-voxels, and a tuner must never select one.
+geometry's tap footprint, or that the VMEM screen rejects, are *skipped
+with a recorded reason* rather than timed — a config the validator
+rejects would produce silently wrong voxels, and a tuner must never
+select one.  Nothing else is skipped: a candidate that fails to compile
+or run raises.
 """
 
 from __future__ import annotations
@@ -86,6 +88,44 @@ def _batch_problem(geom: Geometry, image, pbatch: int):
     return images, mats
 
 
+def _screen(strategy: str, geom: Geometry, gs: GeomStatic, mats_all,
+            pbatch: int, opts: dict) -> None:
+    """Raise ``ValueError`` if the planner or the VMEM screen rejects a
+    candidate — the only reasons a sweep may skip one."""
+    if strategy in STRATEGIES:
+        validate_strip_opts(geom, mats_all, strategy, opts)
+        return
+    from repro.kernels.backproject_ops import (clamp_tiles,
+                                               shared_window_dims,
+                                               validate_strip_config)
+    from .space import pallas_batch_fits_vmem
+    ty, chunk, band, width = clamp_tiles(
+        gs, opts.get("ty", 8), opts.get("chunk", 128),
+        opts.get("band", 16), opts.get("width", 512))
+    if opts.get("shared_window", False):
+        # Size the superset window over the *full* matrix set (what
+        # reconstruct-time resolution will see) and screen it against
+        # the VMEM budget — the planner-tight dims can exceed the base
+        # strip's.
+        pb_eff = max(1, min(pbatch, geom.n_proj))
+        sband, swidth = shared_window_dims(
+            geom, mats_all, ty=ty, chunk=chunk, pbatch=pb_eff,
+            shared_band=opts.get("shared_band"),
+            shared_width=opts.get("shared_width"))
+        itemsize = {"bfloat16": 2, "int8": 1}.get(
+            str(opts.get("strip_dtype")), 4)
+        if not pallas_batch_fits_vmem(
+                gs, pbatch=pb_eff, ty=ty, chunk=chunk, band=sband,
+                width=swidth, depth=pb_eff, itemsize=itemsize):
+            raise ValueError(
+                f"shared window ({sband}, {swidth}) x pbatch={pb_eff} "
+                f"exceeds the VMEM budget")
+        return
+    for A_i in mats_all:
+        validate_strip_config(geom, A_i, ty=ty, chunk=chunk, band=band,
+                              width=width)
+
+
 def sweep_strategies(geom: Geometry, *, image=None, A=None,
                      space: list[Candidate] | None = None,
                      include_pallas: bool | None = None,
@@ -120,77 +160,40 @@ def sweep_strategies(geom: Geometry, *, image=None, A=None,
     for cand in space:
         opts = dict(cand.opts)
         pbatch = max(1, int(opts.pop("pbatch", 1)))
+        if cand.strategy not in STRATEGIES and cand.strategy != "pallas":
+            raise ValueError(f"unknown candidate strategy "
+                             f"{cand.strategy!r}")
+        # Only the planner and the VMEM screen may reject a candidate;
+        # anything the timed run raises (a kernel the compiler refuses
+        # included) propagates.
         try:
-            if cand.strategy in STRATEGIES:
-                validate_strip_opts(geom, mats_all, cand.strategy, opts)
-                if pbatch == 1:
-                    t = time_fn(backproject_one, vol0, image, A, geom,
-                                strategy=cand.strategy, warmup=warmup,
-                                iters=iters, **tkw, **opts)
-                else:
-                    images, mats = _batch_problem(geom, image, pbatch)
-                    t = time_fn(backproject_batch, vol0, images, mats,
-                                geom, strategy=cand.strategy,
-                                pbatch=pbatch, warmup=warmup,
-                                iters=iters, **tkw, **opts) / pbatch
-            elif cand.strategy == "pallas":
-                from repro.kernels.backproject_ops import (
-                    clamp_tiles, pallas_backproject_batch,
-                    pallas_backproject_one, shared_window_dims,
-                    validate_strip_config)
-                from .space import pallas_batch_fits_vmem
-                ty, chunk, band, width = clamp_tiles(
-                    gs, opts.get("ty", 8), opts.get("chunk", 128),
-                    opts.get("band", 16), opts.get("width", 512))
-                if opts.get("shared_window", False):
-                    # Size the superset window over the *full* matrix
-                    # set (what reconstruct-time resolution will see)
-                    # and screen it against the VMEM budget — the
-                    # planner-tight dims can exceed the base strip's.
-                    pb_eff = max(1, min(pbatch, geom.n_proj))
-                    sband, swidth = shared_window_dims(
-                        geom, mats_all, ty=ty, chunk=chunk,
-                        pbatch=pb_eff,
-                        shared_band=opts.get("shared_band"),
-                        shared_width=opts.get("shared_width"))
-                    itemsize = {"bfloat16": 2, "int8": 1}.get(
-                        str(opts.get("strip_dtype")), 4)
-                    if not pallas_batch_fits_vmem(
-                            gs, pbatch=pb_eff, ty=ty, chunk=chunk,
-                            band=sband, width=swidth, depth=pb_eff,
-                            itemsize=itemsize):
-                        raise ValueError(
-                            f"shared window ({sband}, {swidth}) x "
-                            f"pbatch={pb_eff} exceeds the VMEM budget")
-                else:
-                    for A_i in mats_all:
-                        # Micro candidates validate at *their* window
-                        # values — the same values the candidate
-                        # persists, so the resolved config always ran
-                        # through this check.
-                        validate_strip_config(
-                            geom, A_i, ty=ty, chunk=chunk, band=band,
-                            width=width,
-                            micro=bool(opts.get("micro", False)),
-                            micro_group=int(opts.get("micro_group", 8)),
-                            micro_band=int(opts.get("micro_band", 8)),
-                            micro_width=int(opts.get("micro_width", 32)))
-                if pbatch == 1:
-                    t = time_fn(pallas_backproject_one, vol0, image, A,
-                                geom, warmup=warmup, iters=iters, **tkw,
-                                **opts)
-                else:
-                    images, mats = _batch_problem(geom, image, pbatch)
-                    t = time_fn(pallas_backproject_batch, vol0, images,
-                                mats, geom, pbatch=pbatch, validate=False,
-                                warmup=warmup, iters=iters, **tkw,
-                                **opts) / pbatch
-            else:
-                raise ValueError(f"unknown candidate strategy "
-                                 f"{cand.strategy!r}")
+            _screen(cand.strategy, geom, gs, mats_all, pbatch, opts)
         except ValueError as e:
             skipped.append((cand.label, str(e)))
             continue
+        if cand.strategy in STRATEGIES:
+            if pbatch == 1:
+                t = time_fn(backproject_one, vol0, image, A, geom,
+                            strategy=cand.strategy, warmup=warmup,
+                            iters=iters, **tkw, **opts)
+            else:
+                images, mats = _batch_problem(geom, image, pbatch)
+                t = time_fn(backproject_batch, vol0, images, mats, geom,
+                            strategy=cand.strategy, pbatch=pbatch,
+                            warmup=warmup, iters=iters, **tkw,
+                            **opts) / pbatch
+        else:
+            from repro.kernels.backproject_ops import (
+                pallas_backproject_batch, pallas_backproject_one)
+            if pbatch == 1:
+                t = time_fn(pallas_backproject_one, vol0, image, A, geom,
+                            warmup=warmup, iters=iters, **tkw, **opts)
+            else:
+                images, mats = _batch_problem(geom, image, pbatch)
+                t = time_fn(pallas_backproject_batch, vol0, images, mats,
+                            geom, pbatch=pbatch, validate=False,
+                            warmup=warmup, iters=iters, **tkw,
+                            **opts) / pbatch
         timings.append(Timing(
             label=cand.label, strategy=cand.strategy, opts=cand.opts,
             us_per_call=t * 1e6, gups=gs.L ** 3 / t / 1e9))

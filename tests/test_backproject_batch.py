@@ -176,12 +176,12 @@ def test_pallas_batch_border_rays():
 @pytest.mark.parametrize("variant", [
     dict(double_buffer=True, db_depth=2),
     dict(double_buffer=True, db_depth=3),
-    dict(micro=True),
-], ids=["db2", "db3", "micro"])
+    dict(double_buffer=True, db_depth=4),
+], ids=["db2", "db3", "db4"])
 @pytest.mark.parametrize("pbatch", [2, 5])   # 5 % 2 != 0: remainder batch
 def test_pallas_batch_variants_match_ref(ct_case, variant, pbatch):
-    """Interpret-mode parity of the db (depth 2 and deeper) and micro
-    batch variants against the per-projection oracle, full-divisor and
+    """Interpret-mode parity of the db batch variant (depth 2 and
+    deeper) against the per-projection oracle, full-divisor and
     remainder depths."""
     filt, mats = ct_case
     vol0 = jnp.zeros((GEOM.L,) * 3, jnp.float32)
@@ -210,10 +210,10 @@ def test_pallas_batch_db_bitwise_vs_plain(ct_case):
 
 @pytest.mark.parametrize("variant", [
     dict(double_buffer=True, db_depth=3),
-    dict(micro=True),
-], ids=["db3", "micro"])
+    dict(shared_window=True),
+], ids=["db3", "shared"])
 def test_pallas_batch_variants_border_rays(variant):
-    """Zero-outside semantics of both new variants across an in-kernel
+    """Zero-outside semantics of the db and shared variants across an in-kernel
     projection loop with a pbatch remainder on edge-straddling rays."""
     geom = Geometry().scaled(16, n_proj=8, n_u=24, n_v=18)
     rng = np.random.default_rng(3)
@@ -239,29 +239,12 @@ def test_pallas_batch_variant_flags_are_loud(ct_case):
     vol0 = jnp.zeros((GEOM.L,) * 3, jnp.float32)
     with pytest.raises(ValueError, match="exclusive"):
         pallas_backproject_batch(vol0, filt, mats, GEOM, ty=4, chunk=16,
-                                 band=16, width=128, pbatch=2, micro=True,
-                                 double_buffer=True)
+                                 band=16, width=128, pbatch=2,
+                                 shared_window=True, double_buffer=True)
     with pytest.raises(ValueError, match="db_depth"):
         pallas_backproject_batch(vol0, filt, mats, GEOM, ty=4, chunk=16,
                                  band=16, width=128, pbatch=2,
                                  double_buffer=True, db_depth=1)
-
-
-def test_pallas_batch_micro_window_is_loud_or_correct():
-    """The batch micro path runs the same planner-backed window check as
-    the single-projection kernel: an undersized ``(micro_band,
-    micro_width)`` raises before any device work (L=48 is where a 4-row
-    window loses taps, tests/test_kernel_backproject.py)."""
-    geom = Geometry().scaled(48, n_proj=2)
-    rng = np.random.default_rng(7)
-    imgs = rng.standard_normal(
-        (geom.n_proj, geom.n_v, geom.n_u)).astype(np.float32)
-    mats = np.asarray(projection_matrices(geom), np.float32)
-    vol0 = jnp.zeros((48,) * 3, jnp.float32)
-    with pytest.raises(ValueError, match="micro window"):
-        pallas_backproject_batch(vol0, imgs, mats, geom, ty=8, chunk=48,
-                                 band=32, width=256, pbatch=2, micro=True,
-                                 micro_band=4)
 
 
 def test_pallas_batch_validates_stack(ct_case):
@@ -322,12 +305,12 @@ def _write_cache_file(tmp_path, pallas, version):
 
 @pytest.mark.parametrize("variant", [
     {"double_buffer": True, "db_depth": 3},
-    {"micro": True, "micro_group": 8, "micro_band": 8, "micro_width": 32},
-], ids=["db", "micro"])
+    {"shared_window": True},
+], ids=["db", "shared"])
 def test_tuned_batch_flags_resolve_from_v3_cache_file(ct_case, tmp_path,
                                                       monkeypatch,
                                                       variant):
-    """A v3 cache file carrying ``double_buffer``/``micro`` redirects
+    """A cache file carrying ``double_buffer``/``shared_window`` redirects
     the batch path to the matching variant — bit-for-bit against both
     the explicit variant call and the plain batch kernel — and the old
     shed-the-flag warning never fires (warnings are errors here)."""
@@ -354,8 +337,8 @@ def test_tuned_batch_flags_resolve_from_v3_cache_file(ct_case, tmp_path,
     np.testing.assert_array_equal(np.asarray(out_auto),
                                   np.asarray(out_fix))
     # Neither variant changes the arithmetic, only its schedule — the
-    # pipeline moves fetches, the micro window drops only identically-
-    # zero one-hot terms.
+    # pipeline moves fetches, the shared slab only moves the window
+    # origin, so the one-hot terms it adds are identically zero.
     np.testing.assert_array_equal(np.asarray(out_auto), np.asarray(plain))
 
 
@@ -500,14 +483,14 @@ def test_pallas_batch_narrow_wire_differs_but_bounded(ct_case, dtype, rel):
 
 
 def test_pallas_batch_int8_variants_agree_bitwise(ct_case):
-    """Every batch variant (plain / shared / db / micro) dequantises the
-    same codes with the same per-row scales — the DMA shape must not
-    change the int8 arithmetic, so all four agree bit-for-bit."""
+    """Every batch variant (plain / shared / db) dequantises the same
+    codes with the same per-row scales — the DMA shape must not change
+    the int8 arithmetic, so all three agree bit-for-bit."""
     filt, mats = ct_case
     vol0 = jnp.zeros((GEOM.L,) * 3, jnp.float32)
     outs = []
     for flags in (dict(), dict(shared_window=True),
-                  dict(double_buffer=True), dict(micro=True)):
+                  dict(double_buffer=True)):
         outs.append(np.asarray(pallas_backproject_batch(
             vol0, filt, mats, GEOM, ty=4, chunk=16, band=16, width=128,
             pbatch=2, strip_dtype="int8", **flags)))
@@ -517,10 +500,11 @@ def test_pallas_batch_int8_variants_agree_bitwise(ct_case):
 
 def test_pallas_batch_shared_is_exclusive(ct_case):
     """The shared slab owns the window layout — combining it with the
-    DMA pipeline or the micro window must raise, not silently pick one."""
+    DMA pipeline must raise, not silently pick one."""
     filt, mats = ct_case
     vol0 = jnp.zeros((GEOM.L,) * 3, jnp.float32)
-    for bad in (dict(micro=True), dict(double_buffer=True)):
+    for bad in (dict(double_buffer=True), dict(double_buffer=True,
+                                              db_depth=4)):
         with pytest.raises(ValueError, match="exclusive"):
             pallas_backproject_batch(vol0, filt, mats, GEOM, ty=4,
                                      chunk=16, band=16, width=128,

@@ -139,10 +139,9 @@ def test_resolve_kernel_fallback_and_hit(caplog):
         strategy="strip2", opts={}, backend=backend,
         device_kind=device_kind, us_per_call=1.0,
         pallas={"ty": 8, "chunk": 16, "band": 16, "width": 128,
-                "micro": True, "micro_group": 8, "micro_band": 12,
-                "micro_width": 64}))
+                "double_buffer": True, "db_depth": 3}))
     tiles = Dispatcher(insitu=False).resolve_kernel(GEOM)
-    assert tiles["micro"] and tiles["micro_band"] == 12
+    assert tiles["double_buffer"] and tiles["db_depth"] == 3
 
 
 # ----------------------------------------------------------------------

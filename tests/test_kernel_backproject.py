@@ -46,11 +46,9 @@ def test_kernel_shape_sweep(L, ty, chunk, band, width):
 
 @pytest.mark.parametrize("variant", [{"double_buffer": True},
                                      {"double_buffer": True,
-                                      "db_depth": 4},
-                                     {"micro": True}])
+                                      "db_depth": 4}])
 def test_kernel_variants_match_oracle(variant):
-    """CT-3 double-buffer (classical and deep rotation) and CT-5
-    micro-window vs the oracle."""
+    """Double-buffer (classical and deep rotation) vs the oracle."""
     geom, filt, mats = _problem(32, n_proj=4)
     gs = GeomStatic.of(geom)
     vol0 = jnp.zeros((32,) * 3, jnp.float32)
@@ -64,10 +62,9 @@ def test_kernel_variants_match_oracle(variant):
 
 @pytest.mark.parametrize("variant", [{}, {"double_buffer": True},
                                      {"double_buffer": True,
-                                      "db_depth": 3},
-                                     {"micro": True}])
+                                      "db_depth": 3}])
 def test_kernel_variants_border_rays_vs_scalar_oracle(variant):
-    """Interpret-mode parity of all three variants on the border-ray
+    """Interpret-mode parity of both variants on the border-ray
     geometry of tests/test_strategy_sweep.py: taps straddling the
     detector edge must blend with implicit zeros in the kernel too."""
     from repro.core.backproject import backproject_one
@@ -105,8 +102,8 @@ def test_kernel_dtype_sweep(img_dtype):
 
 
 def test_kernel_int8_wire_differs_but_bounded():
-    """int8 per-row affine codes on the kernel wire (plain / db /
-    micro): observably different from f32 (the quantisation is real),
+    """int8 per-row affine codes on the kernel wire (plain / db):
+    observably different from f32 (the quantisation is real),
     within ~2% of the volume scale (the post-gather f32 dequant +
     f32-accumulate contract), and **bitwise identical across variants**
     — every variant dequantises the same codes with the same per-row
@@ -119,7 +116,7 @@ def test_kernel_int8_wire_differs_but_bounded():
                                             geom, **base))
     scale = float(np.abs(f32).max())
     outs = []
-    for variant in ({}, {"double_buffer": True}, {"micro": True}):
+    for variant in ({}, {"double_buffer": True}):
         i8 = np.asarray(pallas_backproject_one(
             vol0, filt[k], mats[k], geom, strip_dtype="int8", **base,
             **variant))
@@ -149,64 +146,6 @@ def test_validate_rejects_undersized_strips():
     with pytest.raises(ValueError, match="does not cover"):
         validate_strip_config(geom, np.asarray(mats[0], np.float64),
                               ty=32, chunk=32, band=8, width=128)
-
-
-def test_micro_window_is_loud_or_correct():
-    """The micro variant's tap-drop hazard (the ``micro_band=4`` default,
-    same bug class as jnp strip2's old ``gband=4``): at L=48 the per-
-    group footprint outgrows a 4-row window, so an undersized micro
-    window must raise loudly at validation — and the bumped default must
-    validate *and* match the scalar oracle."""
-    from repro.core.backproject import backproject_one
-    from repro.core.geometry import projection_matrix
-
-    geom = Geometry().scaled(48, n_proj=4)
-    rng = np.random.default_rng(7)
-    image = jnp.asarray(rng.standard_normal((geom.n_v, geom.n_u)),
-                        jnp.float32)
-    A = jnp.asarray(projection_matrix(geom, 2.9), jnp.float32)
-    A64 = np.asarray(A, np.float64)
-
-    # Strip itself is large enough; the micro window is the problem.
-    validate_strip_config(geom, A64, ty=8, chunk=48, band=32, width=256)
-    with pytest.raises(ValueError, match="micro window"):
-        validate_strip_config(geom, A64, ty=8, chunk=48, band=32,
-                              width=256, micro=True, micro_band=4)
-    with pytest.raises(ValueError, match="micro window"):
-        pallas_backproject_one(
-            jnp.zeros((48,) * 3, jnp.float32), image, A, geom, ty=8,
-            chunk=48, band=32, width=256, micro=True, micro_band=4,
-            validate=True)
-
-    # Default micro window (8) validates and matches the oracle.
-    vol0 = jnp.zeros((48,) * 3, jnp.float32)
-    ref = np.asarray(backproject_one(vol0, image, A, geom,
-                                     strategy="scalar"))
-    assert np.abs(ref).max() > 0
-    out = np.asarray(pallas_backproject_one(
-        vol0, image, A, geom, ty=8, chunk=48, band=32, width=256,
-        micro=True, validate=True))
-    np.testing.assert_allclose(out, ref, rtol=1e-5, atol=1e-5)
-
-
-def test_kernel_variants_are_exclusive():
-    """micro + double_buffer on the single-projection path raises like
-    the batch path does — a tuned decision names exactly one variant,
-    so silently preferring either would misattribute its numbers."""
-    geom, filt, mats = _problem(16)
-    vol0 = jnp.zeros((16,) * 3, jnp.float32)
-    with pytest.raises(ValueError, match="exclusive"):
-        pallas_backproject_one(vol0, filt[0], mats[0], geom, ty=4,
-                               chunk=16, band=16, width=128, micro=True,
-                               double_buffer=True)
-
-
-def test_micro_group_must_divide_chunk():
-    geom, filt, mats = _problem(32)
-    with pytest.raises(ValueError, match="must divide"):
-        validate_strip_config(geom, np.asarray(mats[0], np.float64),
-                              ty=8, chunk=32, band=16, width=128,
-                              micro=True, micro_group=12)
 
 
 def test_gather_kernel_sweep():

@@ -128,23 +128,34 @@ def test_fits_vmem_equals_model_across_grid():
 
 
 def test_budget_sublane_table_matches_kernel_ops():
+    """The byte model prices the padded rows and strip windows the
+    kernel wrappers actually allocate, for every wire."""
     from repro.analysis.lint import budget as budget_mod
     from repro.kernels import backproject_ops
+    from repro.kernels.backproject import SUBLANE
 
-    assert budget_mod._SUBLANE == backproject_ops._SUBLANE
+    for itemsize in SUBLANE:
+        rows = backproject_ops._padded_dims(GS.n_v, GS.n_u, 16, 128,
+                                            itemsize)[0]
+        assert budget_mod._padded_rows(GS, 16, itemsize) == rows
+        assert rows % SUBLANE[itemsize] == 0
 
 
 def test_budget_int8_counts_scale_sideband():
-    """The 1-byte wire carries a (P, 2, rows) f32 sideband at
-    sublane-32 padded rows; wider wires carry none."""
+    """The 1-byte wire carries a (P, rows, 2) f32 sideband at
+    sublane-32 padded rows, lane-padded to 128 and double-buffered;
+    wider wires carry none."""
+    from repro.kernels.backproject import strip_window
+
     kw = dict(pbatch=4, ty=8, chunk=16, band=16, width=128)
     f32 = batch_vmem_estimate(GS, itemsize=4, **kw)
     int8 = batch_vmem_estimate(GS, itemsize=1, **kw)
     assert f32.scale_bytes == 0
-    rows = max(16, GS.n_v + 2)             # 32, already 32-aligned
+    wband, wwidth = strip_window(16, 128, 1)       # (64, 256)
+    rows = max(wband, GS.n_v + 2)
     rows += (-rows) % 32
-    assert int8.scale_bytes == 4 * 2 * rows * 4
-    assert int8.strip_bytes == f32.strip_bytes // 4
+    assert int8.scale_bytes == 2 * 4 * rows * 128 * 4
+    assert int8.strip_bytes == 4 * wband * wwidth
 
 
 def test_budget_screens_candidate_generator():

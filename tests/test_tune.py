@@ -191,59 +191,21 @@ def test_pallas_auto_uses_tuned_tiles(ct_case):
                                      band=16, width=128)
     np.testing.assert_array_equal(np.asarray(out_auto), np.asarray(out_fix))
 
-    # Tuned: auto picks the cached tile config (micro variant here).
+    # Tuned: auto picks the cached tile config (db variant here).
     backend, device_kind = device_identity()
     cfg = TunedConfig(strategy="strip2", opts={}, backend=backend,
                       device_kind=device_kind, us_per_call=1.0,
                       pallas={"ty": 8, "chunk": 16, "band": 16,
-                              "width": 128, "micro": True})
+                              "width": 128, "double_buffer": True})
     store_tuned(GS, cfg)
     out_auto = pallas_backproject_one(vol0, img, A, GEOM, strategy="auto")
     out_fix = pallas_backproject_one(vol0, img, A, GEOM, ty=8, chunk=16,
-                                     band=16, width=128, micro=True)
+                                     band=16, width=128,
+                                     double_buffer=True)
     np.testing.assert_array_equal(np.asarray(out_auto), np.asarray(out_fix))
 
     with pytest.raises(ValueError, match="fixed.*auto|auto.*fixed"):
         pallas_backproject_one(vol0, img, A, GEOM, strategy="strip")
-
-
-def test_pallas_auto_resolves_full_micro_window(ct_case):
-    """A tuned ``micro=True`` decision carries its validated
-    ``(micro_group, micro_band, micro_width)`` window through the cache
-    — auto used to resolve the flag but run default windows the sweep
-    never validated."""
-    from repro.tune import resolve_pallas_config
-    from repro.tune.space import pallas_candidates
-
-    # The swept micro candidate names its window explicitly, so the
-    # timed/validated values are the persisted values.
-    micro_cands = [c for c in pallas_candidates(GS)
-                   if dict(c.opts).get("micro")]
-    assert micro_cands
-    for c in micro_cands:
-        opts = dict(c.opts)
-        assert {"micro_group", "micro_band", "micro_width"} <= set(opts)
-
-    backend, device_kind = device_identity()
-    tuned_win = {"micro_group": 8, "micro_band": 12, "micro_width": 64}
-    cfg = TunedConfig(strategy="strip2", opts={}, backend=backend,
-                      device_kind=device_kind, us_per_call=1.0,
-                      pallas={"ty": 8, "chunk": 16, "band": 16,
-                              "width": 128, "micro": True, **tuned_win})
-    store_tuned(GS, cfg)
-    resolved = resolve_pallas_config(GS)
-    for k, v in tuned_win.items():
-        assert resolved[k] == v
-
-    filt, mats = ct_case
-    vol0 = jnp.zeros((GEOM.L,) * 3, jnp.float32)
-    img, A = jnp.asarray(filt[0]), jnp.asarray(mats[0])
-    out_auto = pallas_backproject_one(vol0, img, A, GEOM, strategy="auto")
-    out_fix = pallas_backproject_one(vol0, img, A, GEOM, ty=8, chunk=16,
-                                     band=16, width=128, micro=True,
-                                     **tuned_win)
-    np.testing.assert_array_equal(np.asarray(out_auto),
-                                  np.asarray(out_fix))
 
 
 def test_pallas_batch_auto_honors_tuned_variant_flags(ct_case):
@@ -307,33 +269,29 @@ def test_pallas_one_auto_resolves_tuned_db_depth(ct_case):
 
 
 def test_pallas_batch_candidates_cross_variants():
-    """The batched candidate family spans pbatch × {plain, db, micro},
-    every variant-bearing candidate naming its full surface (db_depth /
-    micro window) so the timed values are the persisted values, and
-    deep-rotation candidates pass the depth-aware VMEM check."""
+    """The batched candidate family spans pbatch × {plain, db}, every
+    db candidate naming its ``db_depth`` so the timed value is the
+    persisted value, and deep-rotation candidates pass the depth-aware
+    VMEM check."""
     from repro.tune.space import pallas_batch_fits_vmem, pallas_candidates
 
     cands = [dict(c.opts) for c in pallas_candidates(GS)]
     batched = [c for c in cands if c.get("pbatch", 1) > 1]
     assert any(c.get("double_buffer") for c in batched)
-    assert any(c.get("micro") for c in batched)
-    assert any(not c.get("double_buffer") and not c.get("micro")
-               for c in batched)
+    assert any(not c.get("double_buffer") for c in batched)
     for c in batched:
         if c.get("double_buffer"):
             assert c["db_depth"] >= 2
             assert pallas_batch_fits_vmem(
                 GS, pbatch=c["pbatch"], ty=c["ty"], chunk=c["chunk"],
                 band=c["band"], width=c["width"], depth=c["db_depth"])
-        if c.get("micro"):
-            assert {"micro_group", "micro_band", "micro_width"} <= set(c)
-        assert not (c.get("double_buffer") and c.get("micro"))
+        assert not (c.get("double_buffer") and c.get("shared_window"))
 
 
 def test_candidate_space_spans_new_axes():
     """The v4 axes compete: the jnp family proposes a bf16-wire strip2,
     the kernel family proposes bf16 and shared-window batch variants,
-    and no candidate combines the shared slab with db/micro."""
+    and no candidate combines the shared slab with db."""
     from repro.tune.space import jnp_candidates, pallas_candidates
 
     jnp_opts = [dict(c.opts) for c in jnp_candidates(GS)]
@@ -345,7 +303,7 @@ def test_candidate_space_spans_new_axes():
     assert shared and any(c.get("strip_dtype") == "bfloat16"
                           for c in shared)
     for c in shared:
-        assert not c.get("double_buffer") and not c.get("micro")
+        assert not c.get("double_buffer")
 
 
 def test_sweep_times_or_skips_shared_and_bf16():
