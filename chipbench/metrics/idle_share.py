@@ -1,0 +1,9 @@
+"""Percent of the traced window in which no operation ran on the device:
+one minus the union of the device's operation intervals over the
+window's host-clock length."""
+
+
+def read(run):
+    if run.trace is None:
+        return None
+    return 100.0 * (1.0 - run.trace["busy_s"] / run.work["window_s"])
