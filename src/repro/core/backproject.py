@@ -48,6 +48,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.quant import RowQuant, quantize_rows
 
 from .geometry import Geometry
@@ -772,14 +773,16 @@ def validate_strip_opts(geom: Geometry, matrices, strategy: str,
     key = (GeomStatic.of(geom), strategy, chunk, band, width,
            hashlib.sha1(mats.tobytes()).hexdigest())
     if key in _VALIDATED_STRIPS:
+        obs.count("planner.memo_hit", len(mats))
         return
     from .clipping import plan_strips
 
     need_band = need_width = 0
-    for A in mats:
-        plan = plan_strips(geom, A, chunk=chunk)
-        need_band = max(need_band, plan.required_band)
-        need_width = max(need_width, plan.required_width)
+    with obs.span("planner.check", units=len(mats)):
+        for A in mats:
+            plan = plan_strips(geom, A, chunk=chunk)
+            need_band = max(need_band, plan.required_band)
+            need_width = max(need_width, plan.required_width)
     # A full-detector window can never lose a tap: its origin clamps to 0
     # and it spans the whole padded image, so the planner's margin must
     # not push the requirement past the satisfiable maximum.

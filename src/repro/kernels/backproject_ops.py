@@ -19,6 +19,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core.backproject import (DEFAULT_PBATCH, GeomStatic,
                                     strip_wire_dtype)
 from repro.core.clipping import (_round8, _round128, plan_strips,
@@ -203,8 +204,9 @@ def pallas_backproject_one(volume, image, A, geom: Geometry | GeomStatic,
     if validate:
         if isinstance(geom, GeomStatic):
             raise ValueError("validate=True needs the full Geometry")
-        validate_strip_config(geom, np.asarray(A, np.float64), ty=ty,
-                              chunk=chunk, band=band, width=width)
+        with obs.span("planner.check"):
+            validate_strip_config(geom, np.asarray(A, np.float64), ty=ty,
+                                  chunk=chunk, band=band, width=width)
     return _run(jnp.asarray(volume), jnp.asarray(image),
                 jnp.asarray(A, jnp.float32), gs, ty, chunk, band, width,
                 double_buffer, int(db_depth), strip_dtype, _interpret())
@@ -267,9 +269,12 @@ def shared_window_dims(geom: Geometry, mats, *, ty: int, chunk: int,
     key = (gs, ty, chunk, pbatch,
            hashlib.sha1(mats64.tobytes()).hexdigest())
     need = _SHARED_REQS.get(key)
-    if need is None:
-        need = shared_window_requirement(geom, mats64, ty=ty, chunk=chunk,
-                                         pbatch=pbatch)
+    if need is not None:
+        obs.count("planner.memo_hit", len(mats64))
+    else:
+        with obs.span("planner.check", units=len(mats64)):
+            need = shared_window_requirement(geom, mats64, ty=ty,
+                                             chunk=chunk, pbatch=pbatch)
         if len(_SHARED_REQS) >= 4096:
             _SHARED_REQS.clear()
         _SHARED_REQS[key] = need
@@ -394,10 +399,13 @@ def pallas_backproject_batch(volume, images, mats,
         mats64 = np.asarray(mats, np.float64).reshape(-1, 3, 4)
         key = (gs, ty, chunk, band, width,
                hashlib.sha1(mats64.tobytes()).hexdigest())
-        if key not in _VALIDATED_STACKS:
-            for A in mats64:
-                validate_strip_config(geom, A, ty=ty, chunk=chunk,
-                                      band=band, width=width)
+        if key in _VALIDATED_STACKS:
+            obs.count("planner.memo_hit", len(mats64))
+        else:
+            with obs.span("planner.check", units=len(mats64)):
+                for A in mats64:
+                    validate_strip_config(geom, A, ty=ty, chunk=chunk,
+                                          band=band, width=width)
             if len(_VALIDATED_STACKS) >= 4096:
                 _VALIDATED_STACKS.clear()
             _VALIDATED_STACKS.add(key)

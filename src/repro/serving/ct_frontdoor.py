@@ -50,6 +50,7 @@ import time
 
 import numpy as np
 
+from repro import obs
 from repro.core.geometry import Geometry
 from repro.streaming import ProjectionChunk, ReconstructionEngine
 
@@ -98,7 +99,6 @@ class ScanTicket:
     deadline: float | None = None
     arrived: float = 0.0              # clock time open_scan admitted it
     admitted_at: float | None = None  # clock time it got a slot
-    first_submit: float | None = None
     finished_at: float | None = None
     state: str = "pending"            # pending | active | done | aborted
     sid: int | None = None            # backend scan id once active
@@ -473,14 +473,13 @@ class CTFrontDoor:
             raise ValueError(
                 f"scan {ticket.tid} declared {ticket.n_proj} projections; "
                 f"{ticket.received + k} submitted")
-        if ticket.first_submit is None:
-            ticket.first_submit = self._clock()
-        ticket.received += k
-        if ticket.state == "active":
-            self._backend.submit(ticket.sid, chunk)
-        else:
-            ticket.buffered.append(chunk)
-        self.pump()
+        with obs.span("frontdoor.submit", units=k, scan=ticket.tid):
+            ticket.received += k
+            if ticket.state == "active":
+                self._backend.submit(ticket.sid, chunk)
+            else:
+                ticket.buffered.append(chunk)
+            self.pump()
         await asyncio.sleep(0)
 
     async def result(self, ticket: ScanTicket, timeout: float | None = None):

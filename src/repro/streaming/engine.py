@@ -37,6 +37,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core.backproject import (GeomStatic, _backproject_batch_body,
                                     validate_strip_opts)
 from repro.core.filtering import FilterPlan, apply_filter, make_filter_plan
@@ -80,6 +81,13 @@ def _fold_slots(volumes, images, mats, mask, gs, plan):
 
     new = jax.vmap(one)(volumes, images, mats)
     return jnp.where(mask[:, None, None, None], new, volumes)
+
+
+def _bytes_in_use(arr):
+    """The device allocator's ``bytes_in_use`` where ``arr`` lives, or
+    ``None`` where the backend reports none."""
+    stats = next(iter(arr.devices())).memory_stats() or {}
+    return stats.get("bytes_in_use")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -295,10 +303,11 @@ class ReconstructionEngine:
             # The kernel fold path validates its own tile config at fold
             # time (pallas_backproject_batch(validate=...)).
             validate_strip_opts(self.geom, mats, self.strategy, self.opts)
-        filt = _filter_chunk(
-            projs, jnp.asarray(idx), self.plan.cosw, self.plan.hf,
-            self.plan.parker, pad=self.plan.pad, n_u=self.plan.n_u,
-            n_proj=self.plan.n_proj, scale=self.plan.scale)
+        with obs.span("engine.filter", units=k):
+            filt = _filter_chunk(
+                projs, jnp.asarray(idx), self.plan.cosw, self.plan.hf,
+                self.plan.parker, pad=self.plan.pad, n_u=self.plan.n_u,
+                n_proj=self.plan.n_proj, scale=self.plan.scale)
         mats32 = np.asarray(mats, np.float32)
         for i in range(k):
             scan.pending.append((filt[i], mats32[i]))
@@ -344,45 +353,55 @@ class ReconstructionEngine:
                     or (scan.complete and scan.pending):
                 ready.append((slot, scan))
         progressed = False
-        if ready and self._pallas_kwargs is not None:
-            # Tuned kernel fold: the Pallas batch winner, one launch per
-            # ready slot (zero-padded staging contributes exactly 0, so
-            # the static batch shape is shared with the jnp path).
-            from repro.kernels.backproject_ops import \
-                pallas_backproject_batch
-
-            for slot, scan in ready:
-                imgs, ms, n = self._take_batch(scan)
-                vol = pallas_backproject_batch(
-                    self._volumes[slot], imgs, ms, self.geom,
-                    validate=self.validate, **self._pallas_kwargs)
-                self._volumes = self._volumes.at[slot].set(vol)
-                scan.folded += n
-                self.stats["folds"] += n
-                self.stats["pallas_folds"] += n
-            self.stats["fold_ticks"] += 1
-            progressed = True
-        elif ready:
-            images = [self._zero_image[None].repeat(self.pbatch, axis=0)
-                      ] * self.n_slots
-            mats = [np.broadcast_to(np.eye(3, 4, dtype=np.float32),
-                                    (self.pbatch, 3, 4))] * self.n_slots
-            mask = np.zeros((self.n_slots,), bool)
-            for slot, scan in ready:
-                imgs, ms, n = self._take_batch(scan)
-                images[slot] = imgs
-                mats[slot] = ms
-                mask[slot] = True
-                scan.folded += n
-                self.stats["folds"] += n
-            self._volumes = _fold_slots(
-                self._volumes, jnp.stack(images),
-                jnp.asarray(np.stack(mats)), jnp.asarray(mask), self.gs,
-                self.exec_plan)
+        if ready:
+            n = sum(min(self.pbatch, len(scan.pending)) for _, scan in ready)
+            with obs.span("engine.fold", units=n, slots=len(ready)) as rec:
+                if rec is not None:
+                    rec["bytes_in_use_entry"] = _bytes_in_use(self._volumes)
+                if self._pallas_kwargs is not None:
+                    self._fold_kernel(ready)
+                else:
+                    self._fold_jnp(ready)
+                if rec is not None:
+                    rec["bytes_in_use_exit"] = _bytes_in_use(self._volumes)
             self.stats["fold_ticks"] += 1
             progressed = True
         progressed |= self._retire()
         return progressed
+
+    def _fold_kernel(self, ready) -> None:
+        """Tuned kernel fold: the Pallas batch winner, one launch per
+        ready slot (zero-padded staging contributes exactly 0, so the
+        static batch shape is shared with the jnp path)."""
+        from repro.kernels.backproject_ops import pallas_backproject_batch
+
+        for slot, scan in ready:
+            imgs, ms, n = self._take_batch(scan)
+            vol = pallas_backproject_batch(
+                self._volumes[slot], imgs, ms, self.geom,
+                validate=self.validate, **self._pallas_kwargs)
+            self._volumes = self._volumes.at[slot].set(vol)
+            scan.folded += n
+            self.stats["folds"] += n
+            self.stats["pallas_folds"] += n
+
+    def _fold_jnp(self, ready) -> None:
+        """All ready slots in one vmapped, slot-masked jitted call."""
+        images = [self._zero_image[None].repeat(self.pbatch, axis=0)
+                  ] * self.n_slots
+        mats = [np.broadcast_to(np.eye(3, 4, dtype=np.float32),
+                                (self.pbatch, 3, 4))] * self.n_slots
+        mask = np.zeros((self.n_slots,), bool)
+        for slot, scan in ready:
+            imgs, ms, n = self._take_batch(scan)
+            images[slot] = imgs
+            mats[slot] = ms
+            mask[slot] = True
+            scan.folded += n
+            self.stats["folds"] += n
+        self._volumes = _fold_slots(
+            self._volumes, jnp.stack(images), jnp.asarray(np.stack(mats)),
+            jnp.asarray(mask), self.gs, self.exec_plan)
 
     def _retire(self) -> bool:
         any_retired = False

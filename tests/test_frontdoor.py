@@ -318,3 +318,79 @@ def test_sharded_backend_rejects_duplicate_angles():
                                                     mats[idx], idx))
 
     asyncio.run(scenario())
+
+
+# ----------------------------------------------------------------------
+# Spans (repro.obs)
+# ----------------------------------------------------------------------
+
+def _traced_scan(tmp_path, mats, **door_opts):
+    """One scan of ``mats`` through a front door, in chunks of 2, under
+    a profiler capture; returns the spans and the volume."""
+    import jax
+
+    from repro import obs
+
+    projs = _DS[0]
+    fd = CTFrontDoor(GEOM, n_slots=1, **door_opts)
+
+    async def scenario():
+        ticket = await fd.open_scan(n_proj=GEOM.n_proj)
+        for c0 in range(0, GEOM.n_proj, 2):
+            idx = np.arange(c0, c0 + 2)
+            await fd.submit(ticket, ProjectionChunk(projs[idx], mats[idx],
+                                                    idx))
+        return await fd.result(ticket)
+
+    with jax.profiler.trace(str(tmp_path)):
+        vol = asyncio.run(scenario())
+    return obs.recorded(), np.asarray(vol)
+
+
+def _unseen(mats, shift):
+    """The matrices moved by a hair, so that no memo has seen them."""
+    mats = mats.copy()
+    mats[:, 2, 3] += shift
+    return mats
+
+
+def test_frontdoor_spans_hold_the_engine_jnp_path(tmp_path):
+    """On the jnp fold, every engine span sits inside
+    ``frontdoor.submit``, the fold's views add up to the views submitted,
+    and the planner checks the new stack directly under the front door."""
+    spans, out = _traced_scan(tmp_path, _unseen(_DS[1], 2e-3), pbatch=4)
+    doors = [r for r in spans if r[0] == "frontdoor.submit"]
+    assert [r[4] for r in doors] == [2, 2, 2]
+    assert {r[1] for r in doors} == {None}
+    assert all("scan" in r[5] for r in doors)
+    inner = [r for r in spans if r[0] != "frontdoor.submit"]
+    assert {(n, p) for n, p, *_ in inner} == {
+        ("engine.filter", "frontdoor.submit"),
+        ("engine.fold", "frontdoor.submit"),
+        ("planner.check", "frontdoor.submit")}
+    assert sum(r[4] for r in spans if r[0] == "engine.fold") == GEOM.n_proj
+    assert sum(r[4] for r in spans if r[0] == "planner.check") \
+        == GEOM.n_proj
+    assert 0 < np.abs(out).max()
+
+
+def test_frontdoor_spans_put_the_planner_inside_the_kernel_fold(tmp_path):
+    """With a kernel plan (interpret mode on the CPU), the planner's
+    check runs inside ``engine.fold``, and the fold's self time excludes
+    it."""
+    from repro import obs
+    from repro.dispatch import ExecutionPlan
+
+    plan = ExecutionPlan(strategy="gather", pbatch=2, use_pallas=True,
+                         pallas=(("band", 16), ("chunk", 16),
+                                 ("pbatch", 2), ("ty", 8),
+                                 ("width", 128)))
+    spans, out = _traced_scan(tmp_path, _unseen(_DS[1], 3e-3), plan=plan)
+    checks = [r for r in spans if r[0] == "planner.check"]
+    assert checks and {r[1] for r in checks} == {"engine.fold"}
+    assert sum(r[4] for r in checks) == GEOM.n_proj
+    assert sum(r[4] for r in spans if r[0] == "engine.fold") == GEOM.n_proj
+    s = obs.summary()
+    assert s["engine.fold"]["self_s"] == pytest.approx(
+        s["engine.fold"]["total_s"] - s["planner.check"]["total_s"])
+    assert np.isfinite(out).all() and 0 < np.abs(out).max()

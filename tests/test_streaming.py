@@ -12,7 +12,7 @@ import pytest
 
 from repro.core import Geometry, filter_projections, reconstruct
 from repro.core.phantom import make_dataset
-from repro.streaming import ReconstructionEngine
+from repro.streaming import ProjectionChunk, ReconstructionEngine
 
 GEOM = Geometry().scaled(16, n_proj=6)
 _DS = make_dataset(GEOM)
@@ -151,3 +151,61 @@ def test_streamed_auto_strategy_resolves(tmp_path, monkeypatch):
     eng.drain()
     np.testing.assert_allclose(np.asarray(eng.result(sid)), REF,
                                atol=1e-5, rtol=1e-5)
+
+
+# ----------------------------------------------------------------------
+# Spans (repro.obs)
+# ----------------------------------------------------------------------
+
+def test_engine_spans_under_a_capture(tmp_path):
+    """With the jnp fold, the engine records a filter span per submit
+    and fold spans whose views add up to the views submitted; the
+    planner check of a first-seen stack sits outside both."""
+    import jax
+
+    from repro import obs
+
+    projs, mats, _ = _DS
+    eng = ReconstructionEngine(GEOM, n_slots=2, pbatch=4)
+    sid = eng.begin_scan(n_proj=GEOM.n_proj)
+    with jax.profiler.trace(str(tmp_path)):
+        for c in (np.arange(0, 3), np.arange(3, 6)):
+            eng.submit(sid, ProjectionChunk(projs[c], mats[c], c))
+        eng.drain()                 # the remainder of 2 folds here
+    spans = obs.recorded()
+    s = obs.summary()
+    assert s["engine.filter"]["count"] == 2
+    assert s["engine.filter"]["units"] == GEOM.n_proj
+    assert s["engine.fold"]["count"] == 2
+    assert s["engine.fold"]["units"] == GEOM.n_proj
+    assert {p for n, p, *_ in spans if n != "planner.check"} == {None}
+    folds = [a for n, _, _, _, _, a in spans if n == "engine.fold"]
+    assert all(a["slots"] == 1 and "bytes_in_use_entry" in a
+               and "bytes_in_use_exit" in a for a in folds)
+    np.testing.assert_allclose(np.asarray(eng.result(sid)), REF,
+                               atol=1e-5, rtol=1e-5)
+
+
+def test_second_identical_stack_is_a_memo_hit(tmp_path):
+    """The planner checks a stack once: the same matrices again count a
+    ``planner.memo_hit`` and record no ``planner.check``."""
+    import jax
+
+    from repro import obs
+
+    projs, mats, _ = _DS
+    mats = mats.copy()
+    mats[:, 2, 3] += 1e-3          # a stack no other test has checked
+    eng = ReconstructionEngine(GEOM, n_slots=1, pbatch=4)
+    c = np.arange(GEOM.n_proj)
+    with jax.profiler.trace(str(tmp_path / "first")):
+        eng.submit(eng.begin_scan(), ProjectionChunk(projs, mats, c))
+    assert obs.summary()["planner.check"]["units"] == GEOM.n_proj
+    assert "planner.memo_hit" not in obs.summary()
+    with jax.profiler.trace(str(tmp_path / "again")):
+        eng.submit(eng.begin_scan(), ProjectionChunk(projs, mats, c))
+    s = obs.summary()
+    assert "planner.check" not in s
+    assert s["planner.memo_hit"] == {"count": 1, "units": GEOM.n_proj,
+                                     "total_s": 0.0, "self_s": 0.0}
+
