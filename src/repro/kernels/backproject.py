@@ -11,13 +11,20 @@ mechanisms the x86 kernels could only approximate:
    ``4 * TY * CHUNK`` scattered loads: this is the pairwise-load idea at
    DMA granularity.
 2. **MXU as texture unit** — per tile row the horizontal interpolation is
-   a one-hot matmul ``strip(band, width) @ colsel(width, CHUNK)`` on the
-   MXU; the vertical 2-tap blend runs as iota-compare/select plus a
-   sublane reduction on the VPU.  Voxels stay on the lanes throughout, so
-   no step needs a relayout.  Out-of-window one-hot entries are
-   identically zero, which (with the 1-pixel zero border added by ops.py)
-   gives exact zero-outside-detector semantics with *no* per-tap
-   conditionals — the paper's zero-padded buffer trick (section 5.1.1).
+   a one-hot matmul ``strip(band, 128) @ colsel(128, CHUNK)`` on the MXU
+   for each 128-lane column block of the strip that can hold one of the
+   tile's taps, summed over those blocks; the vertical 2-tap blend runs
+   as iota-compare/select plus a sublane reduction on the VPU.  The
+   blocks come from the same scalar corner geometry that places the DMA
+   (:func:`_strip_origin`, :func:`_col_blocks`): a tile's footprint is
+   far narrower than the window sized for the whole sweep, and every
+   block it skips would have contracted an all-zero selector, so the MXU
+   work follows the footprint and no result changes.  Voxels stay on the
+   lanes throughout, so no step needs a relayout.  Out-of-window one-hot
+   entries are identically zero, which (with the 1-pixel zero border
+   added by ops.py) gives exact zero-outside-detector semantics with *no*
+   per-tap conditionals — the paper's zero-padded buffer trick (section
+   5.1.1).
 3. **Grid pipelining instead of SMT** — KNC needed 4-way SMT to hide
    gather latency and still failed (section 6.4); here the volume-tile
    loads/stores are pipelined by the Pallas grid machinery, and the strip
@@ -116,23 +123,30 @@ def _part1_tile(A, o_mm, z, y0, x0, ty, chunk):
 
 def _strip_origin(A, o_mm, z, y0, x0, *, n_u, n_v, ty, chunk, band, width,
                   pad_rows, pad_cols, row_tile):
-    """Tile-aligned strip origin for a (ty, chunk) tile from its four
-    *corner* voxels.
+    """Tile-aligned strip origin and tap columns for a (ty, chunk) tile
+    from its four *corner* voxels.
 
     ``w`` is affine over the tile, so its minimum sits at a corner, and
     where ``w > 0`` both detector coordinates are monotone along each
     voxel axis — the tile extremes of ``ix``/``iy`` are corner values.
     Twelve scalar FMAs per corner replace a full ``(ty, chunk)`` Part-1
-    pass; prefetch and compute always agree because both sides call this
-    one helper.  The origin is clamped so the ``(band, width)`` window
-    stays in the padded image, then aligned down to the
+    pass; prefetch, wait and compute always agree because all of them
+    call this one helper.  The origin is clamped so the ``(band, width)``
+    window stays in the padded image, then aligned down to the
     ``(row_tile, 128)`` DMA tile (``pad_rows``/``band`` and
     ``pad_cols``/``width`` are tile multiples, so the clamp survives the
     alignment).
+
+    Returns ``(r0, c0, cols)``.  ``cols = (lo, hi)`` are padded columns
+    that bracket every tap of the tile: the taps of ``ix`` are
+    ``floor(ix) + 1`` and ``floor(ix) + 2``, and the bracket keeps one
+    more column on each side, so a voxel whose vector Part-1 ``ix``
+    rounds across an integer from the scalar corner value still lands
+    inside (:func:`_col_blocks`).
     """
     O, MM = o_mm
     wz = O + z.astype(jnp.float32) * MM
-    r_lo = c_lo = None
+    r_lo = c_lo = c_hi = None
     for dy in (0.0, float(ty - 1)):
         for dx in (0.0, float(chunk - 1)):
             wy = O + (y0 + dy) * MM
@@ -144,12 +158,29 @@ def _strip_origin(A, o_mm, z, y0, x0, *, n_u, n_v, ty, chunk, band, width,
             ix = jnp.clip(u * r, -1.0, jnp.float32(n_u))
             iy = jnp.clip(v * r, -1.0, jnp.float32(n_v))
             c_lo = ix if c_lo is None else jnp.minimum(c_lo, ix)
+            c_hi = ix if c_hi is None else jnp.maximum(c_hi, ix)
             r_lo = iy if r_lo is None else jnp.minimum(r_lo, iy)
+    lo = jnp.floor(c_lo).astype(jnp.int32)
+    hi = jnp.floor(c_hi).astype(jnp.int32) + 3
     r0 = jnp.clip(jnp.floor(r_lo).astype(jnp.int32), 0, pad_rows - band)
-    c0 = jnp.clip(jnp.floor(c_lo).astype(jnp.int32), 0, pad_cols - width)
+    c0 = jnp.clip(lo, 0, pad_cols - width)
     r0 = pl.multiple_of(jax.lax.div(r0, row_tile) * row_tile, row_tile)
     c0 = pl.multiple_of(jax.lax.div(c0, _LANE) * _LANE, _LANE)
-    return r0, c0
+    return r0, c0, (lo, hi)
+
+
+def _col_blocks(cols, c0, width):
+    """First and last 128-lane block of a ``width``-wide window at padded
+    column ``c0`` that hold any of the columns ``cols`` brackets.
+
+    Clamped to the window: a block outside it holds nothing to contract.
+    Every tap outside ``[kb_lo, kb_hi]`` selects an all-zero one-hot
+    entry, so contracting only these blocks changes no result.
+    """
+    lo, hi = cols
+    last = width // _LANE - 1
+    return (jnp.clip(jax.lax.div(lo - c0, _LANE), 0, last),
+            jnp.clip(jax.lax.div(hi - c0, _LANE), 0, last))
 
 
 def _tile_active(ix, iy, w, n_u, n_v):
@@ -171,7 +202,7 @@ def _dequant_strip(strip, scl_ref, r0, band, p=None):
     wire is not quantised and the strip passes through untouched — every
     variant calls this unconditionally and the f32 path traces to a
     no-op.  Only 1-byte codes ever move on the strip wire, and only the
-    resident ``(band, width)`` window widens to f32.
+    column blocks the tile contracts widen to f32.
     """
     if scl_ref is None:
         return strip
@@ -182,23 +213,42 @@ def _dequant_strip(strip, scl_ref, r0, band, p=None):
     return strip.astype(jnp.float32) * sc[:, 0:1] + sc[:, 1:2]
 
 
-def _tile_contrib(get_strip, ix, iy, r, r0, c0, *, ty, chunk, band, width):
+def _window_blocks(read, scl_ref, r0, band, p=None):
+    """Reader of a resident window's 128-lane column blocks.
+
+    ``read(cols)`` loads the window's ``(band, 128)`` columns ``cols``
+    from its VMEM ref; the returned ``block(kb)`` gives block ``kb``
+    decoded (:func:`_dequant_strip`) and in f32.
+    """
+    def block(kb):
+        cols = pl.ds(pl.multiple_of(kb * _LANE, _LANE), _LANE)
+        return _dequant_strip(read(cols), scl_ref, r0, band,
+                              p).astype(jnp.float32)
+    return block
+
+
+def _tile_contrib(get_blocks, ix, iy, r, r0, c0, blocks, *, ty, chunk,
+                  band):
     """Parts 2+3 for one (ty, chunk) tile against a resident
     ``(band, width)`` strip at padded origin ``(r0, c0)``.
 
     ``ix``/``iy``/``r`` are the tile's Part-1 arrays.  Row by row, with
-    the row's ``chunk`` voxels on the lanes: the horizontal 2-tap
-    selector ``colsel (width, chunk)`` is built by iota-compare, the MXU
-    contracts it with the strip into ``(band, chunk)``, and the vertical
-    2-tap selector ``rowsel (band, chunk)`` blends that with a sublane
-    reduction.  Taps outside the strip select all-zero one-hot entries
-    and vanish — with the zero border this is the exact
-    zero-outside-detector semantics.  The ``1/w²`` weight is folded in;
-    returns the f32 ``(ty, chunk)`` contribution.
+    the row's ``chunk`` voxels on the lanes: for each 128-lane column
+    block ``kb`` of ``blocks = (kb_lo, kb_hi)`` (:func:`_col_blocks`) the
+    horizontal 2-tap selector ``colsel (128, chunk)`` is built by
+    iota-compare and the MXU contracts it with the block into ``(band,
+    chunk)``, accumulated over the blocks; the vertical 2-tap selector
+    ``rowsel (band, chunk)`` blends the sum with a sublane reduction.
+    The blocks outside ``blocks`` hold none of the tile's taps, so their
+    products would be exactly zero and are skipped: the MXU work follows
+    the tile's footprint, not the window.  Taps outside the strip select
+    all-zero one-hot entries and vanish — with the zero border this is
+    the exact zero-outside-detector semantics.  The ``1/w²`` weight is
+    folded in; returns the f32 ``(ty, chunk)`` contribution.
 
-    ``get_strip`` is a zero-arg callable (wait on the strip DMA, read the
-    scratch) invoked once the first row's selectors are built, so the
-    copy overlaps the selector arithmetic.
+    ``get_blocks`` is a zero-arg callable (wait on the strip DMA, return
+    a :func:`_window_blocks` reader) invoked once the tap coordinates are
+    built, so the copy overlaps that arithmetic.
     """
     fx = jnp.floor(ix)
     fy = jnp.floor(iy)
@@ -208,23 +258,29 @@ def _tile_contrib(get_strip, ix, iy, r, r0, c0, *, ty, chunk, band, width):
     rel_c = fx.astype(jnp.int32) + 1 - c0
     rel_r = fy.astype(jnp.int32) + 1 - r0
     rw2 = r * r
+    kb_lo, kb_hi = blocks
     biota = jax.lax.broadcasted_iota(jnp.int32, (band, chunk), 0)
-    wiota = jax.lax.broadcasted_iota(jnp.int32, (width, chunk), 0)
+    liota = jax.lax.broadcasted_iota(jnp.int32, (_LANE, chunk), 0)
     yiota = jax.lax.broadcasted_iota(jnp.int32, (ty, chunk), 0)
-    strip = None
+    block = get_blocks()
     out = jnp.zeros((ty, chunk), jnp.float32)
     for y in range(ty):
         row = slice(y, y + 1)
-        colsel = jnp.where(wiota == rel_c[row], 1.0 - sx[row],
-                           jnp.where(wiota == rel_c[row] + 1, sx[row], 0.0))
+
+        def contract(kb, colmix, c=rel_c[row], s=sx[row]):
+            # MXU: horizontal interpolation of every strip row of block
+            # kb at once; c - kb*128 is the tap's lane within the block.
+            c = c - kb * _LANE
+            colsel = jnp.where(liota == c, 1.0 - s,
+                               jnp.where(liota == c + 1, s, 0.0))
+            return colmix + jax.lax.dot_general(
+                block(kb), colsel, (((1,), (0,)), ((), ())),
+                precision=_PRECISION, preferred_element_type=jnp.float32)
+
+        colmix = jax.lax.fori_loop(kb_lo, kb_hi + 1, contract,
+                                   jnp.zeros((band, chunk), jnp.float32))
         rowsel = jnp.where(biota == rel_r[row], 1.0 - sy[row],
                            jnp.where(biota == rel_r[row] + 1, sy[row], 0.0))
-        if strip is None:
-            strip = get_strip().astype(jnp.float32)
-        # MXU: horizontal interpolation of every strip row at once.
-        colmix = jax.lax.dot_general(
-            strip, colsel, (((1,), (0,)), ((), ())), precision=_PRECISION,
-            preferred_element_type=jnp.float32)            # (band, chunk)
         val = jnp.sum(colmix * rowsel, axis=0, keepdims=True) * rw2[row]
         out = jnp.where(yiota == y, val, out)
     return out
@@ -254,7 +310,7 @@ def backproject_kernel(A_ref, img_ref, *refs,
 
     ix, iy, w, r = _part1_tile(A, o_mm, z, y0, x0, ty, chunk)
     active = _tile_active(ix, iy, w, n_u, n_v)
-    r0, c0 = _strip_origin(
+    r0, c0, cols = _strip_origin(
         A, o_mm, z, y0, x0, n_u=n_u, n_v=n_v, ty=ty, chunk=chunk,
         band=band, width=width, pad_rows=img_ref.shape[0],
         pad_cols=img_ref.shape[1], row_tile=_row_tile(img_ref))
@@ -268,10 +324,12 @@ def backproject_kernel(A_ref, img_ref, *refs,
 
         def strip():
             copy.wait()
-            return _dequant_strip(strip_ref[...], scl_ref, r0, band)
+            return _window_blocks(lambda cols: strip_ref[:, cols], scl_ref,
+                                  r0, band)
 
-        contrib = _tile_contrib(strip, ix, iy, r, r0, c0, ty=ty,
-                                chunk=chunk, band=band, width=width)
+        contrib = _tile_contrib(strip, ix, iy, r, r0, c0,
+                                _col_blocks(cols, c0, width), ty=ty,
+                                chunk=chunk, band=band)
         # --- Part 3: inverse-square-law weighted accumulate -------------
         vol_out_ref[...] = vol_in_ref[...] + contrib.astype(
             vol_in_ref.dtype)[None]
@@ -332,7 +390,7 @@ def backproject_kernel_db(A_ref, img_ref, *refs,
         rest = jax.lax.div(t, nc)
         yn = jax.lax.rem(rest, ny)
         zn = jax.lax.div(rest, ny)
-        r0n, c0n = origin(zn, yn, cn)
+        r0n, c0n, _ = origin(zn, yn, cn)
         s = jax.lax.rem(t, depth)
         pltpu.make_async_copy(
             img_ref.at[pl.ds(r0n, band), pl.ds(c0n, width)],
@@ -340,7 +398,7 @@ def backproject_kernel_db(A_ref, img_ref, *refs,
 
     ix, iy, w, r = _part1_tile(A, o_mm, z, y0, x0, ty, chunk)
     active = _tile_active(ix, iy, w, n_u, n_v)
-    r0, c0 = origin(z, yb, cb)
+    r0, c0, cols = origin(z, yb, cb)
 
     # First step primes the whole lookahead window.
     @pl.when(step == 0)
@@ -362,10 +420,12 @@ def backproject_kernel_db(A_ref, img_ref, *refs,
     def _():
         def strip():
             wait_strip()
-            return _dequant_strip(strip_ref[slot], scl_ref, r0, band)
+            return _window_blocks(lambda cols: strip_ref[slot, :, cols],
+                                  scl_ref, r0, band)
 
-        contrib = _tile_contrib(strip, ix, iy, r, r0, c0, ty=ty,
-                                chunk=chunk, band=band, width=width)
+        contrib = _tile_contrib(strip, ix, iy, r, r0, c0,
+                                _col_blocks(cols, c0, width), ty=ty,
+                                chunk=chunk, band=band)
         vol_out_ref[...] = vol_in_ref[...] + contrib.astype(
             vol_in_ref.dtype)[None]
 
@@ -426,12 +486,12 @@ def backproject_kernel_batch(A_ref, imgs_ref, *refs,
             strip_ref.at[slot], sems.at[slot]).start()
 
     acc_ref[...] = vol_in_ref[0].astype(jnp.float32)
-    start_dma(0, *origin(0), 0)
+    start_dma(0, *origin(0)[:2], 0)
 
     def body(p, _):
         # Projection p's strip is in flight; the wait recomputes the
         # origin its issuer used.
-        r0, c0 = origin(p)
+        r0, c0, cols = origin(p)
         slot = jax.lax.rem(p, 2)
 
         # Prefetch projection p+1's strip into the other slot while p's
@@ -439,7 +499,7 @@ def backproject_kernel_batch(A_ref, imgs_ref, *refs,
         # in-bounds on the last iteration; the DMA only starts when a
         # next projection exists.
         pn = jnp.minimum(p + 1, pbatch - 1)
-        r0n, c0n = origin(pn)
+        r0n, c0n, _ = origin(pn)
 
         @pl.when(p + 1 < pbatch)
         def _():
@@ -458,12 +518,13 @@ def backproject_kernel_batch(A_ref, imgs_ref, *refs,
         def _():
             def strip():
                 wait_strip()
-                return _dequant_strip(strip_ref[slot], scl_ref, r0, band,
-                                      p)
+                return _window_blocks(
+                    lambda cols: strip_ref[slot, :, cols], scl_ref, r0,
+                    band, p)
 
             acc_ref[...] += _tile_contrib(
-                strip, ix, iy, r, r0, c0, ty=ty, chunk=chunk, band=band,
-                width=width)
+                strip, ix, iy, r, r0, c0, _col_blocks(cols, c0, width),
+                ty=ty, chunk=chunk, band=band)
 
         @pl.when(jnp.logical_not(active))
         def _():
@@ -536,9 +597,9 @@ def backproject_kernel_batch_db(A_ref, imgs_ref, *refs,
         rest = jax.lax.div(s, nc)
         yn = jax.lax.rem(rest, ny)
         zn = jax.lax.div(rest, ny)
-        r0, c0 = origin(_read_A(A_ref, p), zn,
-                        (yn * ty).astype(jnp.float32),
-                        (cn * chunk).astype(jnp.float32))
+        r0, c0, _ = origin(_read_A(A_ref, p), zn,
+                           (yn * ty).astype(jnp.float32),
+                           (cn * chunk).astype(jnp.float32))
         slot = jax.lax.rem(t, depth)
         pltpu.make_async_copy(
             imgs_ref.at[p, pl.ds(r0, band), pl.ds(c0, width)],
@@ -569,7 +630,7 @@ def backproject_kernel_batch_db(A_ref, imgs_ref, *refs,
         # geometry — the issuer (iteration t - depth + 1) computed the
         # identical corner origin, producer and consumer agreeing by
         # construction.
-        r0, c0 = origin(A, z, y0, x0)
+        r0, c0, cols = origin(A, z, y0, x0)
         slot = jax.lax.rem(t, depth)
 
         def wait_strip():
@@ -581,12 +642,13 @@ def backproject_kernel_batch_db(A_ref, imgs_ref, *refs,
         def _():
             def strip():
                 wait_strip()
-                return _dequant_strip(strip_ref[slot], scl_ref, r0,
-                                      band, p)
+                return _window_blocks(
+                    lambda cols: strip_ref[slot, :, cols], scl_ref, r0,
+                    band, p)
 
             acc_ref[...] += _tile_contrib(
-                strip, ix, iy, r, r0, c0, ty=ty, chunk=chunk, band=band,
-                width=width)
+                strip, ix, iy, r, r0, c0, _col_blocks(cols, c0, width),
+                ty=ty, chunk=chunk, band=band)
 
         @pl.when(jnp.logical_not(active))
         def _():
@@ -628,12 +690,15 @@ def backproject_kernel_batch_shared(A_ref, imgs_ref, *refs,
     pad_rows = imgs_ref.shape[1]
     pad_cols = imgs_ref.shape[2]
 
+    def origin(A):
+        return _strip_origin(
+            A, o_mm, z, y0, x0, n_u=n_u, n_v=n_v, ty=ty, chunk=chunk,
+            band=band, width=width, pad_rows=pad_rows, pad_cols=pad_cols,
+            row_tile=_row_tile(imgs_ref))
+
     r0s = c0s = None
     for p in range(pbatch):
-        r0p, c0p = _strip_origin(
-            _read_A(A_ref, p), o_mm, z, y0, x0, n_u=n_u, n_v=n_v, ty=ty,
-            chunk=chunk, band=band, width=width, pad_rows=pad_rows,
-            pad_cols=pad_cols, row_tile=_row_tile(imgs_ref))
+        r0p, c0p, _ = origin(_read_A(A_ref, p))
         r0s = r0p if r0s is None else jnp.minimum(r0s, r0p)
         c0s = c0p if c0s is None else jnp.minimum(c0s, c0p)
 
@@ -649,12 +714,15 @@ def backproject_kernel_batch_shared(A_ref, imgs_ref, *refs,
         ix, iy, w, r = _part1_tile(A, o_mm, z, y0, x0, ty, chunk)
         active = _tile_active(ix, iy, w, n_u, n_v)
 
+        # The member's own tap columns, in the shared window's blocks.
+        blocks = _col_blocks(origin(A)[2], c0s, width)
+
         @pl.when(active)
         def _():
             acc_ref[...] += _tile_contrib(
-                lambda: _dequant_strip(win_ref[p], scl_ref, r0s, band, p),
-                ix, iy, r, r0s, c0s, ty=ty, chunk=chunk, band=band,
-                width=width)
+                lambda: _window_blocks(lambda cols: win_ref[p, :, cols],
+                                       scl_ref, r0s, band, p),
+                ix, iy, r, r0s, c0s, blocks, ty=ty, chunk=chunk, band=band)
         return 0
 
     jax.lax.fori_loop(0, pbatch, body, 0)

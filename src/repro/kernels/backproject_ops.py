@@ -26,11 +26,12 @@ from repro.core.clipping import (_round8, _round128, plan_strips,
                                  shared_window_requirement)
 from repro.core.geometry import Geometry
 
-from .backproject import (SUBLANE, backproject_volume_pallas,
+from .backproject import (_EPS_W, _LANE, SUBLANE, backproject_volume_pallas,
                           backproject_volume_pallas_batch, strip_window)
 
 __all__ = ["pallas_backproject_one", "pallas_backproject_batch",
-           "validate_strip_config", "shared_window_dims", "clamp_tiles"]
+           "validate_strip_config", "shared_window_dims", "clamp_tiles",
+           "tile_col_blocks"]
 
 
 def _interpret() -> bool:
@@ -132,6 +133,78 @@ def validate_strip_config(geom: Geometry, A: np.ndarray, *, ty: int,
             f"width={need_width}) for ty={ty}, chunk={chunk}")
 
 
+def tile_col_blocks(gs: GeomStatic, A, *, ty: int, chunk: int, band: int,
+                    width: int):
+    """The kernel's column-block rule for every tile of one projection.
+
+    The host's float32 copy of the kernel's corner rule
+    (:func:`repro.kernels.backproject._strip_origin` and ``_col_blocks``),
+    vectorised over the ``(L, L // ty, L // chunk)`` tiles: each tile's
+    four corner voxels give its tap columns, the window's aligned column
+    ``c0`` and the first and last 128-column block of the window that the
+    kernel contracts.  ``band``/``width`` are the footprint dims the
+    wrappers take; the window is :func:`strip_window` of them.  Returns
+    ``(active, c0, kb_lo, kb_hi)``, each shaped by tile; ``active`` is the
+    kernel's tile test, on the corners.
+    """
+    f32 = np.float32
+    A = np.asarray(A, f32).reshape(3, 4)
+    O, MM = f32(gs.O), f32(gs.MM)
+    wz = (O + np.arange(gs.L, dtype=f32) * MM)[:, None, None]
+    y0 = np.arange(0, gs.L, ty, dtype=f32)[:, None]
+    x0 = np.arange(0, gs.L, chunk, dtype=f32)
+    ext = None                 # per tile: min/max of ix and iy, max of w
+    for dy in (0, ty - 1):
+        wy = O + (y0 + f32(dy)) * MM
+        for dx in (0, chunk - 1):
+            wx = O + (x0 + f32(dx)) * MM
+
+            def affine(a):     # (L, ny, nc), in the kernel's order
+                out = wz * a[2] + (wx * a[0] + wy * a[1])
+                out += a[3]
+                return out
+
+            w = affine(A[2])
+            with np.errstate(divide="ignore"):
+                r = np.reciprocal(w)
+            r[w <= _EPS_W] = 0
+            ix = affine(A[0])
+            ix *= r
+            iy = affine(A[1])
+            iy *= r
+            if ext is None:
+                ext = [ix, ix.copy(), iy, iy.copy(), w]
+            else:
+                for acc, val, op in zip(ext, (ix, ix, iy, iy, w),
+                                        (np.minimum, np.maximum) * 2
+                                        + (np.maximum,)):
+                    op(acc, val, out=acc)
+    ix_lo, ix_hi, iy_lo, iy_hi, w_hi = ext
+    active = ((ix_lo < gs.n_u) & (ix_hi > -1) & (iy_lo < gs.n_v)
+              & (iy_hi > -1) & (w_hi > _EPS_W))
+    # Clipping commutes with the corner min/max: clip once per tile.
+    lo = np.floor(np.clip(ix_lo, -1, gs.n_u)).astype(np.int64)
+    hi = np.floor(np.clip(ix_hi, -1, gs.n_u)).astype(np.int64) + 3
+    wwidth = strip_window(band, width, 4)[1]
+    pad_cols = _padded_dims(gs.n_v, gs.n_u, band, width, 4)[1]
+    c0 = np.clip(lo, 0, pad_cols - wwidth) // _LANE * _LANE
+    last = wwidth // _LANE - 1
+    return (active, c0, np.clip((lo - c0) // _LANE, 0, last),
+            np.clip((hi - c0) // _LANE, 0, last))
+
+
+def _count_col_blocks(gs: GeomStatic, A, *, ty: int, chunk: int, band: int,
+                      width: int) -> None:
+    """Count the column blocks the kernel contracts for one projection
+    (``kernel.col_blocks``) and those of the whole window over the same
+    active tiles (``kernel.col_blocks_window``)."""
+    active, _, kb_lo, kb_hi = tile_col_blocks(gs, A, ty=ty, chunk=chunk,
+                                              band=band, width=width)
+    obs.count("kernel.col_blocks", int((kb_hi - kb_lo + 1)[active].sum()))
+    obs.count("kernel.col_blocks_window",
+              int(active.sum()) * (strip_window(band, width, 4)[1] // _LANE))
+
+
 @functools.partial(
     jax.jit,
     static_argnames=("gs", "ty", "chunk", "band", "width",
@@ -204,9 +277,12 @@ def pallas_backproject_one(volume, image, A, geom: Geometry | GeomStatic,
     if validate:
         if isinstance(geom, GeomStatic):
             raise ValueError("validate=True needs the full Geometry")
-        with obs.span("planner.check"):
+        with obs.span("planner.check") as rec:
             validate_strip_config(geom, np.asarray(A, np.float64), ty=ty,
                                   chunk=chunk, band=band, width=width)
+            if rec is not None:
+                _count_col_blocks(gs, A, ty=ty, chunk=chunk, band=band,
+                                  width=width)
     return _run(jnp.asarray(volume), jnp.asarray(image),
                 jnp.asarray(A, jnp.float32), gs, ty, chunk, band, width,
                 double_buffer, int(db_depth), strip_dtype, _interpret())
@@ -402,9 +478,12 @@ def pallas_backproject_batch(volume, images, mats,
         if key in _VALIDATED_STACKS:
             obs.count("planner.memo_hit", len(mats64))
         else:
-            with obs.span("planner.check", units=len(mats64)):
+            with obs.span("planner.check", units=len(mats64)) as rec:
                 for A in mats64:
                     validate_strip_config(geom, A, ty=ty, chunk=chunk,
+                                          band=band, width=width)
+                    if rec is not None:
+                        _count_col_blocks(gs, A, ty=ty, chunk=chunk,
                                           band=band, width=width)
             if len(_VALIDATED_STACKS) >= 4096:
                 _VALIDATED_STACKS.clear()

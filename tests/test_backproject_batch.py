@@ -22,6 +22,8 @@ from repro.core.phantom import make_dataset
 from repro.kernels.backproject_ops import pallas_backproject_batch
 from repro.kernels.backproject_ref import backproject_volume_ref
 
+from _col_blocks import CASES, assert_case_holds, assert_matches_oracle
+
 GEOM = Geometry().scaled(16, n_proj=5)           # 5: prime vs pbatch 2, 3
 GS = GeomStatic.of(GEOM)
 
@@ -230,6 +232,27 @@ def test_pallas_batch_variants_border_rays(variant):
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=1e-5, atol=1e-5)
     assert (np.asarray(ref) == 0.0).any() and (np.asarray(ref) != 0.0).any()
+
+
+@pytest.mark.parametrize("wire", ["float32", "bfloat16", "int8"])
+@pytest.mark.parametrize("variant", [
+    dict(),
+    dict(double_buffer=True, db_depth=3),
+    dict(shared_window=True),
+], ids=["batch", "batch_db3", "batch_shared"])
+@pytest.mark.parametrize("case", list(CASES))
+def test_pallas_batch_column_block_seams_vs_oracle(case, variant, wire):
+    """Column-block skipping in every batch variant: a tap pair across a
+    128-column block boundary (``seam``) and a tile spanning every block
+    of the window (``wide``), two projections folded in one call."""
+    for k in range(2):
+        assert_case_holds(case, k)
+    c = CASES[case]
+    images, mats = c.images(), c.mats()
+    vol0 = jnp.zeros((c.geom.L,) * 3, jnp.float32)
+    out = pallas_backproject_batch(vol0, images, mats, c.geom, pbatch=2,
+                                   strip_dtype=wire, **c.tiles, **variant)
+    assert_matches_oracle(out, c, images, mats, wire)
 
 
 def test_pallas_batch_variant_flags_are_loud(ct_case):

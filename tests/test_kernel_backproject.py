@@ -18,6 +18,8 @@ from repro.kernels.backproject_ops import (pallas_backproject_one,
                                            validate_strip_config)
 from repro.kernels.backproject_ref import backproject_volume_ref
 
+from _col_blocks import CASES, assert_case_holds, assert_matches_oracle
+
 
 def _problem(L, n_proj=2):
     geom = Geometry().scaled(L, n_proj=n_proj)
@@ -126,6 +128,26 @@ def test_kernel_int8_wire_differs_but_bounded():
         outs.append(i8)
     for other in outs[1:]:
         np.testing.assert_array_equal(outs[0], other)
+
+
+@pytest.mark.parametrize("wire", ["float32", "bfloat16", "int8"])
+@pytest.mark.parametrize("variant", [{}, {"double_buffer": True,
+                                          "db_depth": 3}],
+                         ids=["plain", "db3"])
+@pytest.mark.parametrize("case", list(CASES))
+def test_kernel_column_block_seams_vs_oracle(case, variant, wire):
+    """The kernel contracts only the window's 128-column blocks that hold
+    the tile's taps: a tap pair across a block boundary (``seam``) and a
+    tile spanning every block (``wide``) still match the oracle."""
+    c = CASES[case]
+    images, mats = c.images(), c.mats()
+    out = jnp.zeros((c.geom.L,) * 3, jnp.float32)
+    for k in range(len(mats)):
+        assert_case_holds(case, k)
+        out = pallas_backproject_one(out, images[k], mats[k], c.geom,
+                                     strip_dtype=wire, validate=True,
+                                     **c.tiles, **variant)
+    assert_matches_oracle(out, c, images, mats, wire)
 
 
 def test_kernel_accumulates_over_projections():
