@@ -13,10 +13,12 @@ and Listing 1 of the paper) on a few sampled z-planes:
   ``repro.api.CTFrontDoor`` as ``ProjectionChunk``s of one scan, the
   engine folding with the kernel as a tuned TPU deployment does.
 
-``--four-chips`` instead runs only the mesh path users take for 1024³:
+``--four-chips`` instead runs only the one-shot mesh path:
 ``sharded_reconstruct(prefiltered=False)`` on a 2x2 mesh (``data`` =
 z-slabs, ``model`` = projection shards), and shows the volume is spread
-over all four devices.
+over all four devices.  (Streamed scans at 1024³ go through
+``CTFrontDoor(mesh=...)``, which the benchmark's ``rabbitct-1024.mesh``
+cell runs.)
 
 The geometry is RabbitCT's (1248x960 detector, 200° short scan, L=512 on
 one chip, L=1024 on four); the projections are the analytic phantom's,

@@ -31,8 +31,10 @@ tile-aligned).
   double-buffered like every blocked input), its 2-wide minor dim
   padded to the 128-lane tile: ``2 · pbatch · rows · 128 · 4`` with
   ``rows`` the *padded* row count.
-* **SMEM** — the ``(pbatch, 3, 4)`` f32 matrix stack (reported, never
-  binding: SMEM is KBs and the stack is tiny).
+* **SMEM** — the ``(pbatch, 3, 4)`` f32 matrix stack and the
+  scalar-prefetched ``(z0, row0)`` int32 pair that places a z-slab
+  (reported, never binding: SMEM is KBs and both are tiny).  A slab
+  changes only the grid's plane count, none of these terms.
 
 ``compiled.memory_analysis()`` of a kernel program reports HBM
 arguments and temporaries only (its temp bytes are 0 for these
@@ -121,7 +123,7 @@ def batch_vmem_estimate(gs: GeomStatic, *, pbatch: int, ty: int,
     onehot = 2 * chunk * (wband + wwidth) * 4 + wband * wwidth * 4
     scales = (2 * pbatch * _padded_rows(gs, band, itemsize) * 128 * 4
               if itemsize == 1 else 0)
-    smem = pbatch * 3 * 4 * 4
+    smem = pbatch * 3 * 4 * 4 + 2 * 4
     return VmemEstimate(strip_bytes=strips, tile_bytes=tile,
                         onehot_bytes=onehot, scale_bytes=scales,
                         smem_bytes=smem)

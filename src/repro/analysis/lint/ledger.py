@@ -343,7 +343,9 @@ class ReplayCase:
     ``kind`` selects the ref layout and promised depth; ``n_slots`` is
     the scratch rotation size the ledger bounds peak in-flight copies
     by, ``promised`` the depth the variant claims to sustain (``None``
-    for variants whose DMAs are conditional on tile activity).
+    for variants whose DMAs are conditional on tile activity).  The
+    batch kinds replay a z-slab of the grid's planes starting at global
+    plane ``z0`` (the scalar-prefetched ``(z0, row0)`` pair).
     """
 
     name: str
@@ -351,6 +353,7 @@ class ReplayCase:
     pbatch: int = 1
     depth: int = 2
     quantized: bool = False
+    z0: int = 0            # the slab's first global plane (batch kinds)
 
     @property
     def n_slots(self) -> int:
@@ -455,6 +458,9 @@ def replay(case: ReplayCase, kernel_fn=None, extra_modules=()) -> Ledger:
             vol_out = StubRef(np.zeros((1, _TY, _CHUNK), np.float32),
                               "vol_out")
             refs = [A_ref, img_ref]
+            if batched:
+                refs.insert(0, StubRef(np.array([case.z0, 0], np.int32),
+                                       "zrow"))
             if scl_ref is not None:
                 refs.append(scl_ref)
             refs += [vol_in, vol_out, strip_ref]
@@ -481,6 +487,13 @@ def builtin_cases() -> list:
         for depth in (2, 3, 4):
             cases.append(ReplayCase(f"batch_db_p{pb}_d{depth}",
                                     "batch_db", pbatch=pb, depth=depth))
+    # The grid's planes as the upper slab of a cube twice as deep.
+    z0 = _GRID[0]
+    cases += [ReplayCase(f"batch_p4_z{z0}", "batch", pbatch=4, z0=z0),
+              ReplayCase(f"batch_db_p4_d3_z{z0}", "batch_db", pbatch=4,
+                         depth=3, z0=z0),
+              ReplayCase(f"batch_shared_p4_z{z0}", "batch_shared",
+                         pbatch=4, z0=z0)]
     return cases
 
 
@@ -506,7 +519,8 @@ def replay_fixture(path: str):
                       kind=spec["kind"],
                       pbatch=int(spec.get("pbatch", 1)),
                       depth=int(spec.get("depth", 2)),
-                      quantized=bool(spec.get("quantized", False)))
+                      quantized=bool(spec.get("quantized", False)),
+                      z0=int(spec.get("z0", 0)))
     ledger = replay(case, kernel_fn=mod.kernel, extra_modules=(mod,))
     return _ledger_findings(f"{path}:{case.name}", ledger), ledger
 

@@ -437,14 +437,17 @@ def backproject_kernel_db(A_ref, img_ref, *refs,
         vol_out_ref[...] = vol_in_ref[...]
 
 
-def backproject_kernel_batch(A_ref, imgs_ref, *refs,
+def backproject_kernel_batch(zrow_ref, A_ref, imgs_ref, *refs,
                              o_mm, n_u, n_v, ty, chunk, band, width,
                              pbatch, quantized=False):
     """Projection-batched grid step: the ``(1, ty, chunk)`` volume tile
     stays resident in VMEM while an in-kernel ``fori_loop`` folds in
     ``pbatch`` projections — the inverted loop nest (DESIGN.md §7).
 
-    Refs: ``A_ref`` stacked ``(pbatch, 3, 4)`` f32 in SMEM; ``imgs_ref``
+    Refs: ``zrow_ref`` the scalar-prefetched ``(z0, row0)`` int32 pair
+    (:func:`backproject_volume_pallas_batch`; the grid's first axis runs
+    over the slab's planes, and plane ``i`` is global plane ``z0 + i``);
+    ``A_ref`` stacked ``(pbatch, 3, 4)`` f32 in SMEM; ``imgs_ref``
     stacked zero-padded projections ``(pbatch, rows, cols)`` in ANY/HBM;
     with ``quantized=True`` a ``(pbatch, rows, 2)`` scale block in VMEM
     follows (``imgs_ref`` then holds int8 codes); then the aliased
@@ -467,7 +470,7 @@ def backproject_kernel_batch(A_ref, imgs_ref, *refs,
     if quantized:
         scl_ref, *refs = refs
     vol_in_ref, vol_out_ref, strip_ref, acc_ref, sems = refs
-    z = pl.program_id(0)
+    z = zrow_ref[0] + pl.program_id(0)
     y0 = (pl.program_id(1) * ty).astype(jnp.float32)
     x0 = (pl.program_id(2) * chunk).astype(jnp.float32)
     pad_rows = imgs_ref.shape[1]
@@ -536,7 +539,7 @@ def backproject_kernel_batch(A_ref, imgs_ref, *refs,
     vol_out_ref[...] = acc_ref[...].astype(vol_out_ref.dtype)[None]
 
 
-def backproject_kernel_batch_db(A_ref, imgs_ref, *refs,
+def backproject_kernel_batch_db(zrow_ref, A_ref, imgs_ref, *refs,
                                 o_mm, n_u, n_v, ty, chunk, band, width,
                                 pbatch, depth, grid_dims, quantized=False):
     """Deep-pipelined batched grid step: the strip DMA stream runs
@@ -559,7 +562,9 @@ def backproject_kernel_batch_db(A_ref, imgs_ref, *refs,
     one projection's compute.
 
     Refs as :func:`backproject_kernel_batch`, except ``strip_ref`` is
-    ``(depth, band, width)`` and ``sems`` ``depth`` DMA semaphores.
+    ``(depth, band, width)`` and ``sems`` ``depth`` DMA semaphores.  The
+    sequence runs over the slab's own grid; a future tile's strip is
+    placed at its global plane.
     Issue/wait counts balance by construction: exactly one DMA is
     issued and one waited per sequence index (`t < total` guards both
     ends), and every wait recomputes the same origin the issuer used.
@@ -569,10 +574,12 @@ def backproject_kernel_batch_db(A_ref, imgs_ref, *refs,
         scl_ref, *refs = refs
     vol_in_ref, vol_out_ref, strip_ref, acc_ref, sems = refs
     nz, ny, nc = grid_dims
-    z = pl.program_id(0)
+    zb = zrow_ref[0]                   # the slab's first global plane
+    zl = pl.program_id(0)
+    z = zb + zl
     yb = pl.program_id(1)
     cb = pl.program_id(2)
-    step = (z * ny + yb) * nc + cb
+    step = (zl * ny + yb) * nc + cb
     t0 = step * pbatch
     total = nz * ny * nc * pbatch
     y0 = (yb * ty).astype(jnp.float32)
@@ -597,7 +604,7 @@ def backproject_kernel_batch_db(A_ref, imgs_ref, *refs,
         rest = jax.lax.div(s, nc)
         yn = jax.lax.rem(rest, ny)
         zn = jax.lax.div(rest, ny)
-        r0, c0, _ = origin(_read_A(A_ref, p), zn,
+        r0, c0, _ = origin(_read_A(A_ref, p), zb + zn,
                            (yn * ty).astype(jnp.float32),
                            (cn * chunk).astype(jnp.float32))
         slot = jax.lax.rem(t, depth)
@@ -659,7 +666,7 @@ def backproject_kernel_batch_db(A_ref, imgs_ref, *refs,
     vol_out_ref[...] = acc_ref[...].astype(vol_out_ref.dtype)[None]
 
 
-def backproject_kernel_batch_shared(A_ref, imgs_ref, *refs,
+def backproject_kernel_batch_shared(zrow_ref, A_ref, imgs_ref, *refs,
                                     o_mm, n_u, n_v, ty, chunk, band,
                                     width, pbatch, quantized=False):
     """Shared-superset-window batched grid step: ONE window DMA per
@@ -684,7 +691,7 @@ def backproject_kernel_batch_shared(A_ref, imgs_ref, *refs,
     if quantized:
         scl_ref, *refs = refs
     vol_in_ref, vol_out_ref, win_ref, acc_ref, sem = refs
-    z = pl.program_id(0)
+    z = zrow_ref[0] + pl.program_id(0)
     y0 = (pl.program_id(1) * ty).astype(jnp.float32)
     x0 = (pl.program_id(2) * chunk).astype(jnp.float32)
     pad_rows = imgs_ref.shape[1]
@@ -815,15 +822,25 @@ def backproject_volume_pallas_batch(volume, padded_imgs, A_stack, *, o_mm,
                                     n_u, n_v, ty=8, chunk=128, band=16,
                                     width=512, double_buffer=False,
                                     db_depth=2, shared_window=False,
-                                    scales=None, interpret=False):
-    """``pallas_call`` wrapper: one *batch* of projections into the whole
-    volume, volume tile resident across the in-kernel projection loop.
+                                    scales=None, z0=0, row0=0,
+                                    planes=None, interpret=False):
+    """``pallas_call`` wrapper: one *batch* of projections into a volume
+    or a z-slab of one, volume tile resident across the in-kernel
+    projection loop.
 
-    ``padded_imgs``: stacked zero-padded projections ``(pbatch, rows,
-    cols)`` (rows/cols already rounded up by ops.py); ``A_stack``:
-    ``(pbatch, 3, 4)`` matrices.  Returns the updated volume (input
-    aliased).  Volume HBM traffic per call: one load + one store of
-    ``L³`` — a ``pbatch``× cut versus ``pbatch`` calls of
+    ``volume`` is ``(rows, L, L)``: the kernel folds its ``planes``
+    planes from row ``row0`` on (default: all of them), and those hold
+    the global planes ``z0, z0 + 1, ...`` of the ``L³`` volume, so one
+    slab of a z-sharded volume, or one slot of a stack of slabs seen as
+    ``(slots * planes, L, L)``, folds in place.  ``z0`` and ``row0`` are
+    runtime scalars, prefetched to SMEM: one compiled kernel serves every
+    slab and slot of a shape, and ``z0 = row0 = 0`` over a whole cube is
+    the cube's kernel.  ``padded_imgs``: stacked zero-padded projections
+    ``(pbatch, rows, cols)`` (rows/cols already rounded up by ops.py);
+    ``A_stack``: ``(pbatch, 3, 4)`` matrices.  Returns the updated volume
+    (input aliased: rows outside the folded planes keep their values).
+    Volume HBM traffic per call: one load + one store of the folded
+    planes — a ``pbatch``× cut versus ``pbatch`` calls of
     :func:`backproject_volume_pallas`.
 
     ``double_buffer=True`` selects the deep DMA pipeline
@@ -838,16 +855,21 @@ def backproject_volume_pallas_batch(volume, padded_imgs, A_stack, *, o_mm,
     ``scales`` selects the int8 wire exactly as in
     :func:`backproject_volume_pallas`, stacked ``(pbatch, rows, 2)``.
     """
-    L = volume.shape[0]
+    L = volume.shape[-1]
+    planes = volume.shape[0] if planes is None else int(planes)
     pbatch = int(A_stack.shape[0])
+    assert volume.shape[1:] == (L, L) and volume.shape[0] >= planes
     assert L % ty == 0 and L % chunk == 0
     assert padded_imgs.shape[0] == pbatch
-    grid = (L, L // ty, L // chunk)
+    grid = (planes, L // ty, L // chunk)
     quantized = scales is not None
     band, width = strip_window(band, width, padded_imgs.dtype.itemsize)
     _check_window(padded_imgs.shape, band, width)
+    zrow = jnp.stack([jnp.asarray(z0, jnp.int32),
+                      jnp.asarray(row0, jnp.int32)])
 
-    vol_spec = pl.BlockSpec((1, ty, chunk), lambda z, y, x: (z, y, x))
+    vol_spec = pl.BlockSpec((1, ty, chunk),
+                            lambda z, y, x, zr: (zr[1] + z, y, x))
     if shared_window and double_buffer:
         raise ValueError(
             f"batch kernel variants are exclusive: got double_buffer="
@@ -890,22 +912,21 @@ def backproject_volume_pallas_batch(volume, padded_imgs, A_stack, *, o_mm,
         pl.BlockSpec(memory_space=pltpu.SMEM),       # A stack (P, 3, 4)
         pl.BlockSpec(memory_space=pl.ANY),           # padded images (HBM)
     ]
-    args = [A_stack, padded_imgs]
+    args = [zrow, A_stack, padded_imgs]              # zrow: prefetched
     if quantized:
         # Whole (P, rows, 2) scale block VMEM-resident per call.
         in_specs.append(
-            pl.BlockSpec(scales.shape, lambda z, y, x: (0, 0, 0)))
+            pl.BlockSpec(scales.shape, lambda z, y, x, zr: (0, 0, 0)))
         args.append(scales)
         name += "_int8"
     in_specs.append(vol_spec)                        # volume tile in
     args.append(volume)
     return pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=vol_spec,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=grid, in_specs=in_specs,
+            out_specs=vol_spec, scratch_shapes=scratch),
         out_shape=jax.ShapeDtypeStruct(volume.shape, volume.dtype),
-        scratch_shapes=scratch,
         input_output_aliases={len(args) - 1: 0},
         interpret=interpret,
         name=name,

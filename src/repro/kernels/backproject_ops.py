@@ -30,8 +30,8 @@ from .backproject import (_EPS_W, _LANE, SUBLANE, backproject_volume_pallas,
                           backproject_volume_pallas_batch, strip_window)
 
 __all__ = ["pallas_backproject_one", "pallas_backproject_batch",
-           "validate_strip_config", "shared_window_dims", "clamp_tiles",
-           "tile_col_blocks"]
+           "validate_batch", "validate_strip_config", "shared_window_dims",
+           "clamp_tiles", "tile_col_blocks"]
 
 
 def _interpret() -> bool:
@@ -194,15 +194,18 @@ def tile_col_blocks(gs: GeomStatic, A, *, ty: int, chunk: int, band: int,
 
 
 def _count_col_blocks(gs: GeomStatic, A, *, ty: int, chunk: int, band: int,
-                      width: int) -> None:
+                      width: int, slabs: int = 1) -> np.ndarray:
     """Count the column blocks the kernel contracts for one projection
     (``kernel.col_blocks``) and those of the whole window over the same
-    active tiles (``kernel.col_blocks_window``)."""
+    active tiles (``kernel.col_blocks_window``); returns the contracted
+    blocks of each of ``slabs`` equal z-slabs."""
     active, _, kb_lo, kb_hi = tile_col_blocks(gs, A, ty=ty, chunk=chunk,
                                               band=band, width=width)
-    obs.count("kernel.col_blocks", int((kb_hi - kb_lo + 1)[active].sum()))
+    blocks = np.where(active, kb_hi - kb_lo + 1, 0)
+    obs.count("kernel.col_blocks", int(blocks.sum()))
     obs.count("kernel.col_blocks_window",
               int(active.sum()) * (strip_window(band, width, 4)[1] // _LANE))
+    return blocks.reshape(slabs, -1).sum(axis=1)
 
 
 @functools.partial(
@@ -292,10 +295,10 @@ def pallas_backproject_one(volume, image, A, geom: Geometry | GeomStatic,
     jax.jit,
     static_argnames=("gs", "ty", "chunk", "band", "width", "pbatch",
                      "double_buffer", "db_depth", "shared_window",
-                     "strip_dtype", "interpret"))
+                     "strip_dtype", "interpret", "planes"))
 def _run_batched(volume, images, mats, gs: GeomStatic, ty, chunk, band,
                  width, pbatch, double_buffer, db_depth, shared_window,
-                 strip_dtype, interpret):
+                 strip_dtype, interpret, z0=0, row0=0, planes=None):
     from repro.core.backproject import _stream_batches
 
     # With shared_window the (band, width) passed here are already the
@@ -310,7 +313,8 @@ def _run_batched(volume, images, mats, gs: GeomStatic, ty, chunk, band,
             vol, codes, A, o_mm=(gs.O, gs.MM), n_u=gs.n_u, n_v=gs.n_v,
             ty=ty, chunk=chunk, band=band, width=width,
             double_buffer=double_buffer, db_depth=db_depth,
-            shared_window=shared_window, scales=scl, interpret=interpret)
+            shared_window=shared_window, scales=scl, z0=z0, row0=row0,
+            planes=planes, interpret=interpret)
 
     return _stream_batches((padded, scales), mats, volume, pbatch, call)
 
@@ -381,10 +385,19 @@ def pallas_backproject_batch(volume, images, mats,
                              shared_width: int | None = None,
                              strip_dtype: str = "float32",
                              validate: bool = True,
-                             strategy: str = "fixed"):
+                             strategy: str = "fixed", z0=0, slot=None):
     """Add a stack of projections to ``volume``, ``pbatch`` per kernel
     launch, with the volume tile resident in VMEM across the in-kernel
     projection loop (DESIGN.md §7).
+
+    ``volume`` is the ``(L, L, L)`` cube, or a z-slab ``(S, L, L)`` of it
+    whose first plane is the cube's plane ``z0``; with ``slot`` it is a
+    stack ``(n, S, L, L)`` of such slabs and only ``volume[slot]``
+    folds, in place.  ``z0`` and ``slot`` may be traced scalars (one
+    compiled kernel for every slab), so a ``shard_map`` body folds its
+    rank's slab with ``validate=False`` after the host checked the stack
+    (:func:`validate_batch`): the check covers the whole cube, and so
+    every slab of it.
 
     ``images``: unpadded ``(n_proj, n_v, n_u)`` filtered projections —
     padded once for the whole stack; ``mats``: ``(n_proj, 3, 4)``.
@@ -472,23 +485,55 @@ def pallas_backproject_batch(volume, images, mats,
     elif validate:
         if isinstance(geom, GeomStatic):
             raise ValueError("validate=True needs the full Geometry")
-        mats64 = np.asarray(mats, np.float64).reshape(-1, 3, 4)
-        key = (gs, ty, chunk, band, width,
-               hashlib.sha1(mats64.tobytes()).hexdigest())
-        if key in _VALIDATED_STACKS:
-            obs.count("planner.memo_hit", len(mats64))
-        else:
-            with obs.span("planner.check", units=len(mats64)) as rec:
-                for A in mats64:
-                    validate_strip_config(geom, A, ty=ty, chunk=chunk,
-                                          band=band, width=width)
-                    if rec is not None:
-                        _count_col_blocks(gs, A, ty=ty, chunk=chunk,
-                                          band=band, width=width)
-            if len(_VALIDATED_STACKS) >= 4096:
-                _VALIDATED_STACKS.clear()
-            _VALIDATED_STACKS.add(key)
-    return _run_batched(jnp.asarray(volume), images, mats_f32, gs, ty,
-                        chunk, band, width, pbatch, double_buffer,
-                        int(db_depth), shared_window, strip_dtype,
-                        _interpret())
+        validate_batch(geom, mats, ty=ty, chunk=chunk, band=band,
+                       width=width)
+    volume = jnp.asarray(volume)
+    if slot is None:
+        return _run_batched(volume, images, mats_f32, gs, ty, chunk, band,
+                            width, pbatch, double_buffer, int(db_depth),
+                            shared_window, strip_dtype, _interpret(),
+                            z0=z0)
+    # One slot of a stack of slabs, folded in place: the stack seen as
+    # rows of planes, the slot's rows from slot * planes on.
+    planes = volume.shape[1]
+    out = _run_batched(volume.reshape((-1,) + volume.shape[2:]), images,
+                       mats_f32, gs, ty, chunk, band, width, pbatch,
+                       double_buffer, int(db_depth), shared_window,
+                       strip_dtype, _interpret(), z0=z0,
+                       row0=slot * planes, planes=planes)
+    return out.reshape(volume.shape)
+
+
+def validate_batch(geom: Geometry, mats, *, ty: int, chunk: int,
+                   band: int, width: int, slabs: int = 1) -> None:
+    """Check a projection stack against the host planner for the batch
+    kernel's (already clamped) tile config, memoised per stack.
+
+    A slab's tiles are the cube's own, so the whole cube's check covers
+    every slab.  While a capture runs the check also counts the column
+    blocks the kernel contracts (``kernel.col_blocks``, the whole cube)
+    and, for a cube split into ``slabs`` equal z-slabs, the largest
+    slab's blocks over the stack (``mesh.col_blocks_max_slab``): the
+    MXU work of the chip that finishes a fold last.
+    """
+    gs = GeomStatic.of(geom)
+    mats64 = np.asarray(mats, np.float64).reshape(-1, 3, 4)
+    key = (gs, ty, chunk, band, width,
+           hashlib.sha1(mats64.tobytes()).hexdigest())
+    if key in _VALIDATED_STACKS:
+        obs.count("planner.memo_hit", len(mats64))
+        return
+    with obs.span("planner.check", units=len(mats64)) as rec:
+        per_slab = np.zeros(slabs, np.int64)
+        for A in mats64:
+            validate_strip_config(geom, A, ty=ty, chunk=chunk, band=band,
+                                  width=width)
+            if rec is not None:
+                per_slab += _count_col_blocks(gs, A, ty=ty, chunk=chunk,
+                                              band=band, width=width,
+                                              slabs=slabs)
+        if rec is not None and slabs > 1:
+            obs.count("mesh.col_blocks_max_slab", int(per_slab.max()))
+    if len(_VALIDATED_STACKS) >= 4096:
+        _VALIDATED_STACKS.clear()
+    _VALIDATED_STACKS.add(key)

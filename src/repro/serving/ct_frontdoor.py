@@ -30,11 +30,12 @@ mid-scan without leaking a slot.  This module is that tier
   aborts an in-flight one (``ReconstructionEngine.abort_scan`` retires
   the slot, zeroes it, and refills), so abort-then-reuse of a slot is
   bit-clean.
-* **Sharded mode.** With a ``mesh``, completed scans run
-  :func:`repro.core.pipeline.sharded_reconstruct(prefiltered=False)`,
-  which drives ``reconstruct_shards(..., z0=rank_slab)`` per rank — one
-  scan's volume spans the ``data`` mesh axis while the front door still
-  does admission, backpressure, and cancellation.
+* **Mesh mode.** With a ``mesh``, the engine behind the front door
+  z-shards its slot stack over the mesh's ``data`` axis and each chip
+  folds every streamed view into its own slab with the Pallas kernel
+  (:class:`repro.streaming.ReconstructionEngine` ``mesh=``): a volume
+  too large for one chip streams exactly as a small one does, with the
+  same admission, backpressure and cancellation.
 
 Concurrency model: single event loop, cooperative.  Device work is
 dispatched inline (JAX's async dispatch overlaps it with host code);
@@ -47,8 +48,6 @@ from __future__ import annotations
 import asyncio
 import dataclasses
 import time
-
-import numpy as np
 
 from repro import obs
 from repro.core.geometry import Geometry
@@ -248,7 +247,8 @@ def _resolve_policy(policy) -> AdmissionPolicy:
 # Backends: where admitted scans actually reconstruct
 # ----------------------------------------------------------------------
 class _EngineBackend:
-    """Single-process slot machine: the streaming ReconstructionEngine."""
+    """The slot machine: the streaming ReconstructionEngine, on one chip
+    or z-sharded over a mesh."""
 
     def __init__(self, engine: ReconstructionEngine):
         self.engine = engine
@@ -260,9 +260,6 @@ class _EngineBackend:
     @property
     def free_slots(self) -> int:
         return self.engine.free_slots
-
-    def validate_declared(self, n_proj: int) -> None:
-        pass                        # any positive length streams fine
 
     def begin(self, n_proj: int) -> int:
         return self.engine.begin_scan(n_proj=n_proj)
@@ -283,92 +280,6 @@ class _EngineBackend:
         self.engine.abort_scan(sid)
 
 
-class _ShardedBackend:
-    """Mesh path: one scan's volume spans the ``data`` axis.
-
-    Chunks stage host-side by *global angle index*; when the full scan
-    is in, :func:`repro.core.pipeline.sharded_reconstruct` runs with
-    ``prefiltered=False`` — each rank FDK-filters its projection subset
-    in-shard and ``reconstruct_shards(..., z0=rank_slab)`` back-projects
-    its z-slab, so filtering scales with the ``proj`` axes and the
-    volume with ``data``.  The in-shard filter needs the whole scan
-    (Parker rows by global angle index), so sharded scans must declare
-    ``n_proj == geom.n_proj`` and each angle may arrive exactly once.
-
-    ``n_slots`` here bounds how many scans may stage concurrently — the
-    same admission currency as the engine backend, with host staging
-    memory (``n_proj * n_v * n_u * 4`` bytes per scan) as the resource.
-    """
-
-    def __init__(self, geom: Geometry, mesh, *, n_slots: int = 2,
-                 volume_axis: str = "data",
-                 proj_axes: tuple[str, ...] = ("model",),
-                 strategy: str = "strip2", pbatch: int | None = None,
-                 short_scan: bool | None = None, **opts):
-        self.geom = geom
-        self.mesh = mesh
-        self.n_slots = int(n_slots)
-        self._recon_kw = dict(strategy=strategy, volume_axis=volume_axis,
-                              proj_axes=tuple(proj_axes), pbatch=pbatch,
-                              prefiltered=False, short_scan=short_scan,
-                              **opts)
-        self._staged: dict[int, dict] = {}
-        self._next_sid = 0
-
-    @property
-    def free_slots(self) -> int:
-        return max(0, self.n_slots - len(self._staged))
-
-    def validate_declared(self, n_proj: int) -> None:
-        if n_proj != self.geom.n_proj:
-            raise ValueError(
-                f"sharded mode filters in-shard by global angle index, so "
-                f"scans must be full: declared n_proj={n_proj}, geometry "
-                f"has {self.geom.n_proj}")
-
-    def begin(self, n_proj: int) -> int:
-        self.validate_declared(n_proj)
-        sid = self._next_sid
-        self._next_sid += 1
-        g = self.geom
-        self._staged[sid] = {
-            "projs": np.zeros((g.n_proj, g.n_v, g.n_u), np.float32),
-            "mats": np.zeros((g.n_proj, 3, 4), np.float32),
-            "seen": np.zeros((g.n_proj,), bool),
-        }
-        return sid
-
-    def submit(self, sid: int, chunk: ProjectionChunk) -> None:
-        st = self._staged[sid]
-        projs, mats, idx = chunk.arrays()
-        if idx.min() < 0 or idx.max() >= self.geom.n_proj:
-            raise ValueError(
-                f"angle indices must lie in [0, {self.geom.n_proj})")
-        if st["seen"][idx].any() or len(set(idx.tolist())) != len(idx):
-            raise ValueError(
-                "sharded mode takes each angle index exactly once; "
-                f"duplicate in {idx.tolist()}")
-        st["projs"][idx] = np.asarray(projs, np.float32)
-        st["mats"][idx] = np.asarray(mats, np.float32)
-        st["seen"][idx] = True
-
-    def pump(self) -> None:
-        pass                        # nothing incremental to advance
-
-    def poll(self, sid: int):
-        from repro.core.pipeline import sharded_reconstruct
-
-        st = self._staged.get(sid)
-        if st is None or not st["seen"].all():
-            return None
-        del self._staged[sid]
-        return sharded_reconstruct(st["projs"], st["mats"], self.geom,
-                                   self.mesh, **self._recon_kw)
-
-    def abort(self, sid: int) -> None:
-        self._staged.pop(sid, None)
-
-
 # ----------------------------------------------------------------------
 # The front door
 # ----------------------------------------------------------------------
@@ -382,9 +293,9 @@ class CTFrontDoor:
 
     ``open_scan`` raises :class:`Backpressure` (with ``retry_after``)
     when no slot is free and ``max_pending`` tickets already wait —
-    bounded queues all the way down.  ``mesh=...`` selects the sharded
-    backend; otherwise a :class:`ReconstructionEngine` is built from
-    ``engine_opts`` (or pass a prebuilt one as ``engine=``).
+    bounded queues all the way down.  A :class:`ReconstructionEngine`
+    is built from ``engine_opts``, z-sharded over ``mesh`` when one is
+    given (or pass a prebuilt one as ``engine=``).
     """
 
     def __init__(self, geom: Geometry, *, n_slots: int = 4,
@@ -397,16 +308,12 @@ class CTFrontDoor:
         self.policy = _resolve_policy(policy)
         self.max_pending = int(max_pending)
         self._clock = clock
-        if mesh is not None:
-            if engine is not None:
-                raise ValueError("pass engine= or mesh=, not both")
-            self._backend = _ShardedBackend(geom, mesh, n_slots=n_slots,
-                                            **engine_opts)
-        else:
-            if engine is None:
-                engine = ReconstructionEngine(geom, n_slots=n_slots,
-                                              **engine_opts)
-            self._backend = _EngineBackend(engine)
+        if engine is not None and mesh is not None:
+            raise ValueError("pass engine= or mesh=, not both")
+        if engine is None:
+            engine = ReconstructionEngine(geom, n_slots=n_slots, mesh=mesh,
+                                          **engine_opts)
+        self._backend = _EngineBackend(engine)
         self._pending: list[ScanTicket] = []      # arrival order
         self._active: dict[int, ScanTicket] = {}
         self._next_tid = 0
@@ -435,10 +342,6 @@ class CTFrontDoor:
         n = int(n_proj) if n_proj is not None else self.geom.n_proj
         if n <= 0:
             raise ValueError(f"n_proj must be positive, got {n_proj!r}")
-        # A declared length the backend can never serve must fail the
-        # *opening* client here — not surface mid-pump out of whichever
-        # call happens to admit it later.
-        self._backend.validate_declared(n)
         if self._backend.free_slots <= 0 \
                 and len(self._pending) >= self.max_pending:
             self.stats["rejected"] += 1

@@ -20,7 +20,12 @@ of :class:`repro.serving.engine.ServingEngine`:
   B scans in flight cost one compiled step, mirroring the LM engine's
   ``_masked_decode_step`` slot discipline;
 * finished scans retire, their slot is zeroed and immediately refilled
-  from the admission queue (continuous batching).
+  from the admission queue (continuous batching);
+* with a ``mesh`` the slot stack is z-sharded over the mesh's volume
+  axis (``P(None, vol)``, the paper's plane decomposition): each chunk
+  is placed on every chip and filtered there, and each chip folds every
+  view into its own slab with the Pallas batch kernel, in place (the
+  stack is donated), so no reduction is ever needed (DESIGN.md §5).
 
 Summation order within a volume follows arrival order, so a streamed
 result matches the one-shot :func:`repro.core.backproject.reconstruct`
@@ -36,6 +41,7 @@ import warnings
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro import obs
 from repro.core.backproject import (GeomStatic, _backproject_batch_body,
@@ -83,6 +89,81 @@ def _fold_slots(volumes, images, mats, mask, gs, plan):
     return jnp.where(mask[:, None, None, None], new, volumes)
 
 
+@functools.partial(jax.jit, static_argnames=("gs", "kernel", "mesh", "axis"),
+                   donate_argnums=0)
+def _fold_slabs(volumes, slot, images, mats, gs, kernel, mesh, axis):
+    """Fold one ``pbatch`` batch into slot ``slot`` of a z-sharded stack.
+
+    Every chip folds the replicated batch into its own ``(S, L, L)``
+    slab of the slot with the Pallas batch kernel, at its slab's first
+    global plane; the stack is donated and the kernel aliases it, so the
+    slot is updated in place and nothing else is copied.  ``kernel`` is
+    the kernel's keyword options as sorted items.  Returns the stack and
+    a small array read from the fold's output (one value per chip),
+    which a caller may wait on: the stack itself is donated by the next
+    fold.
+    """
+    from repro.kernels.backproject_ops import pallas_backproject_batch
+
+    def body(vols, slot, imgs, ms):
+        z0 = jax.lax.axis_index(axis) * vols.shape[1]
+        vols = pallas_backproject_batch(vols, imgs, ms, gs, validate=False,
+                                        z0=z0, slot=slot, **dict(kernel))
+        mark = jax.lax.dynamic_slice(vols, (slot, 0, 0, 0), (1, 1, 1, 1))
+        return vols, mark.reshape(1)
+
+    return jax.shard_map(
+        body, mesh=mesh, in_specs=(P(None, axis), P(), P(), P()),
+        out_specs=(P(None, axis), P(axis)), check_vma=False)(
+            volumes, slot, images, mats)
+
+
+@functools.partial(jax.jit, static_argnames=("mesh", "axis", "take"),
+                   donate_argnums=0)
+def _clear_slab_slot(volumes, slot, mesh, axis, take):
+    """Zero slot ``slot`` of a z-sharded stack in place (the stack is
+    donated); with ``take`` also return the slot's volume as it was,
+    sharded ``P(vol)``."""
+
+    def body(vols, slot):
+        zero = jnp.zeros(vols.shape[1:], vols.dtype)
+        out = jax.lax.dynamic_update_index_in_dim(vols, zero, slot, 0)
+        if not take:
+            return out
+        return jax.lax.dynamic_index_in_dim(vols, slot, 0,
+                                            keepdims=False), out
+
+    out_specs = ((P(axis), P(None, axis)) if take else P(None, axis))
+    return jax.shard_map(body, mesh=mesh, in_specs=(P(None, axis), P()),
+                         out_specs=out_specs, check_vma=False)(volumes,
+                                                               slot)
+
+
+def _slab_axis(mesh, L: int) -> str:
+    """The mesh axis the volume's z-planes are split over (the ``vol``
+    logical axis); raises where the mesh asks for a split the engine
+    does not make."""
+    from repro.dist.sharding import ShardingRules, logical_to_spec
+
+    rules = ShardingRules()
+    axis = logical_to_spec(("vol",), rules, mesh)[0]
+    if not isinstance(axis, str):
+        raise ValueError(
+            f"the mesh engine splits the volume over one mesh axis "
+            f"{rules.vol}; mesh axes {mesh.axis_names} give {axis!r}")
+    other = {a: n for a, n in dict(mesh.shape).items()
+             if a != axis and n > 1}
+    if other:
+        raise ValueError(
+            f"the mesh engine splits only the volume's z-planes (over "
+            f"{axis!r}); a split over {other} (the projections) is not "
+            f"supported: use a mesh whose other axes have size 1")
+    if L % mesh.shape[axis]:
+        raise ValueError(f"L={L} does not split into {mesh.shape[axis]} "
+                         f"z-slabs")
+    return axis
+
+
 def _bytes_in_use(arr):
     """The device allocator's ``bytes_in_use`` where ``arr`` lives, or
     ``None`` where the backend reports none."""
@@ -113,9 +194,15 @@ class ProjectionChunk:
         shape = np.shape(self.projections)
         return 1 if len(shape) == 2 else int(shape[0])
 
-    def arrays(self):
-        """Normalise to ``(k, n_v, n_u) f32, (k, 3, 4) f64, (k,) i32``."""
-        projs = jnp.asarray(self.projections, jnp.float32)
+    def arrays(self, sharding=None):
+        """Normalise to ``(k, n_v, n_u) f32, (k, 3, 4) f64, (k,) i32``;
+        ``sharding`` places the projections (default: JAX's default
+        device)."""
+        if sharding is None:
+            projs = jnp.asarray(self.projections, jnp.float32)
+        else:
+            projs = jax.device_put(
+                np.asarray(self.projections, np.float32), sharding)
         if projs.ndim == 2:
             projs = projs[None]
         mats = np.asarray(self.matrices, np.float64).reshape(-1, 3, 4)
@@ -164,12 +251,19 @@ class ReconstructionEngine:
     slot instead of the vmapped jnp body.  Strip windows are validated
     against the host planner per submitted chunk (memoised), so an
     undersized window raises instead of dropping taps.
+
+    ``mesh`` z-shards the slot stack over the mesh's volume axis
+    (:class:`repro.dist.sharding.ShardingRules` ``vol``, the ``data``
+    axis), one slab of ``L / n`` planes per chip, and every fold runs
+    the plan's Pallas batch kernel on each chip's slab in place.  It
+    needs a plan that folds with the kernel; a mesh that also splits
+    another axis (the projections) raises.
     """
 
     def __init__(self, geom: Geometry, *, n_slots: int = 4,
                  strategy: str = "strip2", pbatch: int | None = None,
                  short_scan: bool | None = None, validate: bool = True,
-                 auto_step: bool = True, plan=None, **opts):
+                 auto_step: bool = True, plan=None, mesh=None, **opts):
         self.geom = geom
         self.gs = GeomStatic.of(geom)
         if plan is None:
@@ -200,9 +294,18 @@ class ReconstructionEngine:
         self.auto_step = auto_step
         self.n_slots = int(n_slots)
         self.plan = make_filter_plan(geom, short_scan)
-        self._volumes = jnp.zeros((self.n_slots,) + (geom.L,) * 3,
-                                  jnp.float32)
-        self._zero_image = jnp.zeros((geom.n_v, geom.n_u), jnp.float32)
+        self.mesh = mesh
+        self.shards = 1
+        shape = (self.n_slots,) + (geom.L,) * 3
+        if mesh is None:
+            self._volumes = jnp.zeros(shape, jnp.float32)
+            self._zero_image = jnp.zeros((geom.n_v, geom.n_u), jnp.float32)
+        else:
+            self._init_mesh(mesh, shape)
+        # On a mesh, a small array of the latest fold's output: what a
+        # caller waits on for a fold, since the next fold donates the
+        # stack.  ``None`` off a mesh and before the first fold.
+        self.last_fold = None
         self.slot_scan: list[int | None] = [None] * self.n_slots
         self.scans: dict[int, ScanState] = {}
         self.queue: list[int] = []
@@ -210,6 +313,40 @@ class ReconstructionEngine:
         self.stats = {"folds": 0, "fold_ticks": 0, "retired": 0,
                       "pallas_folds": 0, "aborted": 0}
         self._next_sid = 0
+
+    def _init_mesh(self, mesh, shape) -> None:
+        """Place the engine's state on ``mesh``: the slot stack z-sharded
+        (created there, never whole on one chip), the filter's tables
+        and the zero image replicated."""
+        from repro.kernels.backproject_ops import clamp_tiles
+
+        if self._pallas_kwargs is None:
+            raise ValueError(
+                "the mesh engine folds with the Pallas batch kernel: give "
+                "it a plan with use_pallas set")
+        if self._pallas_kwargs.get("shared_window"):
+            raise ValueError(
+                "the mesh engine folds each slab with the strip kernels; "
+                "the shared-window variant sizes its window on the host "
+                "per fold and is not supported")
+        self._axis = _slab_axis(mesh, self.geom.L)
+        self.shards = int(mesh.shape[self._axis])
+        rep = NamedSharding(mesh, P())
+        self._replicated = rep
+        self._volumes = jax.jit(
+            functools.partial(jnp.zeros, shape, jnp.float32),
+            out_shardings=NamedSharding(mesh, P(None, self._axis)))()
+        self._zero_image = jax.device_put(
+            np.zeros((self.geom.n_v, self.geom.n_u), np.float32), rep)
+        self.plan = self.plan._replace(**{
+            k: jax.device_put(getattr(self.plan, k), rep)
+            for k in ("hf", "cosw", "parker")
+            if getattr(self.plan, k) is not None})
+        kw = self._pallas_kwargs
+        self._tiles = dict(zip(("ty", "chunk", "band", "width"), clamp_tiles(
+            self.gs, int(kw.get("ty", 8)), int(kw.get("chunk", 128)),
+            int(kw.get("band", 16)), int(kw.get("width", 512)))))
+        self._kernel = tuple(sorted({**kw, **self._tiles}.items()))
 
     # ------------------------------------------------------------------
     # Admission
@@ -286,7 +423,11 @@ class ReconstructionEngine:
         scan = self.scans[sid]
         if scan.done:
             raise ValueError(f"scan {sid} already finished")
-        projs, mats, idx = chunk.arrays()
+        if self.mesh is None:
+            projs, mats, idx = chunk.arrays()
+        else:
+            with obs.span("engine.put", units=chunk.n):
+                projs, mats, idx = chunk.arrays(self._replicated)
         k = projs.shape[0]
         if mats.shape[0] != k or idx.shape != (k,):
             raise ValueError(
@@ -355,10 +496,13 @@ class ReconstructionEngine:
         progressed = False
         if ready:
             n = sum(min(self.pbatch, len(scan.pending)) for _, scan in ready)
-            with obs.span("engine.fold", units=n, slots=len(ready)) as rec:
+            with obs.span("engine.fold", units=n, slots=len(ready),
+                          shards=self.shards) as rec:
                 if rec is not None:
                     rec["bytes_in_use_entry"] = _bytes_in_use(self._volumes)
-                if self._pallas_kwargs is not None:
+                if self.mesh is not None:
+                    self._fold_mesh(ready)
+                elif self._pallas_kwargs is not None:
                     self._fold_kernel(ready)
                 else:
                     self._fold_jnp(ready)
@@ -385,6 +529,24 @@ class ReconstructionEngine:
             self.stats["folds"] += n
             self.stats["pallas_folds"] += n
 
+    def _fold_mesh(self, ready) -> None:
+        """Mesh fold: per ready slot, the host planner checks the batch
+        over the whole cube, then every chip folds it into its own slab
+        of the slot, in place (:func:`_fold_slabs`)."""
+        from repro.kernels.backproject_ops import validate_batch
+
+        for slot, scan in ready:
+            imgs, ms, n = self._take_batch(scan)
+            if self.validate:
+                validate_batch(self.geom, ms, slabs=self.shards,
+                               **self._tiles)
+            self._volumes, self.last_fold = _fold_slabs(
+                self._volumes, jnp.int32(slot), imgs, ms, gs=self.gs,
+                kernel=self._kernel, mesh=self.mesh, axis=self._axis)
+            scan.folded += n
+            self.stats["folds"] += n
+            self.stats["pallas_folds"] += n
+
     def _fold_jnp(self, ready) -> None:
         """All ready slots in one vmapped, slot-masked jitted call."""
         images = [self._zero_image[None].repeat(self.pbatch, axis=0)
@@ -403,6 +565,17 @@ class ReconstructionEngine:
             self._volumes, jnp.stack(images), jnp.asarray(np.stack(mats)),
             jnp.asarray(mask), self.gs, self.exec_plan)
 
+    def _clear_slot(self, slot: int, take: bool = False):
+        """Zero slot ``slot``; with ``take`` return its volume first."""
+        if self.mesh is None:
+            vol = self._volumes[slot] if take else None
+            self._volumes = self._volumes.at[slot].set(0.0)
+            return vol
+        out = _clear_slab_slot(self._volumes, jnp.int32(slot),
+                               mesh=self.mesh, axis=self._axis, take=take)
+        vol, self._volumes = out if take else (None, out)
+        return vol
+
     def _retire(self) -> bool:
         any_retired = False
         for slot, sid in enumerate(self.slot_scan):
@@ -410,9 +583,8 @@ class ReconstructionEngine:
                 continue
             scan = self.scans[sid]
             if scan.complete and not scan.pending:
-                scan.volume = self._volumes[slot]
+                scan.volume = self._clear_slot(slot, take=True)
                 scan.done = True
-                self._volumes = self._volumes.at[slot].set(0.0)
                 self.slot_scan[slot] = None
                 self.stats["retired"] += 1
                 any_retired = True
@@ -481,7 +653,7 @@ class ReconstructionEngine:
             self.queue.remove(sid)
         for slot, owner in enumerate(self.slot_scan):
             if owner == sid:
-                self._volumes = self._volumes.at[slot].set(0.0)
+                self._clear_slot(slot)
                 self.slot_scan[slot] = None
         scan.pending.clear()
         scan.done = True

@@ -23,8 +23,8 @@ pltpu = None
 SPEC = {"name": "unbalanced-batch", "kind": "batch", "pbatch": 4}
 
 
-def kernel(A_ref, imgs_ref, vol_in_ref, vol_out_ref, strip_ref, acc_ref,
-           sems, *, o_mm, n_u, n_v, ty, chunk, band, width, pbatch,
+def kernel(zrow_ref, A_ref, imgs_ref, vol_in_ref, vol_out_ref, strip_ref,
+           acc_ref, sems, *, o_mm, n_u, n_v, ty, chunk, band, width, pbatch,
            quantized=False):
     acc_ref[...] = vol_in_ref[0].astype(jnp.float32)
 

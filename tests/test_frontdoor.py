@@ -13,8 +13,8 @@ Three layers of claim (DESIGN.md §14):
   oracle to the same tolerance as a fresh engine).
 * **End to end** — N clients interleaving chunk streams through one
   event loop all converge to the one-shot ``reconstruct`` volume, under
-  every policy, and the sharded backend on the trivial 1x1 mesh matches
-  bitwise-close too.
+  every policy, and the z-sharded engine on the trivial 1x1 mesh
+  matches too.
 """
 
 import asyncio
@@ -278,17 +278,26 @@ def test_deadline_policy_admits_tightest_slo_first():
 
 
 def test_sharded_backend_identity_mesh_matches_oracle():
+    """``mesh=`` streams through the z-sharded engine: on the trivial 1x1
+    mesh, shuffled chunks and a partial scan (no longer refused) fold
+    with the kernel and match the oracle."""
+    from repro.dispatch import ExecutionPlan
     from repro.launch.mesh import make_local_mesh
 
     projs, mats, _ = _DS
     mesh = make_local_mesh(data=1, model=1)
+    plan = ExecutionPlan(strategy="gather", pbatch=2, use_pallas=True,
+                         pallas=(("band", 16), ("chunk", 16),
+                                 ("pbatch", 2), ("ty", 8),
+                                 ("width", 128)))
 
     async def scenario():
-        fd = CTFrontDoor(GEOM, mesh=mesh, n_slots=1, pbatch=4)
-        # Sharded mode requires full scans: a partial declaration fails
-        # at open_scan, in the caller, not mid-pump.
-        with pytest.raises(ValueError, match="must be full"):
-            await fd.open_scan(n_proj=3)
+        fd = CTFrontDoor(GEOM, mesh=mesh, n_slots=1, plan=plan)
+        assert fd._backend.engine.mesh is mesh
+        part = await fd.open_scan(n_proj=3)
+        await fd.submit(part, ProjectionChunk(projs[:3], mats[:3],
+                                              np.arange(3)))
+        await fd.result(part)
         ticket = await fd.open_scan(n_proj=GEOM.n_proj)
         order = np.random.default_rng(3).permutation(GEOM.n_proj)
         for c0 in range(0, GEOM.n_proj, 2):
@@ -299,25 +308,6 @@ def test_sharded_backend_identity_mesh_matches_oracle():
 
     out = asyncio.run(scenario())
     np.testing.assert_allclose(out, REF, atol=1e-5, rtol=1e-5)
-
-
-def test_sharded_backend_rejects_duplicate_angles():
-    from repro.launch.mesh import make_local_mesh
-
-    projs, mats, _ = _DS
-    mesh = make_local_mesh(data=1, model=1)
-
-    async def scenario():
-        fd = CTFrontDoor(GEOM, mesh=mesh, n_slots=1)
-        ticket = await fd.open_scan()
-        idx = np.arange(3)
-        await fd.submit(ticket, ProjectionChunk(projs[idx], mats[idx],
-                                                idx))
-        with pytest.raises(ValueError, match="exactly once"):
-            await fd.submit(ticket, ProjectionChunk(projs[idx],
-                                                    mats[idx], idx))
-
-    asyncio.run(scenario())
 
 
 # ----------------------------------------------------------------------

@@ -143,3 +143,40 @@ def test_strip2_reconstruct_compiles_for_v5e(one_chip):
         _sds((GS.L,) * 3, jnp.float32, one_chip), GS, plan).compile()
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < 16 * 2 ** 30
+
+
+def test_mesh_fold_compiles_for_v5e_2x2(topo, monkeypatch):
+    """The z-sharded engine at RabbitCT 1024³ on four chips: the fold
+    runs the kernel on each chip's slab of the donated two-slot stack in
+    place, and retiring a slot copies out that slot alone."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from repro.streaming import engine
+
+    # Off the chip the wrapper would pick interpret mode; compile the
+    # kernel the chip runs.
+    monkeypatch.setattr(backproject_ops, "_interpret", lambda: False)
+    mesh = Mesh(np.array(topo.devices).reshape(4, 1), ("data", "model"))
+    gs = GeomStatic.of(Geometry(L=1024, voxel_mm=0.25))
+    rep = NamedSharding(mesh, P())
+    stack = _sds((2,) + (gs.L,) * 3, jnp.float32,
+                 NamedSharding(mesh, P(None, "data")))
+    kernel = tuple(sorted(dict(ty=8, chunk=128, band=168, width=1280,
+                               pbatch=PBATCH,
+                               strip_dtype="float32").items()))
+    fold = engine._fold_slabs.lower(
+        stack, _sds((), jnp.int32, rep),
+        _sds((PBATCH, gs.n_v, gs.n_u), jnp.float32, rep),
+        _sds((PBATCH, 3, 4), jnp.float32, rep), gs=gs, kernel=kernel,
+        mesh=mesh, axis="data").compile()
+    assert "tpu_custom_call" in fold.as_text()
+    mem = fold.memory_analysis()
+    slabs = 2 * gs.L ** 3 // 4 * 4            # bytes of a chip's slabs
+    assert mem.alias_size_in_bytes == slabs
+    assert mem.temp_size_in_bytes < 2 ** 20
+    clear = engine._clear_slab_slot.lower(
+        stack, _sds((), jnp.int32, rep), mesh=mesh, axis="data",
+        take=True).compile().memory_analysis()
+    assert clear.alias_size_in_bytes == slabs
+    assert clear.temp_size_in_bytes < 2 ** 20
