@@ -22,7 +22,7 @@ import numpy as np
 from repro import obs
 from repro.core.backproject import (DEFAULT_PBATCH, GeomStatic,
                                     strip_wire_dtype)
-from repro.core.clipping import (_round8, _round128, plan_strips,
+from repro.core.clipping import (_MARGIN, _round8, _round128,
                                  shared_window_requirement)
 from repro.core.geometry import Geometry
 
@@ -31,7 +31,8 @@ from .backproject import (_EPS_W, _LANE, SUBLANE, backproject_volume_pallas,
 
 __all__ = ["pallas_backproject_one", "pallas_backproject_batch",
            "validate_batch", "validate_strip_config", "shared_window_dims",
-           "clamp_tiles", "tile_col_blocks"]
+           "clamp_tiles", "tile_col_blocks", "tile_corner_extents",
+           "tile_coverage_need"]
 
 
 def _interpret() -> bool:
@@ -105,27 +106,148 @@ def _wire_pad(images, band: int, width: int, strip_dtype: str):
     return rq.codes, jnp.stack([rq.scale, rq.offset], axis=-1)
 
 
+# Tiles a block of the corner pass holds: each temporary of a block
+# stays in cache, where whole-cube temporaries would not.
+_CORNER_BLOCK = 1 << 15
+
+
+def tile_corner_extents(gs: GeomStatic, A, *, ty: int, chunk: int,
+                        dtype):
+    """The kernel's corner rule, one block of z-planes at a time.
+
+    Per ``(z, y-group, x-chunk)`` tile, the projective map at the tile's
+    four corner voxels (:func:`repro.kernels.backproject._strip_origin`)
+    in ``dtype``, in the kernel's order of operations.  Yields, block
+    by block of z-planes in order, ``(ix_lo, ix_hi, iy_lo, iy_hi,
+    w_hi)``: the extremes over the corners, each shaped ``(planes,
+    L // ty, L // chunk)``.  Where ``w <= _EPS_W`` a corner's ``ix`` and
+    ``iy`` read 0, as the kernel's reciprocal trick gives.
+    """
+    dt = np.dtype(dtype).type
+    A = np.asarray(A, dt).reshape(3, 4)
+    O, MM = dt(gs.O), dt(gs.MM)
+    wz = O + np.arange(gs.L, dtype=dt) * MM
+    y0 = np.arange(0, gs.L, ty, dtype=dt)[:, None]
+    x0 = np.arange(0, gs.L, chunk, dtype=dt)
+    corners = []               # per corner: the x and y terms of each row
+    for dy in (0, ty - 1):
+        wy = O + (y0 + dt(dy)) * MM
+        for dx in (0, chunk - 1):
+            wx = O + (x0 + dt(dx)) * MM
+            corners.append([wx * a[0] + wy * a[1] for a in A])
+    planes = max(1, _CORNER_BLOCK // (y0.size * x0.size))
+    for z0 in range(0, gs.L, planes):
+        zs = wz[z0:z0 + planes, None, None]
+        ext = None
+        for xy in corners:
+            u, v, w = (zs * a[2] + t for a, t in zip(A, xy))
+            for row, a in zip((u, v, w), A):
+                row += a[3]
+            with np.errstate(divide="ignore"):
+                r = np.reciprocal(w)
+            r[w <= _EPS_W] = 0
+            u *= r
+            v *= r
+            if ext is None:
+                ext = [u, u.copy(), v, v.copy(), w]
+            else:
+                for acc, val, op in zip(ext, (u, u, v, v, w),
+                                        (np.minimum, np.maximum) * 2
+                                        + (np.maximum,)):
+                    op(acc, val, out=acc)
+        yield tuple(ext)
+
+
+def _f32_slack(gs: GeomStatic, A: np.ndarray, w_min: float
+               ) -> tuple[float, float]:
+    """Pixels by which the kernel's float32 ``ix`` and ``iy`` of any
+    voxel can miss their float64 values, where ``w >= w_min``.
+
+    Each float32 row of the projective map is a sum of three products of
+    rounded coordinates and rounded matrix entries, and a rounded
+    constant: it misses its exact value by less than ``10 u S`` with ``u
+    = 2**-24`` and ``S`` the sum of the terms' magnitudes at the
+    volume's largest coordinate.  Then ``ix = u' / w`` misses by less
+    than ``(err(u') + |ix| err(w)) / w`` plus two roundings of ``ix``;
+    ``|ix| <= n_u + 1`` wherever the clip to ``[-1, n_u]`` leaves it.
+    The slack kept is at least twice that.
+    """
+    u = 2.0 ** -24
+    mag = np.abs(A[:, :3]).sum(axis=1) * abs(gs.O) + np.abs(A[:, 3])
+    return tuple(20 * u * ((mag[i] + (n + 1) * mag[2]) / w_min + n + 1)
+                 for i, n in ((0, gs.n_u), (1, gs.n_v)))
+
+
+def tile_coverage_need(gs: GeomStatic, A, *, ty: int, chunk: int
+                       ) -> tuple[int, int]:
+    """The ``(band, width)`` that cover every tile's taps, from the
+    tile's four corners.
+
+    The argument (DESIGN.md §3).  ``w`` is affine, so it is above
+    ``_EPS_W`` at every voxel exactly when it is at the volume's eight
+    corner voxels; then over a tile ``ix`` and ``iy`` are
+    linear-fractional in ``(x, y)``, so quasilinear, and their extremes
+    lie at the tile's corners.  The float64 corner extremes, widened by
+    :func:`_f32_slack` and clipped to ``[-1, n]``, floor to ``lo`` and
+    ``hi``, and every float32 ``iy`` of the tile floors into ``[lo,
+    hi]``.  A voxel's taps are padded rows ``floor(iy) + 1`` and
+    ``floor(iy) + 2``, so rows ``lo + 1 .. hi + 2``.  The kernel's window
+    starts at the floor of its own float32 corner minimum, in ``[lo, lo +
+    1]``, and by :func:`strip_window`'s rounding holds ``band + 1`` rows
+    from there (or reaches the padded image's end), so ``band >= hi - lo
+    + 2`` covers every tap.  The need ``hi - lo + 1 + _MARGIN`` is that
+    and one spare row: the planner's margin for the floor tap pair and
+    the rounding between the two precisions.  Columns likewise.  A
+    need beyond the padded detector (``n + 2``) reads as that: a window
+    so wide is clamped to the padded image's origin and holds all of it.
+    Inactive tiles — no corner extreme overlaps the padded detector by
+    the kernel's test, widened by the slack — need nothing.
+
+    Raises ``ValueError`` where a voxel of the volume lies at or behind
+    the source plane (``w <= _EPS_W``): a tile across that plane has no
+    corner bound, and the kernel, which places both its window and the
+    column blocks it contracts from the corners, can drop taps there
+    whatever the window's size.
+    """
+    A = np.asarray(A, np.float64).reshape(3, 4)
+    ends = gs.O + np.array([0.0, gs.L - 1]) * gs.MM
+    cube = np.stack(np.meshgrid(ends, ends, ends, [1.0]), -1).reshape(-1, 4)
+    w_min = float((cube @ A[2]).min())
+    if w_min <= _EPS_W:
+        raise ValueError(
+            f"projection puts part of the volume at or behind the source "
+            f"plane (w={w_min:.3g} at a corner voxel); the kernel's corner "
+            f"rule does not cover such tiles")
+    s_c, s_r = _f32_slack(gs, A, w_min)
+    need_r = need_c = 0
+    for ix_lo, ix_hi, iy_lo, iy_hi, _ in tile_corner_extents(
+            gs, A, ty=ty, chunk=chunk, dtype=np.float64):
+        active = ((ix_lo < gs.n_u + s_c) & (ix_hi > -1 - s_c)
+                  & (iy_lo < gs.n_v + s_r) & (iy_hi > -1 - s_r))
+        if not active.any():
+            continue
+        span_r = (np.floor(np.clip(iy_hi + s_r, -1, gs.n_v))
+                  - np.floor(np.clip(iy_lo - s_r, -1, gs.n_v)))
+        span_c = (np.floor(np.clip(ix_hi + s_c, -1, gs.n_u))
+                  - np.floor(np.clip(ix_lo - s_c, -1, gs.n_u)))
+        need_r = max(need_r, int(span_r[active].max()))
+        need_c = max(need_c, int(span_c[active].max()))
+    return (min(need_r + 1 + _MARGIN, gs.n_v + 2),
+            min(need_c + 1 + _MARGIN, gs.n_u + 2))
+
+
 def validate_strip_config(geom: Geometry, A: np.ndarray, *, ty: int,
                           chunk: int, band: int, width: int) -> None:
     """Host-side check that (band, width) covers every tile footprint.
 
-    A tile spans ``ty`` lines x ``chunk`` voxels; per-line strip needs are
-    computed exactly by the planner (monotone-beam property), and adjacent
-    lines' strips are merged by taking min/max origins.  Raises with the
-    required sizes if the static config is too small — silent tap loss is
-    never possible.
+    The need comes from each tile's four corners
+    (:func:`tile_coverage_need`), the rule the kernel places its window
+    by.  Raises with the required sizes if the static config is too
+    small, and refuses a projection that puts a voxel at or behind the
+    source plane — silent tap loss is never possible.
     """
-    plan = plan_strips(geom, A, chunk=chunk)
-    r0 = plan.r0.astype(np.int64)
-    c0 = plan.c0.astype(np.int64)
-    # Merge ty adjacent lines: worst-case span = max over the group of
-    # (origin + required extent) - min origin.
-    L = geom.L
-    g = r0.reshape(L, L // ty, ty, -1)
-    span_r = g.max(axis=2) - g.min(axis=2) + plan.required_band
-    gc = c0.reshape(L, L // ty, ty, -1)
-    span_c = gc.max(axis=2) - gc.min(axis=2) + plan.required_width
-    need_band, need_width = int(span_r.max()), int(span_c.max())
+    need_band, need_width = tile_coverage_need(
+        GeomStatic.of(geom), A, ty=ty, chunk=chunk)
     if band < need_band or width < need_width:
         raise ValueError(
             f"strip config (band={band}, width={width}) does not cover the "
@@ -140,57 +262,29 @@ def tile_col_blocks(gs: GeomStatic, A, *, ty: int, chunk: int, band: int,
     The host's float32 copy of the kernel's corner rule
     (:func:`repro.kernels.backproject._strip_origin` and ``_col_blocks``),
     vectorised over the ``(L, L // ty, L // chunk)`` tiles: each tile's
-    four corner voxels give its tap columns, the window's aligned column
-    ``c0`` and the first and last 128-column block of the window that the
-    kernel contracts.  ``band``/``width`` are the footprint dims the
-    wrappers take; the window is :func:`strip_window` of them.  Returns
-    ``(active, c0, kb_lo, kb_hi)``, each shaped by tile; ``active`` is the
-    kernel's tile test, on the corners.
+    four corner voxels (:func:`tile_corner_extents`) give its tap
+    columns, the window's aligned column ``c0`` and the first and last
+    128-column block of the window that the kernel contracts.
+    ``band``/``width`` are the footprint dims the wrappers take; the
+    window is :func:`strip_window` of them.  Returns ``(active, c0,
+    kb_lo, kb_hi)``, each shaped by tile; ``active`` is the kernel's tile
+    test, on the corners.
     """
-    f32 = np.float32
-    A = np.asarray(A, f32).reshape(3, 4)
-    O, MM = f32(gs.O), f32(gs.MM)
-    wz = (O + np.arange(gs.L, dtype=f32) * MM)[:, None, None]
-    y0 = np.arange(0, gs.L, ty, dtype=f32)[:, None]
-    x0 = np.arange(0, gs.L, chunk, dtype=f32)
-    ext = None                 # per tile: min/max of ix and iy, max of w
-    for dy in (0, ty - 1):
-        wy = O + (y0 + f32(dy)) * MM
-        for dx in (0, chunk - 1):
-            wx = O + (x0 + f32(dx)) * MM
-
-            def affine(a):     # (L, ny, nc), in the kernel's order
-                out = wz * a[2] + (wx * a[0] + wy * a[1])
-                out += a[3]
-                return out
-
-            w = affine(A[2])
-            with np.errstate(divide="ignore"):
-                r = np.reciprocal(w)
-            r[w <= _EPS_W] = 0
-            ix = affine(A[0])
-            ix *= r
-            iy = affine(A[1])
-            iy *= r
-            if ext is None:
-                ext = [ix, ix.copy(), iy, iy.copy(), w]
-            else:
-                for acc, val, op in zip(ext, (ix, ix, iy, iy, w),
-                                        (np.minimum, np.maximum) * 2
-                                        + (np.maximum,)):
-                    op(acc, val, out=acc)
-    ix_lo, ix_hi, iy_lo, iy_hi, w_hi = ext
-    active = ((ix_lo < gs.n_u) & (ix_hi > -1) & (iy_lo < gs.n_v)
-              & (iy_hi > -1) & (w_hi > _EPS_W))
-    # Clipping commutes with the corner min/max: clip once per tile.
-    lo = np.floor(np.clip(ix_lo, -1, gs.n_u)).astype(np.int64)
-    hi = np.floor(np.clip(ix_hi, -1, gs.n_u)).astype(np.int64) + 3
     wwidth = strip_window(band, width, 4)[1]
     pad_cols = _padded_dims(gs.n_v, gs.n_u, band, width, 4)[1]
-    c0 = np.clip(lo, 0, pad_cols - wwidth) // _LANE * _LANE
     last = wwidth // _LANE - 1
-    return (active, c0, np.clip((lo - c0) // _LANE, 0, last),
-            np.clip((hi - c0) // _LANE, 0, last))
+    out = []
+    for ix_lo, ix_hi, iy_lo, iy_hi, w_hi in tile_corner_extents(
+            gs, A, ty=ty, chunk=chunk, dtype=np.float32):
+        active = ((ix_lo < gs.n_u) & (ix_hi > -1) & (iy_lo < gs.n_v)
+                  & (iy_hi > -1) & (w_hi > _EPS_W))
+        # Clipping commutes with the corner min/max: clip once per tile.
+        lo = np.floor(np.clip(ix_lo, -1, gs.n_u)).astype(np.int64)
+        hi = np.floor(np.clip(ix_hi, -1, gs.n_u)).astype(np.int64) + 3
+        c0 = np.clip(lo, 0, pad_cols - wwidth) // _LANE * _LANE
+        out.append((active, c0, np.clip((lo - c0) // _LANE, 0, last),
+                    np.clip((hi - c0) // _LANE, 0, last)))
+    return tuple(np.concatenate(parts) for parts in zip(*out))
 
 
 def _count_col_blocks(gs: GeomStatic, A, *, ty: int, chunk: int, band: int,
