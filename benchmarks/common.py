@@ -20,8 +20,7 @@ from repro.core.phantom import make_dataset
 from repro.tune.timing import time_fn  # noqa: F401  (re-export)
 
 # Tiny mode shrinks every standard problem to CI-sized shapes via
-# ``bench_size`` (``benchmarks.run --tiny`` or REPRO_BENCH_TINY=1);
-# moe_dispatch is laptop-sized by construction and takes no size knob.
+# ``bench_size`` (``benchmarks.run --tiny`` or REPRO_BENCH_TINY=1).
 # ``TINY_ENV`` is the immutable env-var default: ``run.main`` *assigns*
 # ``TINY`` per invocation (tiny-ness must not latch across in-process
 # runs), and the env opt-in has to survive that reset.

@@ -20,7 +20,6 @@ dispatch                    beyond-paper: auto-dispatch resolution cost
                             (cold in-situ selection vs warm cache hit)
 cycle_model                 Section 6.4 (per-iteration cycle breakdown)
 quality                     RabbitCT accuracy score (PSNR)
-lm_gather                   the technique on the assigned LM archs
 ==========================  ==============================================
 
 ``python -m benchmarks.run [--only name[,name...]] [--json PATH] [--tiny]``
@@ -48,8 +47,8 @@ from repro.compile_cache import enable_compile_cache
 from . import common
 from . import (ct_hillclimb, cycle_model, dispatch, fig1_single_device,
                fig2_scaling, fig3_codegen, fig4_streaming, fig5_serving,
-               lm_gather, moe_dispatch, quality, table2_op_census,
-               table3_efficiency, table4_gather_micro, table5_traffic)
+               quality, table2_op_census, table3_efficiency,
+               table4_gather_micro, table5_traffic)
 
 MODULES = [
     ("table2_op_census", table2_op_census),
@@ -64,9 +63,7 @@ MODULES = [
     ("dispatch", dispatch),
     ("cycle_model", cycle_model),
     ("quality", quality),
-    ("lm_gather", lm_gather),
     ("ct_hillclimb", ct_hillclimb),
-    ("moe_dispatch", moe_dispatch),
 ]
 
 

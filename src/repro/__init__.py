@@ -1,4 +1,4 @@
-"""repro: paper-reproduction kernels + the LM/CT production stack."""
+"""repro: cone-beam CT back projection on the TPU, from kernels to serving."""
 
 __version__ = "0.1.0"
 

@@ -1,7 +1,7 @@
 """HLO text analysis: collective bytes, op census, roofline terms.
 
-This is the dry-run "profiler" (there is no hardware): everything §Roofline
-needs is derived from ``lowered.compile()`` artifacts —
+A compile-time "profiler" (no hardware needed): every roofline term is
+derived from ``lowered.compile()`` artifacts —
 
 * ``cost_analysis()``      -> per-device HLO flops + bytes accessed
 * ``memory_analysis()``    -> per-device argument/temp/peak bytes

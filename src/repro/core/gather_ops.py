@@ -3,12 +3,11 @@
 The paper's portable lesson is that *how* you materialise a scattered
 gather matters more than ISA width: hardware gather (AVX2/IMCI) can lose to
 structured loads (AVX/FMA3), and on machines with strong matrix units the
-interpolation/selection itself can ride the MXU.  ``repro`` exposes that
-choice wherever an LM gathers:
-
-* ``Embed`` (vocab tables up to 256000 rows in the assigned archs),
-* MoE dispatch/combine (``repro.models.moe``),
-* the back projection kernel itself (:mod:`repro.core.backproject`).
+interpolation/selection itself can ride the MXU.  This module exposes that
+choice for a plain table lookup, the paper's Table 4 micro-benchmark
+(``benchmarks/table4_gather_micro.py``); the back projection strategies
+make the same choice for the detector reads
+(:mod:`repro.core.backproject`).
 
 ``gather_impl`` values:
 
@@ -17,10 +16,10 @@ choice wherever an LM gathers:
     gather" analogue: correct, compact, and at the mercy of the backend's
     descriptor loop.
 ``onehot``
-    chunked one-hot matmul on the MXU.  ``2 * V * D`` flops per token, but
+    chunked one-hot matmul on the MXU.  ``2 * V * D`` flops per id, but
     zero gather HLOs: the matrix unit plays texture unit.  Wins when the
-    table is small/hot (MoE router combines, small codebooks) or when
-    gathers would serialise; loses asymptotically on big-vocab tables.
+    table is small/hot (small codebooks) or when gathers would
+    serialise; loses asymptotically on large tables.
     Differentiable (the transpose matmul is the scatter-add), which makes
     it the *training-safe* path where scatter performance is the concern.
 ``auto``
@@ -39,7 +38,7 @@ import jax.numpy as jnp
 __all__ = ["gather", "take_gather", "onehot_gather", "ONEHOT_AUTO_MAX_ROWS"]
 
 # Crossover measured by benchmarks/table4_gather_micro.py on the CPU
-# backend; re-derived for TPU from the dry-run op census (EXPERIMENTS.md).
+# backend.
 ONEHOT_AUTO_MAX_ROWS = 1024
 
 
@@ -53,7 +52,7 @@ def onehot_gather(table: jax.Array, ids: jax.Array,
                   chunk: int = 2048) -> jax.Array:
     """One-hot-matmul gather: no gather HLO, all flops on the MXU.
 
-    The vocabulary axis is processed in ``chunk``-row tiles inside a
+    The table's row axis is processed in ``chunk``-row tiles inside a
     ``fori_loop`` so HLO size and live memory stay flat in ``V``:
     ``out += onehot(ids in tile) @ table[tile]``.
     """

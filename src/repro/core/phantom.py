@@ -149,8 +149,8 @@ def forward_project(geom: Geometry,
 def make_dataset(geom: Geometry, seed: int = 0):
     """Convenience bundle: (projections, matrices, reference_volume).
 
-    ``seed`` is accepted for API symmetry with the LM data pipeline; the
-    phantom itself is deterministic.
+    ``seed`` is accepted but unused: the phantom itself is
+    deterministic.
     """
     from .geometry import projection_matrices
 

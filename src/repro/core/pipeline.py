@@ -128,9 +128,8 @@ def sharded_reconstruct(projections, matrices, geom: Geometry, mesh: Mesh,
         pw_full = (fplan.parker if fplan.parker is not None
                    else jnp.ones((geom.n_proj, geom.n_u), jnp.float32))
 
-    # One sharding vocabulary with the LM path (repro.dist.sharding):
-    # the CT decomposition is just two more logical axes — ``vol``
-    # (z-planes, the paper's OpenMP plane split) and ``proj``.
+    # The CT decomposition in logical axes (repro.dist.sharding):
+    # ``vol`` (z-planes, the paper's OpenMP plane split) and ``proj``.
     rules = ShardingRules(vol=(volume_axis,), proj=tuple(proj_axes))
     proj_spec = logical_to_spec(("proj",), rules, mesh)
     vol_spec = logical_to_spec(("vol",), rules, mesh)
@@ -169,9 +168,8 @@ def sharded_reconstruct(projections, matrices, geom: Geometry, mesh: Mesh,
             return slab_body(local_projs, local_mats, local_volume)
 
     with sharding_context(mesh, rules):
-        # shard_constraint is the placement mechanism here — the same
-        # annotation idiom (and specs) the LM layers use, not a parallel
-        # device_put path.
+        # shard_constraint is the placement mechanism here: the
+        # logical-axis annotation idiom, not a parallel device_put path.
         volume = shard_constraint(
             jnp.zeros((gs.L, gs.L, gs.L), dtype=jnp.float32),
             ("vol", None, None))

@@ -1,11 +1,11 @@
 """Distribution layer: logical-axis sharding rules + custom collectives.
 
-One sharding vocabulary for both workloads (DESIGN.md §5): model code and
-the CT reconstruction pipeline annotate tensors with *logical* axis names
-(``batch``, ``fsdp``, ``tp``, ``ep``, ``sp``, ``vol``, ``proj``, ...);
+The CT reconstruction annotates tensors with *logical* axis names
+(``vol`` for volume z-planes, ``proj`` for projection subsets);
 :mod:`repro.dist.sharding` maps those to mesh axes, pruning whatever the
-current mesh does not have.  :mod:`repro.dist.collectives` holds the
-hand-scheduled all-reduce variants (bucketed exact, int8 error-feedback).
+current mesh does not have (DESIGN.md §5).  :mod:`repro.dist.collectives`
+holds the hand-scheduled all-reduce variants (bucketed exact, int8
+error-feedback).
 """
 
 from .collectives import bucketed_psum, compress_psum  # noqa: F401

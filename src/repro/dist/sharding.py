@@ -1,12 +1,11 @@
-"""Logical-axis sharding: names in model code, mesh axes at the edge.
+"""Logical-axis sharding: names in the CT code, mesh axes at the edge.
 
 Every tensor annotation in this repo is written against *logical* axis
 names.  A :class:`ShardingRules` instance maps each logical name to a
 tuple of mesh axis names; :func:`logical_to_spec` resolves an annotation
 against a concrete mesh, silently pruning mesh axes the mesh does not
-have — the same rules lower onto a 2-pod 512-chip production mesh, a
-single 16x16 pod, or a 2-device CPU test mesh without touching model
-code (DESIGN.md §5).
+have — the same rules lower onto a four-chip host or a 2-device CPU
+test mesh without touching the reconstruction code (DESIGN.md §5).
 
 Two special logical names are always replicated: ``None`` and ``"null"``
 (the latter used in spec *trees*, where ``None`` would read as an empty
@@ -14,14 +13,14 @@ pytree).
 
 :func:`valid_spec` is the divisibility guard: any tensor dimension that
 does not divide by the total size of its assigned mesh axes falls back
-to replication for that dimension (GSPMD would otherwise pad; for the
-dry-run memory accounting we want exact shards or none).
+to replication for that dimension (GSPMD would otherwise pad; we want
+exact shards or none).
 
-:func:`sharding_context` + :func:`shard_constraint` give model code a
-zero-cost annotation idiom: ``shard_constraint(x, ("batch", None,
-"tp"))`` is the identity outside a context and a
-``jax.lax.with_sharding_constraint`` inside one, so single-device tests
-run the exact same code path as the production launcher.
+:func:`sharding_context` + :func:`shard_constraint` give a zero-cost
+annotation idiom: ``shard_constraint(x, ("vol", None, None))`` is the
+identity outside a context and a ``jax.lax.with_sharding_constraint``
+inside one, so single-device tests run the exact same code path as the
+sharded reconstruction.
 """
 
 from __future__ import annotations
@@ -44,35 +43,21 @@ __all__ = [
 
 @dataclasses.dataclass(frozen=True)
 class ShardingRules:
-    """Logical axis -> mesh axes mapping (+ schedule feature flags).
+    """Logical axis -> mesh axes mapping.
 
-    LM axes: ``batch`` (data parallel), ``fsdp`` (ZeRO-3 parameter
-    sharding), ``tp`` (tensor parallel), ``ep`` (expert parallel), ``sp``
-    (sequence-parallel KV cache), ``sp_act`` (Megatron-SP residual
-    stream).  CT axes: ``vol`` (volume z-planes — the paper's OpenMP
-    plane decomposition), ``proj`` (projection subsets).
-
-    ``flash_decode`` is a schedule flag, not an axis: it opts decode into
-    the manual flash-decoding path over the ``sp`` shards
-    (:func:`repro.models.attention._decode_attend_sp`).
+    ``vol`` splits the volume's z-planes (the paper's OpenMP plane
+    decomposition); ``proj`` splits the projection set.
     """
 
-    batch: tuple[str, ...] = ("pod", "data")
-    fsdp: tuple[str, ...] = ("data",)
-    tp: tuple[str, ...] = ("model",)
-    ep: tuple[str, ...] = ("model",)
-    sp: tuple[str, ...] = ()
-    sp_act: tuple[str, ...] = ()
     vol: tuple[str, ...] = ("data",)
     proj: tuple[str, ...] = ("pod", "model")
-    flash_decode: bool = False
 
 
 def logical_to_spec(axes, rules: ShardingRules, mesh) -> P:
     """Resolve logical axis names to a PartitionSpec on ``mesh``.
 
     Mesh axes named by a rule but absent from ``mesh.axis_names`` are
-    pruned (a podless mesh collapses ``("pod", "data")`` to ``"data"``);
+    pruned (a podless mesh collapses ``("pod", "model")`` to ``"model"``);
     a rule whose axes are all pruned — or mapped to ``()`` — replicates.
     """
     names = set(mesh.axis_names)
@@ -123,8 +108,8 @@ def valid_spec(shape, spec: P, mesh) -> P:
 # ----------------------------------------------------------------------
 
 # (mesh, rules) of the innermost active sharding_context, or None.  A
-# ContextVar (not a bare module global) so nested/threaded launchers each
-# see their own context; model code reads it via ``_CTX.get()``.
+# ContextVar (not a bare module global) so nested/threaded callers each
+# see their own context; :func:`shard_constraint` reads it.
 _CTX: contextvars.ContextVar = contextvars.ContextVar(
     "repro_sharding_context", default=None)
 
@@ -139,11 +124,10 @@ def sharding_context(mesh, rules: ShardingRules):
         _CTX.reset(token)
 
 
-# Valid logical names for annotations (flash_decode is a flag, not an
-# axis).  Checked even outside a context so a typo'd annotation fails in
-# single-device unit tests, not at the first production launch.
-_LOGICAL_AXES = frozenset(
-    f.name for f in dataclasses.fields(ShardingRules)) - {"flash_decode"}
+# Valid logical names for annotations.  Checked even outside a context
+# so a typo'd annotation fails in single-device unit tests, not at the
+# first sharded run.
+_LOGICAL_AXES = frozenset(f.name for f in dataclasses.fields(ShardingRules))
 
 
 def shard_constraint(x, logical_axes):

@@ -1,13 +1,12 @@
 """Pallas TPU kernels (validated with interpret=True on CPU).
 
-Each kernel ships the required triple: the ``pl.pallas_call`` kernel with
-explicit BlockSpec/VMEM tiling, a jit'd ops wrapper, and a pure-jnp
-oracle (``*_ref``).
+The back projection kernel ships the required triple: the
+``pl.pallas_call`` kernels with explicit BlockSpec/VMEM tiling
+(:mod:`.backproject`), the jit'd ops wrappers (:mod:`.backproject_ops`),
+and a pure-jnp oracle (:mod:`.backproject_ref`).
 """
 
 from .backproject_ops import (  # noqa: F401
     pallas_backproject_batch,
     pallas_backproject_one,
 )
-from .gather_kernel_ops import pallas_onehot_gather  # noqa: F401
-from .slstm_ops import fused_slstm_forward  # noqa: F401

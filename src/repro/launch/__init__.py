@@ -1,1 +1,1 @@
-"""Launchers: mesh construction, multi-pod dry-run, train/serve drivers."""
+"""Launch helpers: device mesh construction."""

@@ -1,37 +1,23 @@
-"""Production mesh construction.
+"""Device mesh construction.
 
 A function, not a module-level constant: importing this module never
-touches jax device state (device count is locked at first use, and only
-``dryrun.py`` sets the 512-placeholder-device XLA flag).
-
-Production topology (TPU v5e): a pod is a 16x16 mesh of 256 chips;
-``multi_pod=True`` adds a leading 2-pod axis for the 512-chip dry-run.
-At real deployment the same axes scale out (``pod`` -> #pods) without
-touching model code — all sharding is expressed against axis *names*
-(repro.dist.sharding).
+touches jax device state (device count is locked at first use).  All
+sharding is expressed against the mesh's axis *names*
+(:mod:`repro.dist.sharding`), so the same code runs on a four-chip host
+or a multi-device CPU test process.
 """
 
 from __future__ import annotations
 
 import jax
 
-__all__ = ["make_production_mesh", "make_local_mesh"]
-
-
-def _auto(n):
-    return (jax.sharding.AxisType.Auto,) * n
-
-
-def make_production_mesh(*, multi_pod: bool = False):
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, axis_types=_auto(len(axes)))
+__all__ = ["make_local_mesh"]
 
 
 def make_local_mesh(data: int | None = None, model: int = 1):
-    """Small mesh over whatever devices exist (tests / CPU runs)."""
+    """Mesh ``("data", "model")`` over whatever devices exist."""
     n = jax.device_count()
     if data is None:
         data = n // model
     return jax.make_mesh((data, model), ("data", "model"),
-                         axis_types=_auto(2))
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)

@@ -1,14 +1,12 @@
-"""Serving: batched prefill/decode LM engine + the CT front door."""
+"""Serving: the CT front door over the streaming reconstruction engine."""
 
 from .ct_frontdoor import (AdmissionPolicy, Backpressure,  # noqa: F401
                            CTFrontDoor, DeadlinePolicy, FairSharePolicy,
                            FIFOPolicy, POLICIES, PolicyContext,
                            ScanAborted, ScanTicket, SRSFPolicy)
-from .engine import Request, ServingEngine  # noqa: F401
 
 __all__ = [
     "AdmissionPolicy", "Backpressure", "CTFrontDoor", "DeadlinePolicy",
     "FairSharePolicy", "FIFOPolicy", "POLICIES", "PolicyContext",
     "ScanAborted", "ScanTicket", "SRSFPolicy",
-    "Request", "ServingEngine",
 ]

@@ -1,9 +1,8 @@
 """Multi-tenant CT front door: async admission over the streaming engine.
 
-The LM :class:`repro.serving.engine.ServingEngine` proves the shape —
-continuous batching over a fixed slot pool — and the streaming
-:class:`repro.streaming.ReconstructionEngine` is its CT analogue.  What
-neither has is a *front*: a place where many concurrent clients hand in
+The streaming :class:`repro.streaming.ReconstructionEngine` does
+continuous batching of scans over a fixed slot pool.  What it lacks is
+a *front*: a place where many concurrent clients hand in
 interleaved scan streams, where admission order is a policy rather than
 an accident of arrival, where a full house answers "retry in t seconds"
 instead of buffering without bound, and where a client can walk away

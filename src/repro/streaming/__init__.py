@@ -1,5 +1,5 @@
-"""Streamed reconstruction: serve CT scans the way the LM engine serves
-prompts (DESIGN.md §8)."""
+"""Streamed reconstruction: continuous batching of CT scans over a fixed
+pool of volume slots (DESIGN.md §8)."""
 
 from .engine import (ProjectionChunk, ReconstructionEngine,  # noqa: F401
                      ScanState)

@@ -3,8 +3,8 @@
 The paper's production setting is a C-arm that delivers projections *as a
 stream* — end-to-end latency is set by how much of the filter and
 back-projection work overlaps the acquisition, not by the back projection
-alone (Treibig et al., arXiv:1104.5243).  This engine is the CT analogue
-of :class:`repro.serving.engine.ServingEngine`:
+alone (Treibig et al., arXiv:1104.5243).  This engine serves scans by
+continuous batching over a fixed slot pool:
 
 * fixed ``n_slots`` concurrent reconstructions share one resident volume
   stack ``(n_slots, L, L, L)`` and one jitted fold step;
@@ -17,8 +17,7 @@ of :class:`repro.serving.engine.ServingEngine`:
   (:func:`repro.core.backproject._backproject_batch_body`), so a chunk
   pays one volume pass, not one pass per projection (DESIGN.md §7/§8);
 * every tick folds *all* ready slots in one vmapped+masked jitted call —
-  B scans in flight cost one compiled step, mirroring the LM engine's
-  ``_masked_decode_step`` slot discipline;
+  B scans in flight cost one compiled step;
 * finished scans retire, their slot is zeroed and immediately refilled
   from the admission queue (continuous batching);
 * with a ``mesh`` the slot stack is z-sharded over the mesh's volume
@@ -78,7 +77,7 @@ def _fold_slots(volumes, images, mats, mask, gs, plan):
     per-slot body is the batch-major volume pass of DESIGN.md §7 vmapped
     over slots; masked-out slots keep their volume bit-identical (their
     staged images are zero anyway, but the merge makes the guarantee
-    unconditional — same idiom as the LM engine's masked decode step).
+    unconditional).
     """
 
     def one(vol, imgs, ms):

@@ -1,5 +1,5 @@
 """Shared test fixtures.  NOTE: no XLA_FLAGS here — tests see 1 device
-(the 512-placeholder-device flag belongs to dryrun.py alone)."""
+(multi-device tests set the flag in a child process)."""
 
 import jax
 import pytest

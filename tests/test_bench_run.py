@@ -24,9 +24,8 @@ def test_only_typo_lists_modules_and_exits_nonzero(capsys):
 
 
 def test_known_only_name_is_accepted():
-    # Argument validation only — pick a module and make sure parsing
-    # passes (moe_dispatch is the cheapest real module, but any name in
-    # MODULES must clear the check; we don't execute it here).
+    # Argument validation only: any name in MODULES must clear the
+    # check; we don't execute it here.
     names = [n for n, _ in bench_run.MODULES]
     assert "fig1_single_device" in names
     assert "table5_traffic" in names
@@ -261,16 +260,25 @@ def test_regression_gate_reports_partially_missing_rows(tmp_path, capsys):
 def test_tiny_does_not_latch_across_inprocess_runs(monkeypatch):
     """--tiny must not leak into a later in-process main() without the
     flag (RESULTS/EXTRAS were reset; TINY silently stayed True)."""
+    seen = []
+
+    class Probe:                           # a module that records TINY
+        @staticmethod
+        def run():
+            seen.append(common.TINY)
+
+    monkeypatch.setattr(bench_run, "MODULES", [("probe", Probe)])
     monkeypatch.setattr(common, "TINY", False)
     monkeypatch.setattr(common, "TINY_ENV", False)
     with pytest.raises(SystemExit):
         bench_run.main(["--tiny", "--only", "nonexistent_module"])
     assert common.TINY is False            # parse failed before assign
-    bench_run.main(["--tiny", "--only", "moe_dispatch"])
+    bench_run.main(["--tiny", "--only", "probe"])
     assert common.TINY is True
-    bench_run.main(["--only", "moe_dispatch"])
+    bench_run.main(["--only", "probe"])
     assert common.TINY is False            # assigned, not latched
     # The REPRO_BENCH_TINY env opt-in survives the per-run assignment.
     monkeypatch.setattr(common, "TINY_ENV", True)
-    bench_run.main(["--only", "moe_dispatch"])
+    bench_run.main(["--only", "probe"])
     assert common.TINY is True
+    assert seen == [True, False, True]

@@ -8,6 +8,9 @@ import sys
 import textwrap
 
 import pytest
+from jax.sharding import PartitionSpec as P
+
+from repro.dist.sharding import ShardingRules, logical_to_spec, valid_spec
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -140,30 +143,29 @@ def test_bucketed_psum_exact():
     assert rec["diff"] == 0.0
 
 
-def test_elastic_checkpoint_restore_across_meshes(tmp_path):
-    """Save on a 4-device mesh, restore onto 2 devices (elastic)."""
-    d = str(tmp_path / "ck")
-    _run_child(4, f"""
-        from jax.sharding import PartitionSpec as P, NamedSharding
-        from repro.ckpt import save_checkpoint
-        mesh = jax.make_mesh((4,), ("data",),
-                             axis_types=(jax.sharding.AxisType.Auto,))
-        x = jax.device_put(jnp.arange(32.0).reshape(8, 4),
-                           NamedSharding(mesh, P("data")))
-        save_checkpoint({d!r}, 1, {{"x": x}})
-        print(json.dumps({{"ok": 1}}))
-    """)
-    rec = _run_child(2, f"""
-        from jax.sharding import PartitionSpec as P, NamedSharding
-        from repro.ckpt import load_checkpoint
-        mesh = jax.make_mesh((2,), ("data",),
-                             axis_types=(jax.sharding.AxisType.Auto,))
-        sh = {{"x": NamedSharding(mesh, P("data"))}}
-        out, step = load_checkpoint({d!r}, {{"x": jnp.zeros((8, 4))}},
-                                    shardings=sh)
-        ok = bool(jnp.all(out["x"] == jnp.arange(32.0).reshape(8, 4)))
-        n_shards = len(out["x"].sharding.device_set)
-        print(json.dumps({{"ok": ok, "n_shards": n_shards,
-                           "step": step}}))
-    """)
-    assert rec["ok"] and rec["n_shards"] == 2 and rec["step"] == 1
+class FakeMesh:
+    axis_names = ("pod", "data", "model")
+    shape = {"pod": 2, "data": 16, "model": 16}
+
+
+def test_logical_to_spec_prunes_missing_axes():
+    rules = ShardingRules()
+    spec = logical_to_spec(("vol", None, "proj"), rules, FakeMesh())
+    assert spec == P("data", None, ("pod", "model"))
+
+    class PodlessMesh:
+        axis_names = ("data", "model")
+        shape = {"data": 16, "model": 16}
+
+    spec = logical_to_spec(("vol", None, "proj"), rules, PodlessMesh())
+    assert spec == P("data", None, "model")
+
+
+def test_valid_spec_drops_indivisible():
+    spec = logical_to_spec(("proj", "vol"), ShardingRules(), FakeMesh())
+    assert spec == P(("pod", "model"), "data")
+    assert valid_spec((64, 32), spec, FakeMesh()) == spec
+    # 496 views do not split 32 ways -> the projection dim replicates.
+    assert valid_spec((496, 32), spec, FakeMesh()) == P(None, "data")
+    # 8 planes over 16 shards replicate, and the tail is trimmed.
+    assert valid_spec((64, 8), spec, FakeMesh()) == P(("pod", "model"))

@@ -1,7 +1,6 @@
-"""FDK filtering properties + roofline report rendering."""
+"""FDK filtering properties: ramp, cosine and Parker weights, subsets."""
 
 import dataclasses
-import json
 import math
 
 import jax.numpy as jnp
@@ -108,29 +107,3 @@ def test_single_projection_scalar_angle_index():
         filter_projections(projs[5], geom, angle_indices=9)
     with pytest.raises(ValueError, match="shape"):
         filter_projections(projs[:2], geom, angle_indices=np.arange(3))
-
-
-def test_report_renders(tmp_path):
-    from repro.analysis.report import load, roofline_table, summary
-    rec = {
-        "arch": "test-arch", "shape": "train_4k", "mesh": "pod",
-        "chips": 256, "status": "ok", "step": "train_step",
-        "model_params": 1, "active_params": 1,
-        "roofline": {"compute_s": 1.0, "memory_s": 2.0,
-                     "collective_s": 0.5, "dominant": "memory",
-                     "bound_s": 2.0},
-        "useful_flops_ratio": 0.5,
-        "memory": {"live_bytes": 8e9},
-        "fits_16gb_hbm": True,
-    }
-    skip = {"arch": "test-arch", "shape": "long_500k", "mesh": "pod",
-            "status": "skipped", "reason": "sub-quadratic required"}
-    for i, r in enumerate((rec, skip)):
-        with open(tmp_path / f"r{i}.json", "w") as f:
-            json.dump(r, f)
-    recs = load(str(tmp_path))
-    assert "1 ok, 1 skipped" in summary(recs)
-    table = roofline_table(recs, "pod")
-    assert "test-arch" in table and "memory" in table
-    assert "50.0%" in table           # MFU-bound = compute/bound
-    assert "skip" in table

@@ -1,4 +1,4 @@
-"""gather_ops strategy equivalence + RoPE/attention layer properties."""
+"""gather_ops strategy equivalence: take, one-hot matmul and auto."""
 
 import jax
 import jax.numpy as jnp
@@ -46,72 +46,3 @@ def test_onehot_gather_differentiable_scatter_add():
     g = jax.grad(f)(table)
     assert float(g[3, 0]) == 2.0 and float(g[7, 0]) == 1.0
     assert float(g[0, 0]) == 0.0
-
-
-# ----------------------------------------------------------------------
-# RoPE / attention properties
-# ----------------------------------------------------------------------
-
-def test_rope_preserves_norm_and_relative_phase():
-    from repro.models.layers import apply_rope
-    B, S, H, hd = 2, 8, 2, 16
-    key = jax.random.PRNGKey(0)
-    q = jax.random.normal(key, (B, S, H, hd))
-    pos = jnp.broadcast_to(jnp.arange(S), (B, S))
-    q2, k2 = apply_rope(q, q, pos, hd, 1e4, "standard")
-    np.testing.assert_allclose(np.linalg.norm(np.asarray(q2), axis=-1),
-                               np.linalg.norm(np.asarray(q), axis=-1),
-                               rtol=1e-5)
-    # Relative property: <rope(q,m), rope(k,n)> depends only on m-n.
-    qs, ks = apply_rope(q, q, pos + 5, hd, 1e4, "standard")
-    dot_a = np.einsum("bshd,bshd->bsh", np.asarray(q2), np.asarray(k2))
-    dot_b = np.einsum("bshd,bshd->bsh", np.asarray(qs), np.asarray(ks))
-    np.testing.assert_allclose(dot_a, dot_b, rtol=1e-4, atol=1e-4)
-
-
-def test_mrope_equals_standard_for_text():
-    """Equal (t,h,w) position components reduce M-RoPE to RoPE."""
-    from repro.models.layers import apply_rope
-    B, S, H, hd = 1, 6, 2, 16
-    q = jax.random.normal(jax.random.PRNGKey(1), (B, S, H, hd))
-    pos = jnp.broadcast_to(jnp.arange(S), (B, S))
-    pos3 = jnp.broadcast_to(pos, (3, B, S))
-    a, _ = apply_rope(q, q, pos, hd, 1e4, "standard")
-    b, _ = apply_rope(q, q, pos3, hd, 1e4, "mrope")
-    np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                               rtol=1e-5, atol=1e-5)
-
-
-def test_chunked_attention_equals_dense():
-    from repro.models.attention import (_chunked_attention,
-                                        _dense_attention, _group)
-    B, S, KV, G, hd = 2, 32, 2, 2, 8
-    key = jax.random.PRNGKey(2)
-    q = jax.random.normal(key, (B, S, KV * G, hd))
-    k = jax.random.normal(jax.random.fold_in(key, 1), (B, S, KV, hd))
-    v = jax.random.normal(jax.random.fold_in(key, 2), (B, S, KV, hd))
-    qg = _group(q, KV)
-    dense = _dense_attention(qg, k, v, causal=True)
-    for chunk in (4, 8, 16):
-        chunked = _chunked_attention(qg, k, v, True, chunk)
-        np.testing.assert_allclose(np.asarray(chunked),
-                                   np.asarray(dense),
-                                   rtol=2e-3, atol=2e-3)
-
-
-def test_sliding_decode_offset():
-    """Chunked attention with q_offset masks exactly like dense."""
-    from repro.models.attention import (_chunked_attention,
-                                        _dense_attention, _group)
-    B, T, KV, G, hd = 1, 16, 1, 2, 8
-    key = jax.random.PRNGKey(3)
-    q = jax.random.normal(key, (B, 1, KV * G, hd))
-    k = jax.random.normal(jax.random.fold_in(key, 1), (B, T, KV, hd))
-    v = jax.random.normal(jax.random.fold_in(key, 2), (B, T, KV, hd))
-    qg = _group(q, KV)
-    for idx in (0, 5, 15):
-        dense = _dense_attention(qg, k, v, True, q_offset=idx)
-        chunked = _chunked_attention(qg, k, v, True, 4, q_offset=idx)
-        np.testing.assert_allclose(np.asarray(chunked),
-                                   np.asarray(dense), rtol=2e-3,
-                                   atol=2e-3)

@@ -168,21 +168,3 @@ def test_validate_rejects_undersized_strips():
     with pytest.raises(ValueError, match="does not cover"):
         validate_strip_config(geom, np.asarray(mats[0], np.float64),
                               ty=32, chunk=32, band=8, width=128)
-
-
-def test_gather_kernel_sweep():
-    """One-hot gather kernel vs oracle across shapes/dtypes."""
-    import jax
-    from repro.kernels.gather_kernel_ops import pallas_onehot_gather
-    from repro.kernels.gather_ref import gather_ref
-    key = jax.random.PRNGKey(1)
-    for V, D, N, dt in [(300, 32, 17, jnp.float32),
-                        (1024, 128, 512, jnp.float32),
-                        (513, 64, 100, jnp.bfloat16)]:
-        table = jax.random.normal(key, (V, D), jnp.float32).astype(dt)
-        ids = jax.random.randint(key, (N,), -2, V + 2)
-        out = pallas_onehot_gather(table, ids)
-        ref = gather_ref(table, ids)
-        np.testing.assert_allclose(
-            np.asarray(out, np.float32), np.asarray(ref, np.float32),
-            rtol=1e-5, atol=1e-5)
